@@ -125,6 +125,10 @@ class ZooContext:
     def __init__(self, conf: Optional[ZooConf] = None,
                  devices: Optional[Sequence[jax.Device]] = None):
         self.conf = conf or ZooConf.from_env()
+        # before the first compile of the job: a cold ResNet-50 / BERT
+        # compile is paid once per cache directory, not once per process
+        from analytics_zoo_tpu.inference.aot import enable_persistent_cache
+        enable_persistent_cache()
         self.devices = list(devices if devices is not None else jax.devices())
         self.mesh = self._build_mesh()
         self._rng = jax.random.PRNGKey(self.conf.seed)

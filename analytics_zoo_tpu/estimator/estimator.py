@@ -535,6 +535,8 @@ class Estimator:
                   hist, np_rng, tstate, retries_left) -> History:
         obs = self._fit_metrics_objs()
         epoch = 0
+        # has the step program completed a call since it was (re)built?
+        stepped = False
         while epoch < epochs:
             t0 = time.time()
             losses, seen = [], 0
@@ -569,6 +571,7 @@ class Estimator:
                          ls) = self._scan_step(self.params, self.opt_state,
                                                self.state, sx, sy, sw, rngs)
                         self.global_step += ksteps
+                        stepped = True
                         l = ls[-1]
                         losses.extend(list(ls))
                     else:
@@ -579,6 +582,7 @@ class Estimator:
                          l) = self._train_step(self.params, self.opt_state,
                                                self.state, sx, sy, sw, rng)
                         self.global_step += 1
+                        stepped = True
                         losses.append(l)
                     seen += int(wsum)
                     # registry metrics (PR 4): per-step wall time on the
@@ -626,6 +630,12 @@ class Estimator:
             except (KeyboardInterrupt, SystemExit):
                 raise
             except Exception as e:
+                if not stepped:
+                    # nothing has run since the step was (re)built: this
+                    # is a trace/compile error (or the program's first
+                    # launch), which restoring a checkpoint cannot repair
+                    # — retrying would bury it under quiet restores
+                    raise
                 # failure-retry with checkpoint restore
                 # (Topology.scala:1180-1262 semantics); the backoff between
                 # attempts comes from the shared RetryPolicy so a sick
@@ -649,6 +659,7 @@ class Estimator:
                         self._scan_step = self._build_scanned_train_step()
                     else:
                         self._train_step = self._build_train_step()
+                    stepped = False
                     continue
                 raise
             finally:

@@ -18,10 +18,12 @@ makes cold start a *derived, measured* path:
   ``_jitted_with_scales`` consult BEFORE tracing — a warmed bucket is never
   traced again, and a ``_jitted_scaled_base`` rebuild cannot invalidate it
   (the cache is keyed by load epoch + signature, not wrapper identity).
-- ``enable_persistent_cache(dir)`` wires jax's persistent compilation cache
-  at a per-deployment directory (the manager points every replica of one
-  deployment at ``<pidfile>.xla_cache``): the *second* replica of a
-  topology loads executables from disk instead of compiling at all.
+- ``enable_persistent_cache()`` wires jax's persistent compilation cache —
+  the ONE place in the tree that does — at the directory
+  ``compile_cache_dir`` resolves (``$JAX_COMPILATION_CACHE_DIR``, else a
+  deployment's own setting, else one fixed path inside the checkout):
+  the *second* replica of a topology, and the second run of a training
+  job, load executables from disk instead of compiling at all.
 - ``COMPILE_STATS`` counts what actually happened via jax's monitoring
   events: compile REQUESTS (fired whether the persistent cache answers or
   not) and persistent-cache hits/misses — with every program cacheable,
@@ -139,29 +141,61 @@ def install_compile_listeners() -> CompileStats:
     return COMPILE_STATS
 
 
-def enable_persistent_cache(path: str) -> str:
-    """Point jax's persistent compilation cache at ``path`` (created if
-    missing) and drop the min-compile-time/min-entry-size thresholds so
-    EVERY serving program lands in it — the serving bucket programs are
-    individually small and fast to compile, exactly what the default
-    thresholds skip.  Process-global (jax.config); every replica of one
-    deployment shares the same directory, so the second replica of a
-    topology reads executables instead of compiling.  Returns the path."""
+# The cache directory used when neither the environment nor the deployment
+# names one: a FIXED path inside the checkout (git-ignored).  The directory
+# is part of how a cache is found again, so it is never derived from a
+# pid, a pidfile, a timestamp or mkdtemp.
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_compile_cache")
+
+
+def compile_cache_dir(configured: Optional[str] = None) -> Optional[str]:
+    """Where this process keeps its persistent compilation cache.
+
+    1. ``$JAX_COMPILATION_CACHE_DIR`` when set — jax itself reads it, and
+       then nothing in code names another directory (``configured`` does
+       not apply, not even ``"off"``).
+    2. else ``configured`` — a deployment's ``params.compile_cache_dir``
+       (``"off"`` = no persistent cache, returns None), or the empty
+       directory a cold-start A/B measures against.
+    3. else ``DEFAULT_COMPILE_CACHE_DIR``."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    if configured == "off":
+        return None
+    return configured or DEFAULT_COMPILE_CACHE_DIR
+
+
+def enable_persistent_cache(configured: Optional[str] = None
+                            ) -> Optional[str]:
+    """Turn on jax's persistent compilation cache at
+    ``compile_cache_dir(configured)`` and drop the min-compile-time /
+    min-entry-size thresholds so EVERY program lands in it — the serving
+    bucket programs are individually small and fast to compile, exactly
+    what the default thresholds skip.  Process-global (jax.config) and
+    idempotent; serving replicas, ``manager warmup`` and the training
+    bootstrap (``ZooContext``) all come through here.  A caller with no
+    setting of its own (``configured=None``) never moves a choice made
+    earlier in the process: a directory already in force stays, and a
+    deployment's ``"off"`` (jax's own ``jax_enable_compilation_cache``
+    switch) stays off.  Returns the directory, or None when off."""
     import jax
-    if getattr(jax.config, "jax_compilation_cache_dir", None) == path:
-        # already wired (a replica boot enables before model load AND at
-        # engine start): skip the config churn and the repeat log line
-        install_compile_listeners()
-        return path
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
+    path = compile_cache_dir(configured)
+    if path is None:
+        jax.config.update("jax_enable_compilation_cache", False)
+        return None
+    current = jax.config.jax_compilation_cache_dir
+    if configured is None and current:
+        path = current
+    if current != path:
+        os.makedirs(path, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
+        logger.info("aot: persistent XLA compilation cache at %s", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    try:
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except AttributeError:  # older jax without the size threshold
-        pass
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     install_compile_listeners()
-    logger.info("aot: persistent XLA compilation cache at %s", path)
     return path
 
 
@@ -351,6 +385,11 @@ def resolve_manifest(model, warmup_spec) -> List[WarmupEntry]:
         scale_dtypes=tuple(spec.get("scale_dtypes") or ("|i1",)))
 
 
+def warm_error(entry, exc: BaseException) -> str:
+    """One failed warm-up entry as the short string the stats carry."""
+    return f"{entry}: {type(exc).__name__}: {exc}"[:500]
+
+
 def warm_up(model, manifest: Optional[Sequence[WarmupEntry]] = None,
             progress=None, stop=None, **manifest_kw) -> Dict:
     """Compile every program in ``manifest`` (default: derived via
@@ -360,8 +399,8 @@ def warm_up(model, manifest: Optional[Sequence[WarmupEntry]] = None,
     entry)`` is called after each entry — the serving engine uses it to
     publish per-bucket progress on ``/readyz``.
 
-    Returns ``{"programs", "compiled", "skipped", "failed", "seconds",
-    "compile_stats"}`` where ``compile_stats`` is the COMPILE_STATS delta
+    Returns ``{"programs", "compiled", "skipped", "failed", "errors",
+    "seconds", "compile_stats"}`` where ``compile_stats`` is the COMPILE_STATS delta
     for the pass — on a process whose persistent cache is already
     populated, ``cache_misses`` stays 0 and ``cache_hits`` covers the
     set (the zero-cold-start evidence)."""
@@ -371,6 +410,7 @@ def warm_up(model, manifest: Optional[Sequence[WarmupEntry]] = None,
     before = COMPILE_STATS.snapshot()
     t0 = time.monotonic()
     compiled = skipped = failed = 0
+    errors: List[str] = []
     stopped = False
     for i, entry in enumerate(manifest):
         if stop is not None and stop():
@@ -384,11 +424,12 @@ def warm_up(model, manifest: Optional[Sequence[WarmupEntry]] = None,
             compiled += 1 if fresh else 0
             skipped += 0 if fresh else 1
         except Exception as e:  # noqa: BLE001 — one bad entry must not
-            # strand the rest of the set (the live path falls back to
-            # tracing for whatever stays cold)
+            # strand the rest of the set; it is counted, and its message
+            # rides the stats so `degraded` says WHAT failed
             failed += 1
-            logger.warning("aot: warm-up entry %s failed (%s: %s)",
-                           entry, type(e).__name__, e)
+            errors.append(warm_error(entry, e))
+            logger.warning("aot: warm-up entry %s failed", entry,
+                           exc_info=True)
         if progress is not None:
             progress(i + 1, len(manifest), entry)
     after = COMPILE_STATS.snapshot()
@@ -397,6 +438,7 @@ def warm_up(model, manifest: Optional[Sequence[WarmupEntry]] = None,
         "compiled": compiled,
         "skipped": skipped,
         "failed": failed,
+        "errors": errors,
         "stopped": stopped,
         "seconds": round(time.monotonic() - t0, 3),
         "compile_stats": {k: round(after[k] - before[k], 3)

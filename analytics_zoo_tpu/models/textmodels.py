@@ -387,18 +387,21 @@ class TransformerLM(Layer):
                          max_active: int, kv_quant: str = "off"):
         """Zeroed pool pytree for the paged batcher.  ``n_blocks`` counts
         the TRASH block (row 0) — the allocator hands out ids 1..n-1.
-        int8 mode adds per-(block, head) scale planes and per-slot f32
-        STAGING buffers holding each row's active (partial) block exactly,
-        so every append re-quantizes from exact values."""
+        Pool blocks are (block_len, hidden): heads and head_dim folded
+        into one lane axis (``ops/paged_attention`` layout).  int8 mode
+        adds per-(block, head) scale planes and per-slot f32 STAGING
+        buffers holding each row's active (partial) block exactly (kept
+        unfolded — ``kv_pack_int8`` reduces per head), so every append
+        re-quantizes from exact values."""
         if kv_quant not in ("off", "int8"):
             raise ValueError(f"kv_quant must be off|int8, got {kv_quant!r}")
         nh, hd = self.n_head, self.hidden // self.n_head
         L = self.n_layers
         kdt = np.int8 if kv_quant == "int8" else np.float32
         pools = {
-            "k": [np.zeros((n_blocks, block_len, nh, hd), kdt)
+            "k": [np.zeros((n_blocks, block_len, self.hidden), kdt)
                   for _ in range(L)],
-            "v": [np.zeros((n_blocks, block_len, nh, hd), kdt)
+            "v": [np.zeros((n_blocks, block_len, self.hidden), kdt)
                   for _ in range(L)],
         }
         if kv_quant == "int8":
@@ -507,8 +510,10 @@ class TransformerLM(Layer):
                     .at[rows, off].set(v)
                 qk, sk = kv_pack_int8(stk)                # (A,bl,nh,hd)
                 qv, sv = kv_pack_int8(stv)
-                kp = pstate["k"][li].at[cur].set(qk)
-                vp = pstate["v"][li].at[cur].set(qv)
+                kp = pstate["k"][li].at[cur].set(
+                    qk.reshape(A, bl, self.hidden))
+                vp = pstate["v"][li].at[cur].set(
+                    qv.reshape(A, bl, self.hidden))
                 ksc = pstate["ks"][li].at[cur].set(sk)
                 vsc = pstate["vs"][li].at[cur].set(sv)
                 o = paged_attention(q, kp, vp, bt, pos + 1, ksc, vsc,
@@ -518,8 +523,10 @@ class TransformerLM(Layer):
                 new["stk"].append(stk)
                 new["stv"].append(stv)
             else:
-                kp = pstate["k"][li].at[cur, off].set(k)
-                vp = pstate["v"][li].at[cur, off].set(v)
+                kp = pstate["k"][li].at[cur, off].set(
+                    k.reshape(A, self.hidden))
+                vp = pstate["v"][li].at[cur, off].set(
+                    v.reshape(A, self.hidden))
                 o = paged_attention(q, kp, vp, bt, pos + 1, impl=impl)
             new["k"].append(kp)
             new["v"].append(vp)

@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from analytics_zoo_tpu.ops.dispatch import on_tpu
+
 
 def _attention_core(q, k, v, eq_qk, eq_av, mask=None, causal=False,
                     scale=None, dropout_rate=0.0, dropout_rng=None):
@@ -118,10 +120,10 @@ def _seq_parallel_mesh(t_len: int, mask, dropping: bool):
 def _select_flash(use_flash, t_len, head_dim, mask, dropping, warn=False):
     """Shared flash-eligibility policy for both layout front-ends."""
     if use_flash is None:
-        auto = (jax.default_backend() == "tpu" and _flash_worthwhile(t_len)
+        tpu = on_tpu()
+        auto = (tpu and _flash_worthwhile(t_len)
                 and mask is None and head_dim <= 256 and not dropping)
-        if (warn and dropping and jax.default_backend() == "tpu"
-                and _flash_worthwhile(t_len)):
+        if warn and dropping and tpu and _flash_worthwhile(t_len):
             warnings.warn(
                 "attention dropout forces the O(T^2) XLA attention path; the "
                 "flash kernel does not implement it — consider attn_drop=0 "
@@ -154,15 +156,14 @@ def attention_bthd(q, k, v, mask=None, causal: bool = False,
     use_flash = _select_flash(use_flash, q.shape[1], q.shape[-1], mask,
                               dropping, warn=True)
     if use_flash:
-        try:
-            from analytics_zoo_tpu.ops.flash_attention import flash_attention
+        # selected means used: a flash call that cannot trace or compile
+        # raises here, it never quietly becomes the XLA path
+        from analytics_zoo_tpu.ops.flash_attention import flash_attention
 
-            def t(a):
-                return jnp.transpose(a, (0, 2, 1, 3))
-            return t(flash_attention(t(q), t(k), t(v), causal=causal,
-                                     scale=scale))
-        except Exception:
-            pass
+        def t(a):
+            return jnp.transpose(a, (0, 2, 1, 3))
+        return t(flash_attention(t(q), t(k), t(v), causal=causal,
+                                 scale=scale))
     return _attention_xla_bthd(q, k, v, mask=mask, causal=causal, scale=scale,
                                dropout_rate=dropout_rate,
                                dropout_rng=dropout_rng)
@@ -184,10 +185,7 @@ def dot_product_attention(q, k, v, mask=None, causal: bool = False,
     use_flash = _select_flash(use_flash, q.shape[-2], q.shape[-1], mask,
                               dropping, warn=True)
     if use_flash:
-        try:
-            from analytics_zoo_tpu.ops.flash_attention import flash_attention
-            return flash_attention(q, k, v, causal=causal, scale=scale)
-        except Exception:
-            pass
+        from analytics_zoo_tpu.ops.flash_attention import flash_attention
+        return flash_attention(q, k, v, causal=causal, scale=scale)
     return _attention_xla(q, k, v, mask=mask, causal=causal, scale=scale,
                           dropout_rate=dropout_rate, dropout_rng=dropout_rng)

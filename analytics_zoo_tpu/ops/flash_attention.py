@@ -35,6 +35,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from analytics_zoo_tpu.ops.dispatch import on_tpu
+
 NEG_INF = -1e30
 
 # Backward block sizes, tuned on v5e 2026-07-30 (tools/flash_tune.py --tune,
@@ -147,6 +149,7 @@ def _flash_fwd(q, k, v, causal: bool, scale: float, block_q: int,
         ],
         out_specs=out_specs,
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q3, k3, v3)
     out = res[0].reshape(B, H, Tq_pad, D)[:, :, :T, :]
     if not emit_lse:
@@ -285,6 +288,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal: bool, scale: float,
         ],
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(q3, k3, v3, do3, lse3, dlt3)
 
     dk, dv = pl.pallas_call(
@@ -304,6 +308,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal: bool, scale: float,
         out_specs=[pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0)),
                    pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0))],
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(q3, k3, v3, do3, lse3, dlt3)
 
     dq = dq.reshape(B, H, Tq_pad, D)[:, :, :T, :]
@@ -313,9 +318,11 @@ def _flash_bwd(q, k, v, out, lse, g, causal: bool, scale: float,
 
 
 def _resolve(q, k, scale, block_q, block_k, interpret):
+    """``interpret=None``: compiled on a TPU backend, interpreted on CPU
+    (the only way the kernel runs there), an error on any other backend
+    (``ops/dispatch.on_tpu``)."""
     s = scale if scale is not None else 1.0 / np.sqrt(q.shape[-1])
-    interp = (jax.default_backend() != "tpu") if interpret is None \
-        else interpret
+    interp = (not on_tpu()) if interpret is None else interpret
     bq = min(block_q, q.shape[2])
     bk = min(block_k, k.shape[2])
     return s, bq, bk, interp

@@ -18,10 +18,10 @@ MXU matmul:
   — activations stay 16/32-bit (weight-only quantization, the usual
   int4 recipe).
 
-Every kernel ships with a pure-XLA reference implementation that is both
-the CPU / interpret fallback (``impl="auto"`` picks the kernel only on a
-real TPU backend, mirroring ``ops/flash_attention._resolve``) and the
-numerics ORACLE the parity tests compare against.
+Every kernel ships with a pure-XLA reference implementation: what a CPU
+process computes through (``impl="auto"`` resolves by backend in
+``ops/dispatch.resolve_impl``) and the numerics ORACLE the parity tests
+compare against.
 
 Block sizes follow the flash_attention precedent: (128, 128) output tiles
 keep every dot MXU-shaped; the w4 group loop runs ``group_size``-row
@@ -41,12 +41,15 @@ weight store.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+
+from analytics_zoo_tpu.ops.dispatch import resolve_impl
 
 # Output-tile block sizes (MXU-shaped; clamped to the padded operand).
 BLOCK_M = 128
@@ -65,18 +68,6 @@ W4_GROUP = 128
 
 def _round_up(n: int, m: int) -> int:
     return -(-int(n) // int(m)) * int(m)
-
-
-def _resolve_impl(impl: Optional[str]) -> str:
-    """"auto"/None -> the Pallas kernel on a real TPU backend, the XLA
-    reference everywhere else (CPU containers serve through XLA; the
-    kernels still run there via impl="interpret" — the parity tests'
-    mode).  Explicit "pallas"/"xla"/"interpret" win."""
-    if impl in (None, "auto"):
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
-    if impl not in ("pallas", "xla", "interpret"):
-        raise ValueError(f"impl={impl!r}: expected auto|pallas|xla|interpret")
-    return impl
 
 
 # -- int4 packing (two weights per byte, split layout) -------------------------
@@ -118,7 +109,7 @@ def expand_group_scales(s_g, k: int):
     return jnp.repeat(jnp.asarray(s_g), gs, axis=0)[: int(k)]
 
 
-# -- XLA reference implementations (CPU fallback + numerics oracle) ------------
+# -- XLA reference implementations (the CPU path + numerics oracle) -----------
 
 def w8a8_matmul_xla(x_q, w_q, scale):
     """``x_q`` (M, K) int8 @ ``w_q`` (K, N) int8 with int32 accumulation,
@@ -180,28 +171,50 @@ def w8a8_matmul_pallas(x_q, w_q, scale, block_m: int = BLOCK_M,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         interpret=interpret,
+        name="w8a8_matmul",
     )(x_q, w_q, s2)
     return out[:m, :n]
+
+
+def _w4_chunk(gs: int) -> int:
+    """Contraction rows one kernel loop step covers: whole groups AND whole
+    128-lane tiles, because the step slices the activation's LANE axis at
+    a dynamic offset and Mosaic only takes lane offsets it can prove are
+    multiples of 128 (group_size 64 — the quantizer's default — sliced at
+    64 did not compile)."""
+    return gs * _LANE // math.gcd(gs, _LANE)
 
 
 def _w4a16_kernel(x_ref, p_ref, s_ref, o_ref, *, k: int, gs: int,
                   n_groups: int):
     # x: (bm, K) f32/bf16; p: (K//2, bn) u8 split-packed; s: (G, bn) f32;
-    # o: (bm, bn) f32.  Loop over group-sized K-blocks: each packed tile
-    # yields TWO weight tiles (low nibble = contraction rows [j*gs, ..),
-    # high nibble = the same rows offset by K//2), each dequantized by its
-    # group's scale row entirely in VMEM and fed to the MXU.
+    # o: (bm, bn) f32.  Loop over lane-aligned K-chunks of whole groups:
+    # each packed tile yields TWO weight tiles (low nibble = contraction
+    # rows [j*chunk, ..), high nibble = the same rows offset by K//2), each
+    # dequantized by its groups' scale rows entirely in VMEM and fed to
+    # the MXU as one chunk-deep dot.
     half = k // 2
     g_half = n_groups // 2
+    chunk = _w4_chunk(gs)
+    per = chunk // gs                     # groups per chunk
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
+
+    def scales(g0):
+        # (chunk, bn): row i scales by group g0 + i // gs
+        out = s_ref[pl.ds(g0, 1), :]
+        for g in range(1, per):
+            out = jnp.where(row >= g * gs, s_ref[pl.ds(g0 + g, 1), :], out)
+        return out
 
     def body(j, acc):
-        b = p_ref[pl.ds(j * gs, gs), :].astype(jnp.int32)
-        w_lo = (((b & 0xF) ^ 8) - 8).astype(jnp.float32) \
-            * s_ref[pl.ds(j, 1), :]
+        off = pl.multiple_of(j * chunk, chunk)
+        b = p_ref[pl.ds(off, chunk), :].astype(jnp.int32)
+        w_lo = (((b & 0xF) ^ 8) - 8).astype(jnp.float32) * scales(j * per)
         w_hi = (((b >> 4) ^ 8) - 8).astype(jnp.float32) \
-            * s_ref[pl.ds(j + g_half, 1), :]
-        x_lo = x_ref[:, pl.ds(j * gs, gs)].astype(jnp.float32)
-        x_hi = x_ref[:, pl.ds(half + j * gs, gs)].astype(jnp.float32)
+            * scales(g_half + j * per)
+        x_lo = x_ref[:, pl.ds(off, chunk)].astype(jnp.float32)
+        x_hi = x_ref[:, pl.ds(pl.multiple_of(half + off, _LANE), chunk)] \
+            .astype(jnp.float32)
         acc = acc + jax.lax.dot_general(
             x_lo, w_lo, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -211,20 +224,21 @@ def _w4a16_kernel(x_ref, p_ref, s_ref, o_ref, *, k: int, gs: int,
         return acc
 
     acc0 = jnp.zeros(o_ref.shape, jnp.float32)
-    o_ref[...] = jax.lax.fori_loop(0, half // gs, body, acc0)
+    o_ref[...] = jax.lax.fori_loop(0, half // chunk, body, acc0)
 
 
 def _w4_pallas_ok(k: int, n_groups: int) -> bool:
     """The kernel's alignment contract: groups divide K EXACTLY (the
     kernel's ``gs = k // n_groups`` must equal the expansion's
     ``ceil(k/n_groups)`` — a ragged division would mis-slice packed and
-    scale rows silently), even K, halves made of whole groups, group rows
-    a legal uint8 sublane tile.  Shapes outside it serve through the XLA
-    reference."""
+    scale rows silently), even K, group rows a legal uint8 sublane tile,
+    halves made of whole lane-aligned chunks (``_w4_chunk``).
+    ``impl="auto"`` sends shapes outside it to the XLA reference; an
+    explicit kernel request raises."""
     if k <= 0 or k % 2 != 0 or n_groups % 2 != 0 or k % n_groups != 0:
         return False
     gs = k // n_groups
-    return (k // 2) % gs == 0 and gs % _SUBLANE_I8 == 0
+    return gs % _SUBLANE_I8 == 0 and (k // 2) % _w4_chunk(gs) == 0
 
 
 def w4a16_matmul_pallas(x, w_q4, s_g, block_m: int = BLOCK_M,
@@ -235,8 +249,8 @@ def w4a16_matmul_pallas(x, w_q4, s_g, block_m: int = BLOCK_M,
     if not _w4_pallas_ok(k, n_groups):
         raise ValueError(
             f"w4a16 kernel needs even K with whole {_SUBLANE_I8}-aligned "
-            f"groups per half (K={k}, groups={n_groups}); use the XLA "
-            "reference for this shape")
+            f"groups in {_LANE}-aligned chunks per half (K={k}, "
+            f"groups={n_groups}); use the XLA reference for this shape")
     gs = k // n_groups
     bm = min(int(block_m), _round_up(max(m, 1), _SUBLANE_F32))
     bn = min(int(block_n), _round_up(max(n, 1), _LANE))
@@ -257,6 +271,7 @@ def w4a16_matmul_pallas(x, w_q4, s_g, block_m: int = BLOCK_M,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         interpret=interpret,
+        name="w4a16_matmul",
     )(x, w_q4, jnp.asarray(s_g, jnp.float32))
     return out[:m, :n]
 
@@ -266,8 +281,8 @@ def w4a16_matmul_pallas(x, w_q4, s_g, block_m: int = BLOCK_M,
 def w8a8_matmul(x_q, w_q, scale, impl: Optional[str] = None):
     """Fused-dequant int8 matmul: (M, K) s8 @ (K, N) s8 -> (M, N) f32
     ``= (x_q @ w_q).astype(f32) * scale``.  ``impl`` auto-selects the
-    Pallas kernel on TPU, the XLA reference elsewhere."""
-    mode = _resolve_impl(impl)
+    Pallas kernel on TPU, the XLA reference on CPU."""
+    mode = resolve_impl(impl)
     if mode == "xla":
         return w8a8_matmul_xla(x_q, w_q, scale)
     return w8a8_matmul_pallas(x_q, w_q, scale,
@@ -276,12 +291,14 @@ def w8a8_matmul(x_q, w_q, scale, impl: Optional[str] = None):
 
 def w4a16_matmul(x, w_q4, s_g, impl: Optional[str] = None):
     """Weight-only int4 matmul: (M, K) f32/bf16 @ nibble-packed
-    (ceil(K/2), N) u8 with per-group scales (G, N) -> (M, N) f32.  Shapes
-    outside the kernel's alignment contract fall back to the XLA
-    reference even on TPU."""
-    mode = _resolve_impl(impl)
-    k = int(x.shape[-1])
-    if mode != "xla" and not _w4_pallas_ok(k, int(s_g.shape[0])):
+    (ceil(K/2), N) u8 with per-group scales (G, N) -> (M, N) f32.  With
+    ``impl`` auto, a shape outside the kernel's alignment contract
+    (``_w4_pallas_ok`` — a property of the operands, visible to the
+    caller) computes through the XLA reference; asking for ``pallas`` or
+    ``interpret`` on such a shape raises instead of being demoted."""
+    mode = resolve_impl(impl)
+    if impl in (None, "auto") and not _w4_pallas_ok(
+            int(x.shape[-1]), int(s_g.shape[0])):
         mode = "xla"
     if mode == "xla":
         return w4a16_matmul_xla(x, w_q4, s_g)

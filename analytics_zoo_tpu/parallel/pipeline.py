@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from analytics_zoo_tpu.common.context import PIPE_AXIS
-from analytics_zoo_tpu.utils import jaxcompat
 
 
 def stack_stage_params(params_list):
@@ -33,13 +32,14 @@ def _pipeline_local(stage_params, x, *, stage_fn, axis_name: str):
     """Per-device body.  stage_params: leaves (1, ...) — this device's stage slice;
     x: (M, Bm, ...) full microbatched input (replicated)."""
     params = jax.tree.map(lambda a: a[0], stage_params)
-    S = jaxcompat.axis_size(axis_name)
+    S = jax.lax.axis_size(axis_name)
     s = jax.lax.axis_index(axis_name)
     M = x.shape[0]
     perm = [(i, (i + 1) % S) for i in range(S)]
     # activation buffer entering this stage each tick; pcast marks it varying over
-    # the pipe axis (shard_map manual-axes typing, jax >= 0.9)
-    zero_act = jaxcompat.pcast_varying(jnp.zeros_like(x[0]), axis_name)
+    # the pipe axis (shard_map manual-axes typing)
+    zero_act = jax.lax.pcast(jnp.zeros_like(x[0]), (axis_name,),
+                             to="varying")
 
     def tick(carry, t):
         act = carry
@@ -64,7 +64,7 @@ def pipeline_apply(stage_fn: Callable, stacked_params, x_microbatches,
     stacked_params: leaves (S, ...); x_microbatches: (M, Bm, ...).
     Returns (M, Bm, ...) outputs (replicated over the pipe axis)."""
     pspec = jax.tree.map(lambda _: P(axis_name), stacked_params)
-    fn = jaxcompat.shard_map(
+    fn = jax.shard_map(
         functools.partial(_pipeline_local, stage_fn=stage_fn,
                           axis_name=axis_name),
         mesh=mesh,
@@ -119,8 +119,8 @@ def pipeline_apply_stages(stage_fns, stage_params_list, x_microbatches,
         s = jax.lax.axis_index(axis_name)
         M = x.shape[0]
         perm = [(i, (i + 1) % S) for i in range(S)]
-        zero_act = jaxcompat.pcast_varying(jnp.zeros_like(x[0]),
-                                           axis_name)
+        zero_act = jax.lax.pcast(jnp.zeros_like(x[0]), (axis_name,),
+                                 to="varying")
         branches = [
             functools.partial(
                 lambda f, u, n, t: f(u(vec[:n]), t), f, u, n)
@@ -139,7 +139,7 @@ def pipeline_apply_stages(stage_fns, stage_params_list, x_microbatches,
         mask = (s == S - 1).astype(results.dtype)
         return jax.lax.psum(results * mask, axis_name)
 
-    fn = jaxcompat.shard_map(local, mesh=mesh, in_specs=(P(axis_name), P()),
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(P(axis_name), P()),
                        out_specs=P())
     return fn(stacked, x_microbatches)
 

@@ -23,13 +23,13 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from analytics_zoo_tpu.common.context import SEQ_AXIS
-from analytics_zoo_tpu.utils import jaxcompat
+from analytics_zoo_tpu.ops.dispatch import on_tpu
 
 
 def _ring_local(q, k, v, *, axis_name: str, causal: bool,
                 scale: Optional[float]):
     """Per-shard body.  q/k/v: (B, H, T_local, D)."""
-    n = jaxcompat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
@@ -38,7 +38,7 @@ def _ring_local(q, k, v, *, axis_name: str, causal: bool,
     q_pos = idx * Tq + jnp.arange(Tq)
 
     # derive accumulators from q so they carry the same varying-axis type as the
-    # rotating k/v blocks (jax>=0.9 shard_map manual-axes typing)
+    # rotating k/v blocks (shard_map manual-axes typing)
     o0 = q32 * 0.0
     l0 = q32[..., 0] * 0.0
     m0 = q32[..., 0] * 0.0 - 1e30
@@ -79,7 +79,7 @@ def _ring_local_flash(q, k, v, *, axis_name: str, causal: bool,
     the flash backward as a delta shift)."""
     from analytics_zoo_tpu.ops.flash_attention import flash_attention_with_lse
 
-    n = jaxcompat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     s = scale if scale is not None else 1.0 / np.sqrt(q.shape[-1])
     NEG = jnp.float32(-1e30)
@@ -144,7 +144,7 @@ def ring_attention(q, k, v, mesh: Mesh, *, causal: bool = False,
         from analytics_zoo_tpu.ops.attention import _flash_worthwhile
         # same eligibility gates as the single-chip flash dispatch
         # (_select_flash): measured crossover AND the kernel's head-dim limit
-        impl = ("flash" if jax.default_backend() == "tpu"
+        impl = ("flash" if on_tpu()
                 and _flash_worthwhile(t_local) and q.shape[-1] <= 256
                 else "xla")
     if impl not in ("flash", "xla"):
@@ -152,7 +152,7 @@ def ring_attention(q, k, v, mesh: Mesh, *, causal: bool = False,
                          "(expected 'auto', 'flash', or 'xla')")
     body = (_ring_local_flash if impl == "flash" else _ring_local)
     spec = P(None, None, axis_name, None)
-    fn = jaxcompat.shard_map(
+    fn = jax.shard_map(
         functools.partial(body, axis_name=axis_name, causal=causal,
                           scale=scale),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,

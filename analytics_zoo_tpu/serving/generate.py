@@ -356,7 +356,8 @@ class ContinuousBatcher:
         self._exhausted_boundary = False
         if gen.paged:
             missing = [m for m in ("prefill_kv", "prefill_shared",
-                                   "decode_paged", "init_paged_pools")
+                                   "decode_paged", "init_paged_pools",
+                                   "n_head")
                        if not hasattr(inner, m)]
             if missing:
                 raise ValueError(
@@ -517,11 +518,16 @@ class ContinuousBatcher:
                     v = jnp.concatenate([v, z], axis=1)
                 kb = k.reshape(bb, npb, bl, nh, hd)
                 vb = v.reshape(bb, npb, bl, nh, hd)
+                # pool blocks fold (nh, hd) into one lane axis
+                # (ops/paged_attention layout)
+                fold = (bb, npb, bl, nh * hd)
                 if kq == "int8":
                     qk, sk = kv_pack_int8(kb)
                     qv, sv = kv_pack_int8(vb)
-                    out["k"][li] = out["k"][li].at[dest].set(qk)
-                    out["v"][li] = out["v"][li].at[dest].set(qv)
+                    out["k"][li] = out["k"][li].at[dest].set(
+                        qk.reshape(fold))
+                    out["v"][li] = out["v"][li].at[dest].set(
+                        qv.reshape(fold))
                     out["ks"][li] = out["ks"][li].at[dest].set(sk)
                     out["vs"][li] = out["vs"][li].at[dest].set(sv)
                     tk = jnp.take_along_axis(kb, tsel, axis=1)[:, 0]
@@ -531,8 +537,10 @@ class ContinuousBatcher:
                     out["stv"][li] = out["stv"][li].at[slots].set(
                         tv, mode="drop")
                 else:
-                    out["k"][li] = out["k"][li].at[dest].set(kb)
-                    out["v"][li] = out["v"][li].at[dest].set(vb)
+                    out["k"][li] = out["k"][li].at[dest].set(
+                        kb.reshape(fold))
+                    out["v"][li] = out["v"][li].at[dest].set(
+                        vb.reshape(fold))
             return out
 
         def pprefill(p, prompt, lengths, pools, dest, slots):
@@ -543,20 +551,23 @@ class ContinuousBatcher:
                     slots):
             npb = ptab.shape[1]
             bb = suffix.shape[0]
+            nh = inner.n_head
             pk, pv = [], []
             for li in range(len(pools["k"])):
-                k = jnp.take(pools["k"][li], ptab, axis=0)
-                v = jnp.take(pools["v"][li], ptab, axis=0)
+                # gather the prefix blocks and unfold their lane axis
+                k = jnp.take(pools["k"][li], ptab, axis=0) \
+                    .reshape(bb, npb, bl, nh, -1)
+                v = jnp.take(pools["v"][li], ptab, axis=0) \
+                    .reshape(bb, npb, bl, nh, -1)
                 if kq == "int8":
                     k = kv_unpack_int8(
                         k, jnp.take(pools["ks"][li], ptab, axis=0))
                     v = kv_unpack_int8(
                         v, jnp.take(pools["vs"][li], ptab, axis=0))
-                sh = k.shape
                 pk.append(k.astype(jnp.float32)
-                          .reshape(bb, npb * bl, *sh[3:]))
+                          .reshape(bb, npb * bl, nh, -1))
                 pv.append(v.astype(jnp.float32)
-                          .reshape(bb, npb * bl, *sh[3:]))
+                          .reshape(bb, npb * bl, nh, -1))
             ks, vs, logits0 = inner.prefill_shared(p, suffix, slens,
                                                    prefix_len, pk, pv)
             return commit(pools, ks, vs, slens, dest, slots), logits0
@@ -1457,6 +1468,7 @@ class ContinuousBatcher:
         before = aot.COMPILE_STATS.snapshot()
         t0 = time.monotonic()
         compiled = skipped = failed = 0
+        errors: List[str] = []
         stopped = False
         lanes = {lane.bucket: lane for lane in self._lanes}
         for i, entry in enumerate(manifest):
@@ -1468,14 +1480,16 @@ class ContinuousBatcher:
                 compiled += 1 if fresh else 0
                 skipped += 0 if fresh else 1
             except Exception as e:  # noqa: BLE001 — one bad entry must not
-                failed += 1         # strand the set; the live path compiles
-                logger.warning("generate: warm-up entry %s failed (%s: %s)",
-                               entry, type(e).__name__, e)
+                failed += 1         # strand the set; counted and reported
+                errors.append(aot.warm_error(entry, e))
+                logger.warning("generate: warm-up entry %s failed", entry,
+                               exc_info=True)
             if progress is not None:
                 progress(i + 1, len(manifest), entry)
         after = aot.COMPILE_STATS.snapshot()
         return {"programs": len(manifest), "compiled": compiled,
-                "skipped": skipped, "failed": failed, "stopped": stopped,
+                "skipped": skipped, "failed": failed, "errors": errors,
+                "stopped": stopped,
                 "seconds": round(time.monotonic() - t0, 3),
                 "compile_stats": {k: round(after[k] - before[k], 3)
                                   for k in after}}

@@ -74,9 +74,10 @@ config.yaml surface (scripts/cluster-serving/config.yaml template):
                                         # compile cache
       compile_cache_dir: null           # persistent XLA compilation cache
                                         # shared by every replica spawn:
-                                        # null = <pidfile>.xla_cache
-                                        # (created by the manager), a
-                                        # path pins it, "off" disables
+                                        # $JAX_COMPILATION_CACHE_DIR wins
+                                        # when set; else null = the fixed
+                                        # <checkout>/.jax_compile_cache,
+                                        # a path pins it, "off" disables
       trace_sample: 1.0                 # distributed tracing (PR 13):
                                         # head-sampling rate in [0, 1] —
                                         # the keep/drop verdict is a pure
@@ -144,6 +145,8 @@ CLI (used by scripts/cluster-serving/*.sh):
         # respawned, its orphaned in-flight records reclaimed by survivors.
         # Replica i gets pidfile <pidfile>.r<i> (+ its own health snapshot)
         # and params.http_port + i when a probe port is configured.
+        # On an accelerator host N > 1 is refused at once: a chip belongs
+        # to one process and every replica claims all local chips.
         [--autoscale]                  # PR 10: run the closed-loop
         # autoscaler in the supervisor — fleet signals from the per-replica
         # health docs, topology through the scale file (same path as
@@ -172,10 +175,10 @@ CLI (used by scripts/cluster-serving/*.sh):
         # health.json snapshot
     python -m analytics_zoo_tpu.serving.manager warmup [-c config.yaml]
         # zero cold start (PR 11): one throwaway pass that persists the
-        # deployment's warm state next to the pidfile — the mmap weight
-        # store (<pidfile>.weights, np.load(mmap_mode="r") at every
+        # deployment's warm state — the mmap weight store next to the
+        # pidfile (<pidfile>.weights, np.load(mmap_mode="r") at every
         # replica boot, page cache shared host-wide) and the persistent
-        # XLA compilation cache (<pidfile>.xla_cache) covering the whole
+        # XLA compilation cache (aot.compile_cache_dir) covering the whole
         # (bucket x scales-variant) program set.  `start --replicas` runs
         # this implicitly when params.warmup is set (skip: --no-prewarm);
         # every replica spawned after it — including autoscaler
@@ -349,7 +352,6 @@ def serve_from_config(config_path: str,
                       tensorboard_dir: Optional[str] = None,
                       replica_id: Optional[str] = None,
                       http_port_offset: int = 0,
-                      cache_dir: Optional[str] = None,
                       weight_store: Optional[str] = None,
                       model_version: Optional[str] = None) -> ClusterServing:
     cfg = load_config(config_path)
@@ -367,10 +369,6 @@ def serve_from_config(config_path: str,
         # replicas cannot share one probe port: replica i listens on
         # http_port + i (documented in the module docstring)
         params.http_port += http_port_offset
-    if cache_dir and not params.compile_cache_dir:
-        # the manager's per-deployment cache dir (PR 11); the engine
-        # enables it at start(), before any program compiles
-        params.compile_cache_dir = cache_dir
     serving = ClusterServing(load_model(cfg, weight_store=weight_store),
                              build_queue(cfg),
                              params=params,
@@ -402,13 +400,6 @@ def _knobs_path(pidfile: str) -> str:
 
 def _autoscaler_path(pidfile: str) -> str:
     return pidfile + ".autoscaler.json"
-
-
-def _cache_dir(pidfile: str) -> str:
-    """Per-deployment persistent XLA compilation cache (PR 11), created
-    by the manager and shared read/write across every replica spawn of
-    this deployment — the second replica of a topology never compiles."""
-    return pidfile + ".xla_cache"
 
 
 def _profiles_dir(pidfile: str) -> str:
@@ -462,14 +453,6 @@ def _jsonable(v):
     if isinstance(v, dict):
         return {str(k): _jsonable(x) for k, x in v.items()}
     return str(v)
-
-
-def _resolve_cache_dir(params: ServingParams, pidfile: str):
-    """`params.compile_cache_dir`: an explicit path wins, "off" disables,
-    unset defaults to the per-deployment dir next to the pidfile."""
-    if params.compile_cache_dir == "off":
-        return None
-    return params.compile_cache_dir or _cache_dir(pidfile)
 
 
 def _write_health(serving, path: str) -> None:
@@ -549,16 +532,13 @@ def _run_foreground(config_path: str, pidfile: str,
     with open(pidfile, "w") as f:
         f.write(str(os.getpid()))
     # zero cold start (PR 11): every replica of one deployment shares the
-    # BASE pidfile's compile cache + weight store (replica pidfiles are
-    # `<base>.rN`); the cache dir must be live before the model loads so
+    # BASE pidfile's weight store (replica pidfiles are `<base>.rN`) and
+    # one compile cache; the cache must be live before the model loads so
     # no compile escapes it
     base = base_pidfile or pidfile
     cfg0 = load_config(config_path)
-    params0 = serving_params(cfg0)
-    cache_dir = _resolve_cache_dir(params0, base)
-    if cache_dir:
-        from analytics_zoo_tpu.inference import aot
-        aot.enable_persistent_cache(cache_dir)
+    from analytics_zoo_tpu.inference import aot
+    aot.enable_persistent_cache(serving_params(cfg0).compile_cache_dir)
     # rollout (PR 16): a version-assigned replica loads the REGISTRY's
     # immutable snapshot for that version, integrity-verified first — a
     # corrupt version fails the spawn loudly instead of serving garbage
@@ -566,7 +546,6 @@ def _run_foreground(config_path: str, pidfile: str,
                     if model_version else _weights_dir(base))
     serving = serve_from_config(config_path, replica_id=replica_id,
                                 http_port_offset=http_port_offset,
-                                cache_dir=cache_dir,
                                 weight_store=weight_store,
                                 model_version=model_version)
     # on-demand profiling (PR 15): traces land next to the deployment's
@@ -657,6 +636,34 @@ def _run_foreground(config_path: str, pidfile: str,
         time.sleep(1)
 
 
+def _accelerator_host() -> Optional[dict]:
+    """``{"platform", "chips"}`` when this host's jax backend is an
+    accelerator, None on CPU.  An accelerator chip belongs to ONE process,
+    and a process that initialises the backend claims every local chip —
+    so an accelerator host runs one replica process (one replica pinned
+    per chip is ROADMAP reach item 8).  Probed in a child that has exited
+    before any replica starts: the supervisor must never initialise a
+    backend itself, it would hold the chips its replicas need.
+    ``JAX_PLATFORMS=cpu`` (the caller's explicit choice) skips the probe."""
+    import subprocess
+    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        return None
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import jax; d = jax.local_devices(); "
+         "print('DEVICES', d[0].platform, len(d))"],
+        capture_output=True, text=True, timeout=300)
+    words = (out.stdout or "").split()
+    if out.returncode != 0 or "DEVICES" not in words:
+        raise RuntimeError(
+            f"device probe failed (rc {out.returncode}): "
+            f"{(out.stderr or '')[-500:]}")
+    platform, chips = words[words.index("DEVICES") + 1:][:2]
+    if platform == "cpu":
+        return None
+    return {"platform": platform, "chips": int(chips)}
+
+
 def _prewarm(config_path: str, pidfile: str,
              timeout_s: float = 900.0,
              version: Optional[str] = None) -> Optional[dict]:
@@ -665,8 +672,10 @@ def _prewarm(config_path: str, pidfile: str,
     children fork clean) runs `manager warmup`, which exports the mmap
     weight store and populates the per-deployment XLA compilation cache.
     Every replica spawned afterwards — including every future autoscaler
-    scale-up — loads executables from disk instead of compiling.  Failure
-    is logged, not fatal: replicas fall back to compiling for themselves.
+    scale-up — loads executables from disk instead of compiling.  Returns
+    the warm-up document, or None on failure (reported on stderr): at
+    `start` that fails the deployment, during a rollout the replaced
+    replicas compile for themselves.
 
     With ``version`` (PR 16 rollout), the pass loads the REGISTRY
     snapshot for that version instead of re-exporting — run before the
@@ -707,7 +716,8 @@ def _prewarm(config_path: str, pidfile: str,
 def _run_supervisor(config_path: str, pidfile: str, replicas: int,
                     autoscale: bool = False,
                     lb_port: Optional[int] = None,
-                    prewarm: bool = True):
+                    prewarm: bool = True,
+                    replica_limit: Optional[int] = None):
     """Replica supervisor (PR 5 tentpole): fork one serving process per
     replica over the SHARED queue, monitor them, respawn crashed ones (a
     SIGKILLed replica's orphaned records are reclaimed by the survivors
@@ -723,7 +733,12 @@ def _run_supervisor(config_path: str, pidfile: str, replicas: int,
     controller metrics snapshotted to `<pidfile>.autoscaler.json` each
     pass.  With ``lb_port`` the single-port load-balancing front door
     (serving/lb.py) serves next to the supervisor, tracking membership as
-    the fleet resizes."""
+    the fleet resizes.
+
+    ``replica_limit`` caps the fleet on an accelerator host (see
+    ``_accelerator_host``): a `manager scale N` or autoscaler target above
+    it is refused with an event instead of forking replicas that can
+    never open the device and would respawn forever."""
     with open(pidfile, "w") as f:
         f.write(str(os.getpid()))
     scale_path = _scale_path(pidfile)
@@ -733,6 +748,7 @@ def _run_supervisor(config_path: str, pidfile: str, replicas: int,
     last_spawn: dict = {}                  # index -> monotonic ts (backoff)
     stopping: set = set()                  # indices already SIGTERMed
 
+    from analytics_zoo_tpu.inference import aot
     cfg = load_config(config_path)
     params = serving_params(cfg)
     # incident auto-capture (PR 15): config `incident:` section —
@@ -806,12 +822,25 @@ def _run_supervisor(config_path: str, pidfile: str, replicas: int,
     _save_rollout()
 
     if prewarm and params.warmup and \
-            _resolve_cache_dir(params, pidfile):
+            aot.compile_cache_dir(params.compile_cache_dir):
         # pre-populate the deployment's compile cache + weight store so
         # the replicas about to fork (and every scale-up after them) boot
         # warm.  The fleet takes traffic a few seconds later but each
-        # member reaches /readyz in seconds instead of a compile.
-        _prewarm(config_path, pidfile, version=rst.get("base"))
+        # member reaches /readyz in seconds instead of a compile.  The
+        # pass is a child that has EXITED before the first replica opens
+        # the device.  A warm-up set that cannot compile here cannot
+        # compile in the replicas either: fail the start, do not fork a
+        # fleet that will come up `degraded`.
+        if _prewarm(config_path, pidfile, version=rst.get("base")) is None:
+            for path in (pidfile, scale_path):
+                try:
+                    os.unlink(path)
+                except OSError:
+                    pass
+            raise SystemExit(
+                "manager start: the pre-warm pass failed (see the "
+                "'prewarm failed' event above); fix it or start with "
+                "--no-prewarm")
     scaler = None
     balancer = None
     if autoscale:
@@ -959,7 +988,7 @@ def _run_supervisor(config_path: str, pidfile: str, replicas: int,
                       file=sys.stderr, flush=True)
                 return
             if rparams.prewarm and params.warmup and \
-                    _resolve_cache_dir(params, pidfile):
+                    aot.compile_cache_dir(params.compile_cache_dir):
                 # pre-warm the new version's programs into the SHARED
                 # XLA cache before any replica is retired: every
                 # replaced replica then boots with zero steady-state
@@ -1161,6 +1190,18 @@ def _run_supervisor(config_path: str, pidfile: str, replicas: int,
                 desired = max(0, int(f.read().strip()))
         except (OSError, ValueError):
             desired = replicas
+        if replica_limit is not None and desired > replica_limit:
+            print(json.dumps({
+                "event": "scale refused", "requested": desired,
+                "limit": replica_limit,
+                "detail": "an accelerator chip belongs to one process; "
+                          "this host runs one replica"}),
+                file=sys.stderr, flush=True)
+            recorder.record("scale_refused", requested=desired,
+                            limit=replica_limit)
+            desired = replica_limit
+            with open(scale_path, "w") as f:
+                f.write(str(desired))
         # reap exits (crash -> respawn below; scale-down exit -> forget)
         for index, pid in list(children.items()):
             try:
@@ -1402,9 +1443,7 @@ def main(argv=None):
         from analytics_zoo_tpu.inference import aot, weightstore
         cfg = load_config(args.config)
         params = serving_params(cfg)
-        cache_dir = _resolve_cache_dir(params, args.pidfile)
-        if cache_dir:
-            aot.enable_persistent_cache(cache_dir)
+        cache_dir = aot.enable_persistent_cache(params.compile_cache_dir)
         if args.version:
             # rollout pre-warm (PR 16): warm the REGISTRY snapshot for
             # this version into the shared compile cache — verified
@@ -1989,17 +2028,29 @@ def main(argv=None):
                                        "file:<dir>), not inproc"}),
                   file=sys.stderr)
             return 1
+        host = _accelerator_host()
+        if host is not None and args.replicas > 1:
+            print(json.dumps({
+                "error": f"--replicas {args.replicas}: this host has "
+                         f"{host['chips']} {host['platform']} chip(s), and "
+                         "a chip belongs to one process — every replica "
+                         "process claims all local chips, so the second "
+                         "one cannot open the device.  Start one replica "
+                         "here (one replica pinned per chip is not "
+                         "implemented)"}), file=sys.stderr)
+            return 1
+        sup_kw = dict(autoscale=args.autoscale, lb_port=args.lb_port,
+                      prewarm=not args.no_prewarm,
+                      replica_limit=None if host is None else 1)
         if args.foreground:
             _run_supervisor(args.config, args.pidfile, args.replicas,
-                            autoscale=args.autoscale, lb_port=args.lb_port,
-                            prewarm=not args.no_prewarm)
+                            **sup_kw)
             return 0
         pid = os.fork()
         if pid == 0:                       # child: detach and supervise
             os.setsid()
             _run_supervisor(args.config, args.pidfile, args.replicas,
-                            autoscale=args.autoscale, lb_port=args.lb_port,
-                            prewarm=not args.no_prewarm)
+                            **sup_kw)
             return 0
         print(json.dumps({"started": True, "pid": pid,
                           "replicas": args.replicas}))

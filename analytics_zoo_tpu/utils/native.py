@@ -2,13 +2,16 @@
 
 Reference parity: the reference ships native code as external `zoo-core` artifacts
 loaded through JNI stubs (SURVEY.md §2.9).  Here the native library builds on demand
-from csrc/ with g++ (cached in build/) and binds through ctypes — no JNI, no pybind11.
+from csrc/ with g++ (kept in build/ under a name derived from the source's content) and
+binds through ctypes — no JNI, no pybind11.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import platform
 import subprocess
 import threading
 from typing import Optional
@@ -24,16 +27,32 @@ _lib = None
 _lib_lock = threading.Lock()
 
 
+_CXX = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC"]
+
+
 def _build_library() -> str:
+    """Build (or reuse) the library for the source AS IT IS NOW.  `build/`
+    is git-ignored and travels with a copied tree, so a binary found there
+    is trusted only through its name: the hash of the source bytes, the
+    compile command and the machine type.  An mtime cannot tell a fresh
+    binary from one built elsewhere from other source."""
     os.makedirs(_BUILD, exist_ok=True)
     src = os.path.join(_CSRC, "sample_store.cpp")
-    out = os.path.join(_BUILD, "libsamplestore.so")
-    if (os.path.exists(out)
-            and os.path.getmtime(out) >= os.path.getmtime(src)):
+    h = hashlib.sha256()
+    with open(src, "rb") as f:
+        h.update(f.read())
+    h.update(" ".join(_CXX + [platform.machine()]).encode())
+    out = os.path.join(_BUILD, f"libsamplestore-{h.hexdigest()[:16]}.so")
+    if os.path.exists(out):
         return out
-    cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-o", out, src,
-           "-lpthread"]
-    subprocess.run(cmd, check=True, capture_output=True)
+    tmp = f"{out}.{os.getpid()}.tmp"
+    try:
+        subprocess.run(_CXX + ["-o", tmp, src, "-lpthread"], check=True,
+                       capture_output=True)
+        os.replace(tmp, out)      # atomic: concurrent builders both win
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
     return out
 
 

@@ -211,6 +211,12 @@ def _pool_case(seed, A, n_table, bl, nh, hd, lengths):
     return q, kc, vc, kp, vp, tables, np.asarray(lengths, np.int32)
 
 
+def _fold(pool):
+    """(n_blocks, bl, nh, hd) -> the pool layout (n_blocks, bl, nh*hd)."""
+    pool = np.asarray(pool)
+    return pool.reshape(pool.shape[:2] + (-1,))
+
+
 def _ref_attention(q, kc, vc, lengths):
     hd = q.shape[-1]
     s = np.einsum("ahd,athd->aht", q, kc) / np.sqrt(hd)
@@ -226,7 +232,8 @@ def _ref_attention(q, kc, vc, lengths):
 def test_paged_attention_xla_matches_reference(lengths):
     from analytics_zoo_tpu.ops.paged_attention import paged_attention_xla
     q, kc, vc, kp, vp, tables, lens = _pool_case(0, 4, 4, 8, 2, 8, lengths)
-    out = np.asarray(paged_attention_xla(q, kp, vp, tables, lens))
+    out = np.asarray(paged_attention_xla(q, _fold(kp), _fold(vp), tables,
+                                         lens))
     np.testing.assert_allclose(out, _ref_attention(q, kc, vc, lens),
                                rtol=2e-5, atol=2e-5)
 
@@ -237,6 +244,7 @@ def test_paged_attention_kernel_parity_float(lengths):
     ``impl="auto"`` dispatch contract from quant_matmul, paged."""
     from analytics_zoo_tpu.ops.paged_attention import paged_attention
     q, _, _, kp, vp, tables, lens = _pool_case(1, 4, 4, 8, 2, 8, lengths)
+    kp, vp = _fold(kp), _fold(vp)
     oracle = np.asarray(paged_attention(q, kp, vp, tables, lens,
                                         impl="xla"))
     kern = np.asarray(paged_attention(q, kp, vp, tables, lens,
@@ -252,6 +260,7 @@ def test_paged_attention_kernel_parity_int8(lengths):
     q, _, _, kp, vp, tables, lens = _pool_case(2, 4, 4, 8, 2, 8, lengths)
     qk, ks = kv_pack_int8(kp)
     qv, vs = kv_pack_int8(vp)
+    qk, qv = _fold(qk), _fold(qv)
     oracle = np.asarray(paged_attention_xla(q, qk, qv, tables, lens,
                                             k_scale=ks, v_scale=vs))
     kern = np.asarray(paged_attention(q, qk, qv, tables, lens,
@@ -259,7 +268,8 @@ def test_paged_attention_kernel_parity_int8(lengths):
                                       impl="interpret"))
     np.testing.assert_allclose(kern, oracle, rtol=2e-5, atol=2e-5)
     # the quantization itself stays close to the float answer
-    flt = np.asarray(paged_attention_xla(q, kp, vp, tables, lens))
+    flt = np.asarray(paged_attention_xla(q, _fold(kp), _fold(vp), tables,
+                                         lens))
     np.testing.assert_allclose(oracle, flt, atol=0.15)
 
 

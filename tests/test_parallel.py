@@ -94,9 +94,8 @@ def test_ring_attention_mixed_mesh():
     spec = NamedSharding(mesh, P("data", None, "seq", None))
     qs, ks, vs = (jax.device_put(t, spec) for t in (q, k, v))
     from analytics_zoo_tpu.parallel.ring_attention import _ring_local
-    from analytics_zoo_tpu.utils import jaxcompat
     import functools
-    fn = jaxcompat.shard_map(
+    fn = jax.shard_map(
         functools.partial(_ring_local, axis_name="seq", causal=True, scale=None),
         mesh=mesh,
         in_specs=(P("data", None, "seq", None),) * 3,

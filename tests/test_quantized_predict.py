@@ -84,17 +84,21 @@ def test_w4a16_kernel_vs_oracle_tolerance(rng):
         np.testing.assert_allclose(ker, ref, rtol=1e-5, atol=1e-4)
 
 
-def test_w4a16_unaligned_falls_back_to_oracle(rng):
+def test_w4a16_unaligned_is_the_oracle_only_under_auto(rng):
     # ragged groups / odd K are outside the kernel's alignment contract:
-    # the public entry point silently serves the XLA reference instead
+    # impl="auto" computes such a shape through the XLA reference (the
+    # shape is the caller's to see); an explicit kernel request raises
+    # instead of being demoted behind the caller's back
     k, n = 100, 8
     q = rng.integers(-7, 8, (k, n)).astype(np.int8)
     packed = qm.pack_int4(q)
     s_g = np.full((3, n), 0.1, np.float32)          # gs=34: ragged
     x = rng.standard_normal((4, k)).astype(np.float32)
-    out = np.asarray(qm.w4a16_matmul(x, packed, s_g, impl="interpret"))
+    out = np.asarray(qm.w4a16_matmul(x, packed, s_g))
     ref = np.asarray(qm.w4a16_matmul_xla(x, packed, s_g))
     assert np.array_equal(out, ref)
+    with pytest.raises(ValueError, match="w4a16 kernel needs"):
+        qm.w4a16_matmul(x, packed, s_g, impl="interpret")
 
 
 def test_w8a8_pointwise_conv_routes_through_matmul_kernel(rng):
@@ -147,16 +151,19 @@ def test_pointwise_conv_with_explicit_padding_stays_on_conv_path(rng):
 def test_w4a16_ragged_group_division_falls_back(rng):
     """Review regression: group counts that do not divide K exactly
     (floor-vs-ceil group size ambiguity) are OUTSIDE the kernel contract
-    and must serve through the XLA reference, never mis-slice silently."""
+    and must never mis-slice silently: auto computes them through the XLA
+    reference, an explicit kernel request raises."""
     k, n, g = 2048, 8, 66                    # ceil gs 32 but floor gs 31
     assert not qm._w4_pallas_ok(k, g)
     q = rng.integers(-7, 8, (k, n)).astype(np.int8)
     packed = qm.pack_int4(q)
     s_g = (rng.random((g, n)).astype(np.float32) + 0.05) * 0.1
     x = rng.standard_normal((3, k)).astype(np.float32)
-    out = np.asarray(qm.w4a16_matmul(x, packed, s_g, impl="interpret"))
+    out = np.asarray(qm.w4a16_matmul(x, packed, s_g))
     ref = np.asarray(qm.w4a16_matmul_xla(x, packed, s_g))
     assert np.array_equal(out, ref)
+    with pytest.raises(ValueError, match="w4a16 kernel needs"):
+        qm.w4a16_matmul(x, packed, s_g, impl="interpret")
 
 
 # -- calibration: path keying (collision fix), percentile, FeatureSet ----------

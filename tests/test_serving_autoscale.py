@@ -750,8 +750,7 @@ def test_chaos_swing_ab_autoscale_on_holds_slo(tmp_path, ctx):
     subprocess over the shared FileQueue spool), autoscale-on holds the
     stated e2e p99 SLO, loses zero records, replaces the dead replica and
     scales the fleet; autoscale-off at the initial fleet size violates the
-    SLO by a wide margin.  The full protocol + recorded numbers live in
-    RUNLOG_serving.md."""
+    SLO by a wide margin."""
     sys.path.insert(0, os.path.join(REPO, "tools"))
     import serving_bench
 

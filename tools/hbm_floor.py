@@ -130,8 +130,8 @@ def main():
 
     floor_ms = conv_ms + mem_ms
     peak = peak_flops(jax.devices()[0])
-    floor_mfu = flops / (floor_ms / 1e3) / peak if peak else 0.0
-    meas_mfu = flops / (args.measured_step_ms / 1e3) / peak if peak else 0.0
+    floor_mfu = flops / (floor_ms / 1e3) / peak
+    meas_mfu = flops / (args.measured_step_ms / 1e3) / peak
 
     print(json.dumps({
         "stream_triad_gbps": round(bw_triad / 1e9, 1),

@@ -46,7 +46,7 @@ def time_matmul(m, k, n, dtype, trials):
 
         def run(it, trial=0):
             # trial-perturbed weights: no two timing dispatches are
-            # byte-identical (the relay must not serve cached replies)
+            # byte-identical
             float(loop(x, w + jnp.int8(trial % 2), it))
     else:
         x = jnp.asarray(rng.normal(size=(m, k)), jnp.bfloat16)
@@ -56,7 +56,7 @@ def time_matmul(m, k, n, dtype, trials):
             float(loop(x, w + jnp.bfloat16(trial * 1e-8), it))
 
     fl = 2.0 * m * k * n
-    # (5n-n) window must rise above relay jitter (conv_ceiling sizing rule)
+    # (5n-n) window must rise above host jitter (conv_ceiling sizing rule)
     n_lo = max(8, int(25e12 / fl))
     return _rate_two_point(run, fl, trials, n_lo) / 1e12
 
