@@ -53,12 +53,19 @@ process was DOING around a crash or SLO burn, not just where time went.
 ``process_stats()`` is the per-process resource read (RSS, CPU seconds,
 open FDs, thread count) the health doc and prom exposition carry.
 
+``PhaseClock`` (PR 25) partitions one thread's wall time into named phases
+(the generate loop's cycle: idle / intake / admit / dispatch / decode_wait
+/ fold / ...): cumulative seconds per phase for ``/healthz`` and the
+registry, and one profiler annotation per phase so a device trace shows
+what the host was doing in every gap.
+
 Pure stdlib + numpy-free: safe to import from the client, the queues, and
 the trainer without dragging in jax.
 """
 
 from __future__ import annotations
 
+import contextlib
 import json
 import math
 import os
@@ -979,6 +986,83 @@ class SpanTimer:
         self._tracer.span(self.stage, self._t0, self._clock(),
                           trace_id=self.trace_id, uri=self.uri, error=err)
         return False
+
+
+# -- phase clock (PR 25) --------------------------------------------------------
+
+class PhaseClock:
+    """A partition of ONE thread's wall time into named phases.
+
+    A switch, not a nest: ``to(name)`` reads the clock once, charges the
+    time since the last switch to the phase that was current and makes
+    ``name`` current, so there is always exactly one current phase and the
+    phases' seconds sum to the time since construction — no gap, no double
+    count.  ``with clock.phase(name):`` is the same switch that returns to
+    the enclosing phase on the way out (exceptions included).
+
+    ``annotate`` is a context-manager factory taking a span name
+    (``jax.profiler.TraceAnnotation``, injected so this module stays
+    stdlib-only; ``None`` = no annotation): every phase is opened as
+    ``<prefix><name>``, so a profiler trace shows the phases on the owning
+    thread's line, on the device's clock.  With no trace active an
+    annotation is a flag test.
+
+    Only the owning thread switches.  ``totals()`` may be called from any
+    thread while the owner runs: it takes no lock (a reader that meets a
+    switch in progress reads again) and includes the open phase up to now.
+    """
+
+    def __init__(self, phases: Sequence[str], annotate=None,
+                 prefix: str = "", clock: Callable[[], float] = time.monotonic):
+        self.phases = tuple(phases)
+        self._names = {p: prefix + p for p in self.phases}
+        self._annotate = annotate
+        self._clock = clock
+        self._seconds = {p: 0.0 for p in self.phases}
+        self._counts = {p: 0 for p in self.phases}
+        # the owner may be another thread than the constructor, so the
+        # first phase gets no annotation: one is opened at the first switch
+        self._span = None
+        self._open = (self.phases[0], clock())     # (current phase, since)
+
+    @property
+    def current(self) -> str:
+        return self._open[0]
+
+    def to(self, name: str) -> None:
+        label = self._names[name]      # an unknown phase fails here, at once
+        prev, since = self._open
+        now = self._clock()
+        self._open = None              # a switch is in progress (totals)
+        self._seconds[prev] += now - since
+        self._counts[prev] += 1
+        self._open = (name, now)
+        if self._annotate is not None:
+            if self._span is not None:
+                self._span.__exit__(None, None, None)
+            self._span = self._annotate(label)
+            self._span.__enter__()
+
+    @contextlib.contextmanager
+    def phase(self, name: str):
+        outer = self._open[0]
+        self.to(name)
+        try:
+            yield
+        finally:
+            self.to(outer)
+
+    def totals(self) -> Tuple[Dict[str, float], Dict[str, int]]:
+        """``(seconds, counts)`` by phase: ``counts`` are completed visits,
+        ``seconds`` include the open phase up to now, so their sum is the
+        time since construction."""
+        while True:
+            open_ = self._open
+            seconds, counts = dict(self._seconds), dict(self._counts)
+            if open_ is not None and self._open is open_:
+                break
+        seconds[open_[0]] += self._clock() - open_[1]
+        return seconds, counts
 
 
 # -- SLO attribution (PR 13) ---------------------------------------------------

@@ -976,6 +976,19 @@ class ClusterServing:
                 (reg.gauge("serving_active_slots",
                            "Decode slots currently serving a request",
                            fn=slots_fn), slots_fn))
+            # where the generate loop's time goes (PR 25): the batcher's
+            # phase clock read at scrape time, no second accumulation
+            phase_g = reg.gauge(
+                "serving_generate_phase_seconds_total",
+                "Wall time of the generate thread by phase of its cycle "
+                "(the phases partition it; the device is busy in "
+                "prefill_wait and decode_wait)", labels=("phase",))
+            for name in self._batcher.clock.phases:
+                fn = (lambda n=name, b=self._batcher:
+                      b.clock.totals()[0][n])
+                child = phase_g.labels(phase=name)
+                child.add_function(fn)
+                self._gauge_fns.append((child, fn))
             self._last_steps = 0
             self._tps_window = (time.monotonic(), 0)   # (t0, tokens0)
             # generation continuity (PR 20): where checkpoints spool
@@ -2298,11 +2311,14 @@ class ClusterServing:
             # generation mode: run the scheduler to quiescence — reads one
             # micro-batch, then steps until every admitted request reached
             # a terminal state (tests and embedded callers)
+            clock = self._batcher.clock
+            clock.to("intake")
             for group in staged or ():
                 self._submit_group(group)
             before = self.total_records
             while not self._batcher.idle and not self._stop.is_set():
                 self._gen_tick()
+            clock.to("idle")
             return self.total_records - before
         if not staged:
             return 0
@@ -2642,6 +2658,7 @@ class ClusterServing:
                 # emitted while the waiting room blocks are still charged
                 # to their tenants at the step boundary
                 self._gen_tick()
+                self._batcher.clock.to("intake")
 
     def _gen_tick(self) -> None:
         """One decode-step boundary + its bookkeeping (stage timer,
@@ -2650,8 +2667,12 @@ class ClusterServing:
         b = self._batcher
         t0 = time.monotonic()
         events = b.step()
+        b.clock.to("bookkeep")
         now = time.monotonic()
         if b.active or events:
+            # step()'s whole wall (admission, prefill, decode and fold in
+            # one number; the phase clock splits it): the autoscaler's
+            # predict_p99_ms reads it for the generate plane too
             self._stages["predict"].record(now - t0)
         # per-boundary decode spans (PR 13): one span per request per
         # boundary, carrying tokens-emitted — the spans TTFT decomposes
@@ -2763,19 +2784,25 @@ class ClusterServing:
         finish -> batched result write + ack (+ e2e/cold-start stamps),
         partial -> best-effort streaming overwrite, shed -> terminal
         deadline marker, quarantine -> dead-letter, first_token -> TTFT."""
+        self._batcher.clock.to("flush")
         pairs: List[Tuple[str, Dict]] = []
         finals = []
         for ev in events:
+            if ev.t_out is not None:
+                # first output event of the request (partial or finish):
+                # the wait for stream_interval tokens after the first one
+                self._span("first_out", ev.t_first, ev.t_out,
+                           trace_id=ev.trace_id, uri=ev.rid)
             if ev.kind == "first_token":
-                if ev.ttft_s is not None:
-                    self._m_ttft.record(ev.ttft_s)
-                    # prefill span (PR 13): scheduler admission wait +
-                    # prefill program, ending at the first token — the
-                    # hop between queue_wait and the first decode
-                    # boundary in the TTFT decomposition
-                    now0 = time.monotonic()
-                    self._span("prefill", now0 - ev.ttft_s, now0,
-                               trace_id=ev.trace_id, uri=ev.rid)
+                self._m_ttft.record(ev.ttft_s)
+                # the scheduler's own stamps (PR 25): waiting room, then
+                # batch assembly + prefill program — the two hops
+                # between queue_wait and the first decode boundary in
+                # the TTFT decomposition; they sum to ttft_s
+                self._span("sched_wait", ev.t_first - ev.ttft_s,
+                           ev.t_admit, trace_id=ev.trace_id, uri=ev.rid)
+                self._span("prefill", ev.t_admit, ev.t_first,
+                           trace_id=ev.trace_id, uri=ev.rid)
             elif ev.kind == "partial":
                 if self._brownout is not None \
                         and self._brownout.suppress_partials:
@@ -2888,8 +2915,11 @@ class ClusterServing:
             # what is already staged, then take the next decode step
             try:
                 if b.idle:
+                    b.clock.to("idle")
                     group = self._staged.get(timeout=0.1)
+                    b.clock.to("intake")      # a timeout stays idle
                 else:
+                    b.clock.to("intake")
                     group = self._staged.get_nowait()
             except _q.Empty:
                 group = None

@@ -36,6 +36,12 @@ models/textmodels.py):
   failure) quarantines ITS SLOT only: rows are independent in every lane
   program, so neighbours' outputs are bitwise identical with or without
   the poison.
+- **its own clock** (PR 25) — the generate thread's wall time is
+  partitioned into ``PHASES`` by one ``PhaseClock`` (``self.clock``, shared
+  with the engine's generate loop: loop and scheduler are one thread and
+  one cycle), and every request is stamped where each step of its time to
+  first token happens (submit -> admit -> first token -> first output).
+  ``stats()`` carries both as cumulative sums.
 """
 
 from __future__ import annotations
@@ -49,7 +55,16 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from analytics_zoo_tpu.common.observability import PhaseClock
+
 logger = logging.getLogger(__name__)
+
+# The generate thread's cycle, in the order a boundary runs it.  The engine
+# owns idle / intake / bookkeep / flush, the scheduler the rest; the device
+# is busy in the two *_wait phases (and from the dispatch on in admit).
+PHASES = ("idle", "intake", "shed", "admit", "prefill_wait", "dispatch",
+          "decode_wait", "fold", "bookkeep", "flush")
+PHASE_SPAN_PREFIX = "zoo.gen."       # a phase's name on a profiler trace
 
 
 def _pow2_ceil(n: int) -> int:
@@ -203,8 +218,8 @@ class GenRequest:
     """One admitted generation request (engine-internal)."""
 
     __slots__ = ("rid", "prompt", "deadline_ns", "trace_id", "t_read",
-                 "max_tokens", "t_submit", "tenant", "resume_tokens",
-                 "epoch")
+                 "max_tokens", "t_submit", "t_admit", "tenant",
+                 "resume_tokens", "epoch")
 
     def __init__(self, rid: str, prompt: np.ndarray,
                  deadline_ns: Optional[int] = None,
@@ -227,6 +242,8 @@ class GenRequest:
         self.resume_tokens = resume_tokens
         self.epoch = int(epoch)
         self.t_submit = time.monotonic()
+        # popped from the waiting room by the admission that kept it
+        self.t_admit: Optional[float] = None
 
 
 @dataclass
@@ -250,16 +267,23 @@ class GenEvent:
     t_read: Optional[float] = None
     wall_s: Optional[float] = None
     tenant: Optional[str] = None       # attribution (PR 19)
+    # the TTFT chain's stamps (monotonic): ``first_token`` carries t_admit
+    # and t_first, the request's FIRST output event (partial or finish)
+    # t_first and t_out
+    t_admit: Optional[float] = None
+    t_first: Optional[float] = None
+    t_out: Optional[float] = None
 
 
 class _Slot:
-    __slots__ = ("req", "generated", "t_first", "last_stream", "budget",
-                 "ckpt_mark")
+    __slots__ = ("req", "generated", "t_first", "t_out", "last_stream",
+                 "budget", "ckpt_mark")
 
     def __init__(self, req: GenRequest, budget: int):
         self.req = req
         self.generated: List[int] = []
         self.t_first: Optional[float] = None
+        self.t_out: Optional[float] = None     # first partial/finish event
         self.last_stream = 0
         self.budget = budget
         # tokens-generated count at the last checkpoint (PR 20)
@@ -398,6 +422,7 @@ class ContinuousBatcher:
         self._exec_counts: Dict[str, int] = {}
         self.compiles = 0
         self.decode_steps = 0
+        self.boundaries = 0          # calls of a decode program
         self.generated_tokens = 0
         self.admitted = 0
         self.finished = 0
@@ -412,6 +437,24 @@ class ContinuousBatcher:
         self.checkpoints = 0
         self.snapshot_bytes = 0
         self.pending_checkpoints: List[Dict] = []
+        # the generate thread's phase clock (PR 25): step()/_admit* switch
+        # it, the engine's loop switches it around them; warm() runs on
+        # another thread and never touches it
+        import jax
+        self.clock = PhaseClock(PHASES, annotate=jax.profiler.TraceAnnotation,
+                                prefix=PHASE_SPAN_PREFIX)
+        # the TTFT chain (PR 25), cumulative seconds and counts, each added
+        # where its interval ends: read -> submit (engine intake), submit ->
+        # admit (waiting room), admit -> first token (batch assembly +
+        # prefill), first token -> first output event (stream_interval)
+        self.intake_s_sum = self.queue_wait_s_sum = 0.0
+        self.prefill_s_sum = self.first_out_s_sum = 0.0
+        self.intake_n = self.queue_wait_n = 0
+        self.prefill_n = self.first_out_n = 0
+        # prefill work, useful over attempted: prompt positions asked for
+        # vs positions the (batch bucket x prompt bucket) programs computed
+        self.prefill_positions_real = 0
+        self.prefill_positions_padded = 0
         # COMPILE_STATS listeners: steady-state zero-compile evidence
         from analytics_zoo_tpu.inference import aot
         aot.install_compile_listeners()
@@ -684,6 +727,9 @@ class ContinuousBatcher:
             if len(self._waiting) >= self.MAX_WAITING:
                 return False
             self._waiting.append(req)
+            if req.t_read is not None:
+                self.intake_s_sum += req.t_submit - req.t_read
+                self.intake_n += 1
             return True
 
     @property
@@ -860,6 +906,8 @@ class ContinuousBatcher:
         for j in range(n, bb):
             padded[j] = padded[0]
             lengths[j] = lengths[0]
+        self.prefill_positions_real += int(lengths[:n].sum())
+        self.prefill_positions_padded += bb * pb
         prefill, _, insert = self._lane_fns(lane)
         try:
             self._ensure_lane_state(lane)
@@ -869,10 +917,12 @@ class ContinuousBatcher:
             self._count_exec(("prefill", bb, pb, lane.bucket))
             if self._is_pair(res):
                 sub, logits0 = res
+                with self.clock.phase("prefill_wait"):
+                    logits0 = np.asarray(logits0)
                 # host-side argmax (matches the paged path): an eager
                 # jnp.argmax would XLA-compile once per batch bucket —
                 # a steady-state compile the admission path must not pay
-                toks0 = np.asarray(logits0).argmax(axis=-1)
+                toks0 = logits0.argmax(axis=-1)
             else:
                 sub, toks0 = res, None
             ins = self._compiled(("insert", bb, lane.bucket), insert,
@@ -913,11 +963,7 @@ class ContinuousBatcher:
             if toks0 is not None:
                 # cache models emit their first token AT prefill: TTFT
                 # stops here, and the token feeds the first decode step
-                info.t_first = time.monotonic()
-                events.append(GenEvent(
-                    "first_token", req.rid, trace_id=req.trace_id,
-                    ttft_s=info.t_first - req.t_submit,
-                    t_read=req.t_read, tenant=req.tenant))
+                self._first_token(info, time.monotonic(), events)
                 lane.tokens[slot] = int(toks0[j])
                 self._account_token(lane, slot, info, int(toks0[j]),
                                     events)
@@ -989,6 +1035,7 @@ class ContinuousBatcher:
                 req = self._waiting.popleft() if self._waiting else None
             if req is None:
                 break
+            req.t_admit = time.monotonic()   # a requeue stamps it again
             if self._expired(req.deadline_ns):
                 self.shed += 1
                 events.append(GenEvent(
@@ -1101,6 +1148,8 @@ class ContinuousBatcher:
             if shared is not None:
                 ptab[j] = ptab[0]
                 plens[j] = plens[0]
+        self.prefill_positions_real += int(lengths[:n].sum())
+        self.prefill_positions_padded += bb * pb
         pprefill, pshared, _ = self._paged_fns()
         try:
             self._ensure_lane_state(lane)
@@ -1120,7 +1169,9 @@ class ContinuousBatcher:
                                           plens, ptab, lane.state, dest,
                                           slots_arr)
             self._count_exec(key)
-            toks0 = np.asarray(logits0).argmax(axis=-1)
+            with self.clock.phase("prefill_wait"):
+                logits0 = np.asarray(logits0)
+            toks0 = logits0.argmax(axis=-1)
         except Exception as e:  # noqa: BLE001 — batch-level failure
             if n == 1:
                 req, slot, resv = members[0]
@@ -1158,11 +1209,7 @@ class ContinuousBatcher:
                     prompt = self._concat_prompt(req)
                     self._prefix.register(prompt[:full * bl],
                                           table[:full])
-            info.t_first = time.monotonic()
-            events.append(GenEvent(
-                "first_token", req.rid, trace_id=req.trace_id,
-                ttft_s=info.t_first - req.t_submit, t_read=req.t_read,
-                tenant=req.tenant))
+            self._first_token(info, time.monotonic(), events)
             lane.tokens[slot] = int(toks0[j])
             self._account_token(lane, slot, info, int(toks0[j]), events)
         return admitted
@@ -1179,6 +1226,7 @@ class ContinuousBatcher:
                 req = self._waiting.popleft() if self._waiting else None
             if req is None:
                 break
+            req.t_admit = time.monotonic()   # a requeue stamps it again
             if self._expired(req.deadline_ns):
                 self.shed += 1
                 events.append(GenEvent(
@@ -1271,17 +1319,45 @@ class ContinuousBatcher:
         lane.slots[slot] = None
         lane.free.append(slot)
 
+    def _first_token(self, info: _Slot, now: float,
+                     events: List[GenEvent]) -> None:
+        """Stamp a request's first token: the ``first_token`` event (TTFT
+        = submit -> now) and the two links of the chain that end here,
+        which sum to that TTFT exactly."""
+        req = info.req
+        info.t_first = now
+        self.queue_wait_s_sum += req.t_admit - req.t_submit
+        self.queue_wait_n += 1
+        self.prefill_s_sum += now - req.t_admit
+        self.prefill_n += 1
+        events.append(GenEvent(
+            "first_token", req.rid, trace_id=req.trace_id,
+            ttft_s=now - req.t_submit, t_read=req.t_read, tenant=req.tenant,
+            t_admit=req.t_admit, t_first=now))
+
+    def _first_output(self, info: _Slot, now: float, ev: GenEvent) -> None:
+        """``ev`` is the first event of this request a client can see
+        (partial or finish): close the chain's last link on it."""
+        info.t_out = now
+        if info.t_first is not None:
+            self.first_out_s_sum += now - info.t_first
+            self.first_out_n += 1
+            ev.t_first, ev.t_out = info.t_first, now
+
     def _finish(self, lane: _Lane, slot: int, info: _Slot, reason: str,
                 events: List[GenEvent]) -> None:
         self.finished += 1
         now = time.monotonic()
-        events.append(GenEvent(
+        ev = GenEvent(
             "finish", info.req.rid, trace_id=info.req.trace_id,
             tokens=list(info.generated), finish_reason=reason,
             ttft_s=(info.t_first - info.req.t_submit
                     if info.t_first is not None else None),
             t_read=info.req.t_read, wall_s=now - info.req.t_submit,
-            tenant=info.req.tenant))
+            tenant=info.req.tenant)
+        if info.t_out is None:
+            self._first_output(info, now, ev)
+        events.append(ev)
         self._free(lane, slot)
 
     def _account_token(self, lane: _Lane, slot: int, info: _Slot,
@@ -1301,10 +1377,13 @@ class ContinuousBatcher:
         si = self.gen.stream_interval
         if si and len(info.generated) - info.last_stream >= si:
             info.last_stream = len(info.generated)
-            events.append(GenEvent(
+            ev = GenEvent(
                 "partial", info.req.rid, trace_id=info.req.trace_id,
                 tokens=list(info.generated), t_read=info.req.t_read,
-                tenant=info.req.tenant))
+                tenant=info.req.tenant)
+            if info.t_out is None:
+                self._first_output(info, time.monotonic(), ev)
+            events.append(ev)
 
     def _shed_active(self, events: List[GenEvent]) -> None:
         for lane in self._lanes:
@@ -1322,15 +1401,21 @@ class ContinuousBatcher:
         """One decode-step boundary: shed expired, admit into free slots,
         run one token step per non-empty lane, fold the emitted tokens.
         Returns the events the engine must act on; an idle scheduler
-        returns [] without touching the device."""
+        returns [] without touching the device.  Leaves the clock in the
+        last phase it ran (``fold`` after a decode, else ``admit``): the
+        caller switches it on."""
         events: List[GenEvent] = []
+        clock = self.clock
+        clock.to("shed")
         self.last_boundary = []
         self._exhausted_boundary = False
         self._shed_active(events)
+        clock.to("admit")
         self.last_admitted = self._admit(events)
         for lane in self._lanes:
             if lane.active == 0:
                 continue
+            clock.to("dispatch")
             tokens = lane.tokens
             if isinstance(lane, _PagedLane):
                 _, _, pdecode = self._paged_fns()
@@ -1341,7 +1426,9 @@ class ContinuousBatcher:
                 block, lane.state = exe(self._params(), lane.state,
                                         lane.tables, lane.pos, tokens)
                 self._count_exec(key)
+                clock.to("decode_wait")
                 block = np.asarray(block)
+                clock.to("fold")
                 # host cursors advance with the in-scan carry; idle rows
                 # clamp at lane capacity (their writes target the trash
                 # block regardless).  MUST run before the token fold —
@@ -1357,19 +1444,17 @@ class ContinuousBatcher:
                 block, lane.state = exe(self._params(), lane.state,
                                         tokens)
                 self._count_exec(key)
+                clock.to("decode_wait")
                 block = np.asarray(block)      # (decode_quantum, A)
+                clock.to("fold")
+            self.boundaries += 1
             self.decode_steps += int(block.shape[0])   # token-level steps
             now = time.monotonic()
             for slot, info in enumerate(lane.slots):
                 if info is None:
                     continue
                 if info.t_first is None:
-                    info.t_first = now
-                    events.append(GenEvent(
-                        "first_token", info.req.rid,
-                        trace_id=info.req.trace_id,
-                        ttft_s=info.t_first - info.req.t_submit,
-                        t_read=info.req.t_read, tenant=info.req.tenant))
+                    self._first_token(info, now, events)
                 n0 = len(info.generated)
                 for k in range(block.shape[0]):
                     self._account_token(lane, slot, info,
@@ -1652,10 +1737,28 @@ class ContinuousBatcher:
              "checkpoints": self.checkpoints,
              "snapshot_bytes": self.snapshot_bytes,
              "can_resume": bool(self._cache_model),
+             "boundaries": self.boundaries,
+             "intake_s_sum": self.intake_s_sum,
+             "intake_n": self.intake_n,
+             "queue_wait_s_sum": self.queue_wait_s_sum,
+             "queue_wait_n": self.queue_wait_n,
+             "prefill_s_sum": self.prefill_s_sum,
+             "prefill_n": self.prefill_n,
+             "first_out_s_sum": self.first_out_s_sum,
+             "first_out_n": self.first_out_n,
+             "prefill_positions_real": self.prefill_positions_real,
+             "prefill_positions_padded": self.prefill_positions_padded,
              "lanes": [{"bucket": lane.bucket,
                         "max_active": lane.max_active,
                         "active": lane.active}
                        for lane in self._lanes]}
+        # where the generate thread's time went (PR 25): the phases
+        # partition it, so phase_s.* sum to loop_s
+        seconds, counts = self.clock.totals()
+        for name in self.clock.phases:
+            d["phase_s." + name] = seconds[name]
+            d["phase_n." + name] = counts[name]
+        d["loop_s"] = sum(seconds.values())
         if self._pool is not None:
             pool = {"blocks": self._pool.n_blocks,
                     "block_len": self._pool.block_len,

@@ -1,7 +1,8 @@
 """HBM-bandwidth floor model for the ResNet-50 training step (VERDICT r4 #1).
 
 The round-3 verdict framed the 68 vs 122 TF/s gap as "lost inside the
-framework step".  The xprof trace (tools/xprof_lines.py) shows otherwise: the
+framework step".  The xprof trace (MFU_ANALYSIS.md; read today with
+benchmark/xplane.py, which replaced tools/xprof_lines.py) shows otherwise: the
 conv fusions themselves run AT the raw conv ceiling (~25ms of the 45.6ms
 step); the rest is BatchNorm statistics + backward reductions and
 normalize/residual elementwise passes.  On a TPU core ops execute serially —
