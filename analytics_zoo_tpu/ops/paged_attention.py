@@ -1,4 +1,4 @@
-"""Paged decode attention — block-pool KV gather kernels (PR 18).
+"""Paged decode attention — block-pool KV gather kernels (PR 18, 26).
 
 The continuous batcher's monolithic per-slot KV lanes become a fixed pool
 of ``(n_blocks, block_len, heads * head_dim)`` buffers; each decode row
@@ -21,16 +21,36 @@ Two data paths, the `quant_matmul.py` shape:
   Because the gather materializes the same values at the same positions,
   the float path is BITWISE-equal to monolithic decode on one backend —
   the parity anchor, and what a CPU process serves through.
-- ``_paged_kernel`` — Pallas TPU kernel: the block table rides in as a
-  SCALAR-PREFETCH operand (``pltpu.PrefetchScalarGridSpec``) so the
-  ``k_pool``/``v_pool`` BlockSpec index maps dereference it per grid step
-  — the pool block streams HBM->VMEM by PHYSICAL id, no host gather, no
-  (A, used_len) materialization.  Online-softmax carry across the
-  page-grid axis, flash_attention style.  Per-head reductions over the
-  folded lane axis are matmuls against a 0/1 head-membership matrix
-  (``_head_segments``), so every in-kernel value is a plain 2-D tile.
-  int8 pools dequantize IN-KERNEL: the per-(block, head) scale multiplies
-  the per-head logits / probabilities, never the (block_len, width) tile.
+- ``_paged_kernel`` — Pallas TPU kernel over a grid of (rows, table
+  groups).  The block table and the lengths ride in as SCALAR-PREFETCH
+  operands (``pltpu.PrefetchScalarGridSpec``); the pools stay in HBM
+  (``pl.ANY``) and the kernel copies pool blocks HBM->VMEM itself by
+  PHYSICAL id — no host gather, no (A, used_len) materialization.
+  - A grid step folds a GROUP of G consecutive table entries of a row,
+    ``G * block_len`` = 128 cache positions at the served shapes, into the
+    row's online-softmax carry (flash_attention style, PR 26; one block a
+    step before).  G follows from the shapes the kernel is handed
+    (``_group_blocks``): the largest power of two with ``G * block_len <=
+    128``, no wider than the table rounded up to a power of two (a narrower
+    table is one group, a table that is no multiple of G is padded with
+    entries that are never fetched), halved until both pools'
+    double-buffered fetch slots fit ``_FETCH_VMEM_BYTES``.
+  - Fetching: one DMA a LIVE block (one holding positions below the row's
+    length) into one of two VMEM slots; a live step starts the next live
+    step's DMAs (the row's next group, or the next row's first) before it
+    waits for its own, so fetch and fold overlap across steps and rows.
+    Entries past a row's length are not fetched at all — their buffer rows
+    keep stale values and are masked — and a group wholly past it is
+    skipped.
+  - Per-head reductions over the folded lane axis are matmuls against a
+    0/1 head-membership matrix (``_head_segments``), so every in-kernel
+    value is a plain 2-D tile; with ~128 rows on the moving side the
+    matrix's MXU weight tiles are loaded once per 128 positions.  The
+    sums keep float32 accuracy in three bf16 passes (``_dot01``).
+  - int8 pools dequantize IN-KERNEL: the per-(block, head) scale rows of
+    the group's blocks (fetched beside them) multiply the per-head logits
+    / probabilities, never the (positions, width) tile.  Float pools carry
+    no scale operands.
 
 ``impl`` resolves through ``ops/dispatch.resolve_impl``: Pallas on a TPU
 backend, the reference on CPU; ``"interpret"`` runs the kernel on CPU for
@@ -59,6 +79,8 @@ from analytics_zoo_tpu.ops.dispatch import resolve_impl
 NEG_INF = -1e30
 _LANE = 128
 _SUBLANE = 8
+_GROUP_ROWS = 128               # cache positions one grid step folds
+_FETCH_VMEM_BYTES = 4 << 20     # both pools' double-buffered fetch slots
 
 
 def _check(q, k_pool, v_pool, block_tables, lengths, k_scale, v_scale):
@@ -122,29 +144,109 @@ def _head_segments(nh: int, hd: int):
     lane multiple): column h is 1 on head h's lanes.  ``x @ seg`` sums
     each head's lanes (per-head reduce), ``y @ seg.T`` broadcasts a
     per-head value back over them — the folded-lane substitute for a
-    (.., heads, head_dim) reshape, which Mosaic cannot do in registers."""
+    (.., heads, head_dim) reshape, which Mosaic cannot do in registers.
+    bfloat16 holds 0 and 1 exactly (see ``_dot01``)."""
     seg = np.zeros((nh * hd, -(-nh // _LANE) * _LANE), np.float32)
     seg[np.arange(nh * hd), np.arange(nh * hd) // hd] = 1.0
-    return seg
+    return jnp.asarray(seg, jnp.bfloat16)
 
 
-def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                  seg_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                  block_len: int, n_table: int, scale: float):
-    """One (row, table-entry) grid step: fold the prefetched block into
-    the row's online-softmax carry (m/l per head, acc per lane; scratch
-    persists across the table axis), emit at the last entry.  W = heads *
-    head_dim lanes, P = heads padded to a lane multiple."""
-    del bt_ref                            # consumed by the index maps
+def _dot01(x, seg, contract):
+    """``x`` (f32) contracted with the 0/1 matrix ``seg`` (bf16) to float32
+    accuracy in three single-pass MXU matmuls: x splits exactly into three
+    bf16 parts (3 x 8 mantissa bits), each product with 0 or 1 is exact and
+    the MXU accumulates in float32.  ``Precision.HIGHEST`` would split the
+    0/1 side too and pay six passes for the same sums."""
+    out = None
+    for _ in range(3):
+        part = x.astype(jnp.bfloat16)
+        x = x - part.astype(jnp.float32)
+        d = jax.lax.dot_general(part, seg, (contract, ((), ())),
+                                preferred_element_type=jnp.float32)
+        out = d if out is None else out + d
+    return out
+
+
+def _group_blocks(block_len: int, n_table: int, width: int,
+                  itemsize: int) -> int:
+    """Table entries one grid step folds: the largest power of two with
+    ``G * block_len <= 128`` cache positions (the rows one MXU weight tile
+    is worth loading for), no wider than the table rounded up to a power of
+    two (a narrower table is one group), and with the 2 pools x 2 slots of
+    ``(G * block_len, width)`` fetch buffers inside ``_FETCH_VMEM_BYTES``."""
+    g = 1
+    while (2 * g * block_len <= _GROUP_ROWS and g < n_table
+           and 8 * g * block_len * width * itemsize <= _FETCH_VMEM_BYTES):
+        g *= 2
+    return g
+
+
+def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
+                  block_len: int, n_table: int, group: int, quant: bool,
+                  scale: float):
+    """One (row, table-group) grid step: fold the group's ``group`` pool
+    blocks — ``GB = group * block_len`` cache positions — into the row's
+    online-softmax carry (m/l per head, acc per lane; scratch persists
+    across the group axis), emit at the row's last group.  W = heads *
+    head_dim lanes, P = heads padded to a lane multiple.
+
+    The pools stay in HBM.  A live step (one that holds positions below the
+    row's length) first starts the DMAs of the NEXT live step's blocks —
+    the row's next group, or group 0 of the next row — into the other of
+    two VMEM slots, then waits for its own, so a group's fetch overlaps
+    the previous group's fold; only the call's first group is fetched in
+    the open.  One DMA a live block by physical id from the scalar-
+    prefetched table; a block wholly past the length is never fetched (its
+    buffer rows keep whatever they held, and are masked), a group wholly
+    past it costs one predicate."""
+    if quant:
+        ks_hbm, vs_hbm, seg_ref, o_ref, m_ref, l_ref, acc_ref, slot_ref, \
+            k_buf, v_buf, ks_buf, vs_buf, sems = rest
+    else:
+        seg_ref, o_ref, m_ref, l_ref, acc_ref, slot_ref, k_buf, v_buf, \
+            sems = rest
     a, t = pl.program_id(0), pl.program_id(1)
-    exact = jax.lax.Precision.HIGHEST     # the 0/1 matmuls must not round
+    n_rows = pl.num_programs(0)
+    gb = group * block_len
+
+    def live_blocks(row):
+        # table entries holding positions below the row's length; at least
+        # one, so that every row has a live group 0 and the chain of
+        # prefetches never breaks
+        return jnp.clip(pl.cdiv(len_ref[row], block_len), 1, n_table)
+
+    def fetch(row, grp, slot, wait: bool = False):
+        """Start, or wait for, the DMAs of group ``grp`` of ``row`` into
+        ``slot`` (a wait rebuilds the descriptors its start used)."""
+        n_live = live_blocks(row)
+        pairs = [(k_hbm, k_buf), (v_hbm, v_buf)]
+        if quant:
+            pairs += [(ks_hbm, ks_buf), (vs_hbm, vs_buf)]
+        for i in range(group):
+            j = grp * group + i
+
+            @pl.when(j < n_live)
+            def _block():
+                blk = bt_ref[row, j]
+                for n, (src, dst) in enumerate(pairs):
+                    copy = pltpu.make_async_copy(
+                        src.at[blk], dst.at[slot, i], sems.at[slot, n])
+                    copy.wait() if wait else copy.start()
 
     def spread(rows):
         # (r, P) per-head values -> (r, W) over each head's lanes: rows @
         # seg.T, contracted on seg's head axis
-        return jax.lax.dot_general(
-            rows, seg_ref[...], (((1,), (1,)), ((), ())), precision=exact,
-            preferred_element_type=jnp.float32)
+        return _dot01(rows, seg_ref[...], ((1,), (1,)))
+
+    @pl.when((a == 0) & (t == 0))
+    def _first():
+        slot_ref[0] = 0
+        if not quant:
+            # masked rows multiply the buffer by 0: never-written float
+            # VMEM may hold NaN patterns (any int8 is finite)
+            k_buf[...] = jnp.zeros(k_buf.shape, k_buf.dtype)
+            v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
+        fetch(0, 0, 0)      # the one fetch nothing overlaps
 
     @pl.when(t == 0)
     def _init():
@@ -152,57 +254,76 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
         l_ref[...] = jnp.zeros(l_ref.shape, l_ref.dtype)
         acc_ref[...] = jnp.zeros(acc_ref.shape, acc_ref.dtype)
 
-    # entries wholly past the row's length contribute nothing: skip them
-    @pl.when(t * block_len < len_ref[a])
+    # groups wholly past the row's length contribute nothing: skip them
+    @pl.when(t * group < live_blocks(a))
     def _fold():
+        slot = slot_ref[0]
+        more = (t + 1) * group < live_blocks(a)
+
+        @pl.when(more | (a + 1 < n_rows))
+        def _prefetch():
+            fetch(jnp.where(more, a, a + 1), jnp.where(more, t + 1, 0),
+                  1 - slot)
+
+        fetch(a, t, slot, wait=True)
+        slot_ref[0] = 1 - slot
+
         q = q_ref[0].astype(jnp.float32) * scale                  # (1, W)
-        k = k_ref[0].astype(jnp.float32)                          # (bl, W)
-        v = v_ref[0].astype(jnp.float32)
-        # s[j, h] = ks[h] * sum_{lanes of h} q * k[j]
-        s = jnp.dot(q * k, seg_ref[...], precision=exact,
-                    preferred_element_type=jnp.float32) * ks_ref[0]
-        idx = t * block_len + jax.lax.broadcasted_iota(
-            jnp.int32, (block_len, 1), 0)
-        s = jnp.where(idx < len_ref[a], s, NEG_INF)               # (bl, P)
+        k = k_buf[slot].astype(jnp.float32).reshape(gb, -1)       # (GB, W)
+        v = v_buf[slot].astype(jnp.float32).reshape(gb, -1)
+        # s[j, h] = ks[block of j, h] * sum_{lanes of h} q * k[j]
+        s = _dot01(q * k, seg_ref[...], ((1,), (0,)))             # (GB, P)
+        # (a table padded into its last group has positions past its end)
+        valid = t * gb + jax.lax.broadcasted_iota(
+            jnp.int32, (gb, 1), 0) < jnp.minimum(len_ref[a],
+                                                 n_table * block_len)
+        if quant:
+            def rows(buf):        # (G, 1, P) block scales -> (GB, P)
+                sc = buf[slot]
+                return jnp.broadcast_to(
+                    sc, (group, block_len, sc.shape[-1])).reshape(gb, -1)
+            s = s * rows(ks_buf)
+        s = jnp.where(valid, s, NEG_INF)
         m_prev = m_ref[...]                                       # (8, P)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
-        p = jnp.exp(s - m_new[:1])                                # (bl, P)
+        p = jnp.exp(s - m_new[:1])                                # (GB, P)
         alpha = jnp.exp(m_prev - m_new)                           # (8, P)
         m_ref[...] = m_new
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=0, keepdims=True)
+        if quant:
+            # an unfetched block's scale rows are stale: 0 * stale != 0
+            p = jnp.where(valid, p * rows(vs_buf), 0.0)
         # one spread for both per-head factors: the (dequantized)
-        # probabilities and the carry rescale
-        wide = spread(jnp.concatenate([p * vs_ref[0], alpha], axis=0))
-        acc_ref[...] = acc_ref[...] * wide[block_len:block_len + 1] \
-            + jnp.sum(wide[:block_len] * v, axis=0, keepdims=True)
+        # probabilities and the carry rescale (twice: 16-row bf16 tiles)
+        wide = spread(jnp.concatenate([p, alpha, alpha], axis=0))
+        acc_ref[...] = acc_ref[...] * wide[gb:gb + 1] \
+            + jnp.sum(wide[:gb] * v, axis=0, keepdims=True)
 
-    @pl.when(t == n_table - 1)
+    @pl.when(t == pl.num_programs(1) - 1)
     def _emit():
-        o_ref[0] = (acc_ref[...] / spread(l_ref[...])[:1]).astype(
-            o_ref.dtype)
+        l = l_ref[...]
+        o_ref[0] = (acc_ref[...] / spread(
+            jnp.concatenate([l, l], axis=0))[:1]).astype(o_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def _paged_pallas(q, k_pool, v_pool, block_tables, lengths, k_scale,
                   v_scale, interpret: bool):
+    # jitted so that a model's layers, which call this with one set of
+    # shapes, share one trace and one Mosaic lowering of the kernel
     A, nh, hd = q.shape
-    n_blocks, bl, W = k_pool.shape
+    _, bl, W = k_pool.shape
     n_table = int(block_tables.shape[1])
+    quant = k_scale is not None
+    group = _group_blocks(bl, n_table, W, k_pool.dtype.itemsize)
     seg = _head_segments(nh, hd)
     P = seg.shape[1]
-    if k_scale is None:
-        # one kernel for both modes: float pools ride unit scales
-        # (x * 1.0 is exact, so the float kernel numerics are unchanged)
-        k_scale = jnp.ones((n_blocks, nh), jnp.float32)
-        v_scale = k_scale
 
     def lanes(s):
-        # (n_blocks, heads) -> (n_blocks, 1, P): a block is one full
+        # (n_blocks, heads) -> (n_blocks, 1, P): a block's scales are one
         # (1, P) row, and padded heads scale by 0 (they own no lanes)
         return jnp.pad(jnp.asarray(s, jnp.float32),
                        [(0, 0), (0, P - nh)])[:, None, :]
-
-    def pool_block(a, t, bt, ln):
-        return (bt[a, t], 0, 0)
 
     def row_block(a, t, bt, ln):
         return (a, 0, 0)
@@ -210,37 +331,43 @@ def _paged_pallas(q, k_pool, v_pool, block_tables, lengths, k_scale,
     def whole(a, t, bt, ln):
         return (0, 0)
 
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    fetch_buf = pltpu.VMEM((2, group, bl, W), k_pool.dtype)
+    scale_buf = pltpu.VMEM((2, group, 1, P), jnp.float32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(A, n_table),
-        in_specs=[
-            pl.BlockSpec((1, 1, W), row_block),
-            pl.BlockSpec((1, bl, W), pool_block),
-            pl.BlockSpec((1, bl, W), pool_block),
-            pl.BlockSpec((1, 1, P), pool_block),
-            pl.BlockSpec((1, 1, P), pool_block),
-            pl.BlockSpec((W, P), whole),
-        ],
+        grid=(A, pl.cdiv(n_table, group)),
+        in_specs=[pl.BlockSpec((1, 1, W), row_block), in_hbm, in_hbm]
+        + [in_hbm, in_hbm] * quant
+        + [pl.BlockSpec((W, P), whole)],
         out_specs=pl.BlockSpec((1, 1, W), row_block),
         # m/l carry 8 identical sublanes so every matmul operand built
-        # from them is a whole (8, 128) tile
+        # from them is made of whole (8, 128) tiles
         scratch_shapes=[pltpu.VMEM((_SUBLANE, P), jnp.float32),
                         pltpu.VMEM((_SUBLANE, P), jnp.float32),
-                        pltpu.VMEM((1, W), jnp.float32)])
-    kernel = functools.partial(_paged_kernel, block_len=bl,
-                               n_table=n_table, scale=1.0 / np.sqrt(hd))
+                        pltpu.VMEM((1, W), jnp.float32),
+                        pltpu.SMEM((1,), jnp.int32),
+                        fetch_buf, fetch_buf]
+        + [scale_buf, scale_buf] * quant
+        + [pltpu.SemaphoreType.DMA((2, 4 if quant else 2))])
+    kernel = functools.partial(_paged_kernel, block_len=bl, n_table=n_table,
+                               group=group, quant=quant,
+                               scale=1.0 / np.sqrt(hd))
+    scales = (lanes(k_scale), lanes(v_scale)) if quant else ()
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((A, 1, W), jnp.float32),
+        # the fetch slot and the DMAs in flight carry from one step to the
+        # next, across rows too: both axes run in order
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="paged_attention_int8" if k_pool.dtype == jnp.int8
         else "paged_attention",
     )(jnp.asarray(block_tables, jnp.int32),
       jnp.asarray(lengths, jnp.int32), q.reshape(A, 1, W), k_pool, v_pool,
-      lanes(k_scale), lanes(v_scale), seg)
+      *scales, seg)
     return out.reshape(A, nh, hd)
 
 
@@ -255,7 +382,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, lengths,
     - ``block_tables`` (rows, n_table) int32 — logical block j of row a
       lives in pool block ``block_tables[a, j]``.  Entries past a row's
       allocation may point anywhere resident (conventionally block 0, the
-      batcher's trash block): their positions are masked by ``lengths``.
+      batcher's trash block): the reference masks their positions by
+      ``lengths``, the kernel does not fetch them.
     - ``lengths`` (rows,) int32 >= 1 — valid cache positions per row
       (cursor + 1 at decode time: the current token's K/V is written
       before the read).

@@ -90,8 +90,15 @@ FULL = {
                  "prompt_lens": [5, 12, 23, 40, 57, 9],
                  "prefill_buckets": [16, 64], "block_len": 16,
                  "http_requests": 2},
-    "kernels": {"paged": {"rows": 4, "heads": 12, "head_dim": 64,
-                          "block_len": 16, "n_table": 8},
+    # paged: GPT-2-small width, then the benchmark cells' shape (gpt2-large,
+    # 8 slots, lane 1024: eight table groups a row, ragged over all of them)
+    "kernels": {"paged": [{"rows": 4, "heads": 12, "head_dim": 64,
+                           "block_len": 16, "n_table": 8,
+                           "lengths": [128, 65, 17, 1]},
+                          {"rows": 8, "heads": 20, "head_dim": 64,
+                           "block_len": 16, "n_table": 64,
+                           "lengths": [1, 16, 17, 128, 129, 600, 1023,
+                                       1024]}],
                 "flash": {"batch": 1, "heads": 12, "seq": 2048,
                           "head_dim": 64},
                 "matmul": [(512, 2048, 1000), (512, 768, 3072)]},
@@ -247,32 +254,33 @@ def leg_kernels(cfg: dict, impl: str = "pallas") -> dict:
     g = np.random.default_rng(0)
 
     # paged decode, float and int8: ragged lengths, permuted block order
-    pc = cfg["paged"]
-    A, nh, hd = pc["rows"], pc["heads"], pc["head_dim"]
-    bl, nt = pc["block_len"], pc["n_table"]
-    n_blocks = 1 + A * nt
-    q = g.normal(size=(A, nh, hd)).astype(np.float32)
-    k4 = g.normal(size=(n_blocks, bl, nh, hd)).astype(np.float32)
-    v4 = g.normal(size=(n_blocks, bl, nh, hd)).astype(np.float32)
-    tables = g.permutation(np.arange(1, n_blocks)) \
-        .reshape(A, nt).astype(np.int32)
-    lens = np.asarray([nt * bl, nt * bl // 2 + 1, bl + 1, 1][:A]
-                      + [nt * bl] * max(0, A - 4), np.int32)
+    for pc in cfg["paged"]:
+        A, nh, hd = pc["rows"], pc["heads"], pc["head_dim"]
+        bl, nt = pc["block_len"], pc["n_table"]
+        n_blocks = 1 + A * nt
+        shape = f"{A}x{nh}x{hd}x{nt}"
+        q = g.normal(size=(A, nh, hd)).astype(np.float32)
+        k4 = g.normal(size=(n_blocks, bl, nh, hd)).astype(np.float32)
+        v4 = g.normal(size=(n_blocks, bl, nh, hd)).astype(np.float32)
+        tables = g.permutation(np.arange(1, n_blocks)) \
+            .reshape(A, nt).astype(np.int32)
+        lens = np.asarray(pc["lengths"], np.int32)
 
-    def fold(x):
-        return np.ascontiguousarray(x).reshape(n_blocks, bl, nh * hd)
+        def fold(x):
+            return np.ascontiguousarray(x).reshape(n_blocks, bl, nh * hd)
 
-    run("paged_attention",
-        lambda *a: paged_attention(*a, impl=impl),
-        paged_attention_xla, (q, fold(k4), fold(v4), tables, lens))
-    qk, ks = kv_pack_int8(k4)
-    qv, vs = kv_pack_int8(v4)
-    run("paged_attention_int8",
-        lambda q_, k_, v_, t_, l_, ks_, vs_: paged_attention(
-            q_, k_, v_, t_, l_, ks_, vs_, impl=impl),
-        paged_attention_xla,
-        (q, fold(np.asarray(qk)), fold(np.asarray(qv)), tables, lens,
-         np.asarray(ks), np.asarray(vs)))
+        run(f"paged_attention_{shape}",
+            lambda *a: paged_attention(*a, impl=impl),
+            paged_attention_xla, (q, fold(k4), fold(v4), tables, lens),
+            tol_key="paged_attention")
+        qk, ks = kv_pack_int8(k4)
+        qv, vs = kv_pack_int8(v4)
+        run(f"paged_attention_int8_{shape}",
+            lambda q_, k_, v_, t_, l_, ks_, vs_: paged_attention(
+                q_, k_, v_, t_, l_, ks_, vs_, impl=impl),
+            paged_attention_xla,
+            (q, fold(np.asarray(qk)), fold(np.asarray(qv)), tables, lens,
+             np.asarray(ks), np.asarray(vs)), tol_key="paged_attention_int8")
 
     # flash forward + dq + dkv through the dispatching entry point.  On the
     # chip use_flash stays None: auto-selection is part of what is checked.

@@ -27,8 +27,13 @@ TOY = {
                  "max_len": 64, "slots": 2, "max_tokens": 12,
                  "prompt_lens": [3, 11], "prefill_buckets": [16],
                  "block_len": 8, "http_requests": 1},
-    "kernels": {"paged": {"rows": 4, "heads": 4, "head_dim": 8,
-                          "block_len": 8, "n_table": 4},
+    # one table group, then three with the last one half empty
+    "kernels": {"paged": [{"rows": 4, "heads": 4, "head_dim": 8,
+                           "block_len": 8, "n_table": 4,
+                           "lengths": [32, 17, 9, 1]},
+                          {"rows": 3, "heads": 4, "head_dim": 8,
+                           "block_len": 8, "n_table": 40,
+                           "lengths": [320, 129, 1]}],
                 "flash": {"batch": 1, "heads": 2, "seq": 128, "head_dim": 16},
                 "matmul": [(16, 256, 40)]},
 }
@@ -75,8 +80,10 @@ def test_no_except_between_a_leg_and_the_exit_code():
 
 def test_kernels_leg_interpreted():
     doc = chip_smoke.leg_kernels(TOY["kernels"], impl="interpret")
-    assert set(doc["kernels"]) >= {"paged_attention", "paged_attention_int8",
-                                   "flash_fwd", "flash_bwd"}
+    assert set(doc["kernels"]) >= {
+        "paged_attention_4x4x8x4", "paged_attention_int8_4x4x8x4",
+        "paged_attention_3x4x8x40", "paged_attention_int8_3x4x8x40",
+        "flash_fwd", "flash_bwd"}
     assert all(k["mosaic_calls"] == 0 for k in doc["kernels"].values())
 
 

@@ -191,9 +191,10 @@ def test_kv_quantize_append_dequant_roundtrip():
 
 # -- paged attention kernel ---------------------------------------------------
 
-def _pool_case(seed, A, n_table, bl, nh, hd, lengths):
+def _pool_case(seed, A, n_table, bl, nh, hd, lengths, trash_rows=()):
     """Random monolithic caches scattered into a pool under a permuted
-    block order, plus garbage in the unreferenced blocks."""
+    block order, plus garbage in the unreferenced blocks.  ``trash_rows``
+    are inactive slots: every table entry is block 0, the trash block."""
     g = np.random.default_rng(seed)
     C = n_table * bl
     q = np.asarray(g.normal(size=(A, nh, hd)), np.float32)
@@ -208,6 +209,7 @@ def _pool_case(seed, A, n_table, bl, nh, hd, lengths):
         for t in range(n_table):
             kp[tables[a, t]] = kc[a, t * bl:(t + 1) * bl]
             vp[tables[a, t]] = vc[a, t * bl:(t + 1) * bl]
+    tables[list(trash_rows)] = 0
     return q, kc, vc, kp, vp, tables, np.asarray(lengths, np.int32)
 
 
@@ -238,12 +240,38 @@ def test_paged_attention_xla_matches_reference(lengths):
                                rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("lengths", [(32, 32, 32, 32), (32, 17, 9, 1)])
-def test_paged_attention_kernel_parity_float(lengths):
+# (rows, n_table, block_len, heads, head_dim, lengths, trash rows).  The
+# kernel folds G table entries a grid step, G * block_len = 128 positions
+# (block_len 8 -> G 16), a narrower table being one group.
+KERNEL_CASES = [
+    pytest.param(4, 4, 8, 2, 8, (32, 32, 32, 32), (), id="aligned"),
+    pytest.param(4, 4, 8, 2, 8, (32, 17, 9, 1), (), id="ragged"),
+    # three groups: lengths inside the first, on its boundary, one past
+    # it, at 1, at the full table, and on the second boundary
+    pytest.param(6, 48, 8, 2, 8, (100, 128, 129, 1, 384, 256), (),
+                 id="three-groups"),
+    # n_table 20 is not a multiple of G 16: the second group is padded
+    pytest.param(4, 20, 8, 2, 8, (160, 129, 128, 5), (),
+                 id="table-not-a-multiple"),
+    # inactive slots between live rows: all-trash tables, length 1
+    pytest.param(5, 32, 8, 2, 8, (1, 200, 1, 256, 1), (0, 2, 4),
+                 id="trash-rows"),
+    # block_len 16 -> G 8, n_table 12: two groups, the second half empty
+    pytest.param(3, 12, 16, 3, 8, (192, 130, 16), (), id="block-len-16"),
+    # the benchmark cells' shape (gpt2-large, 8 slots, lane 1024)
+    pytest.param(8, 64, 16, 20, 64, (1, 16, 17, 128, 129, 600, 1023, 1024),
+                 (), id="cell-shape"),
+]
+
+
+@pytest.mark.parametrize("A,n_table,bl,nh,hd,lengths,trash", KERNEL_CASES)
+def test_paged_attention_kernel_parity_float(A, n_table, bl, nh, hd,
+                                             lengths, trash):
     """Pallas kernel (interpret mode on CPU) vs the XLA oracle: the
     ``impl="auto"`` dispatch contract from quant_matmul, paged."""
     from analytics_zoo_tpu.ops.paged_attention import paged_attention
-    q, _, _, kp, vp, tables, lens = _pool_case(1, 4, 4, 8, 2, 8, lengths)
+    q, _, _, kp, vp, tables, lens = _pool_case(1, A, n_table, bl, nh, hd,
+                                               lengths, trash)
     kp, vp = _fold(kp), _fold(vp)
     oracle = np.asarray(paged_attention(q, kp, vp, tables, lens,
                                         impl="xla"))
@@ -252,12 +280,14 @@ def test_paged_attention_kernel_parity_float(lengths):
     np.testing.assert_allclose(kern, oracle, rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("lengths", [(32, 32, 32, 32), (32, 17, 9, 1)])
-def test_paged_attention_kernel_parity_int8(lengths):
+@pytest.mark.parametrize("A,n_table,bl,nh,hd,lengths,trash", KERNEL_CASES)
+def test_paged_attention_kernel_parity_int8(A, n_table, bl, nh, hd,
+                                            lengths, trash):
     from analytics_zoo_tpu.inference.quantize import kv_pack_int8
     from analytics_zoo_tpu.ops.paged_attention import (paged_attention,
                                                        paged_attention_xla)
-    q, _, _, kp, vp, tables, lens = _pool_case(2, 4, 4, 8, 2, 8, lengths)
+    q, _, _, kp, vp, tables, lens = _pool_case(2, A, n_table, bl, nh, hd,
+                                               lengths, trash)
     qk, ks = kv_pack_int8(kp)
     qv, vs = kv_pack_int8(vp)
     qk, qv = _fold(qk), _fold(qv)
@@ -271,6 +301,40 @@ def test_paged_attention_kernel_parity_int8(lengths):
     flt = np.asarray(paged_attention_xla(q, _fold(kp), _fold(vp), tables,
                                          lens))
     np.testing.assert_allclose(oracle, flt, atol=0.15)
+
+
+@pytest.mark.parametrize("bl,n_table,width,itemsize,want", [
+    (16, 64, 1280, 4, 8),       # the cells: 128 positions a step
+    (16, 8, 768, 4, 8),         # chip_smoke, GPT-2-small width
+    (8, 4, 16, 4, 4),           # a table narrower than a group is one
+    (8, 20, 16, 4, 16),         # ... and a longer one is padded into groups
+    (16, 64, 1280, 1, 8),       # int8 pools: the same rows
+    (16, 64, 8192, 4, 2),       # fetch buffers held to the VMEM budget
+    (256, 8, 1280, 4, 1),       # a block longer than a group: one a step
+])
+def test_paged_attention_group_follows_shapes(bl, n_table, width, itemsize,
+                                              want):
+    from analytics_zoo_tpu.ops.paged_attention import _group_blocks
+    assert _group_blocks(bl, n_table, width, itemsize) == want
+
+
+@pytest.mark.parametrize("contract", [((1,), (0,)), ((1,), (1,))],
+                         ids=["reduce", "spread"])
+def test_paged_attention_01_matmul_keeps_float32(contract):
+    """The three-bf16-pass contraction with the 0/1 head matrix is as
+    exact as a float32 one (a single bf16 pass is off by ~4e-3)."""
+    import jax.numpy as jnp
+    from analytics_zoo_tpu.ops.paged_attention import (_dot01,
+                                                       _head_segments)
+    nh, hd = 4, 8
+    seg = _head_segments(nh, hd)                       # (32, 128) bf16
+    g = np.random.default_rng(5)
+    x = (g.normal(size=(48, seg.shape[contract[1][0]]))
+         * 10.0 ** g.integers(-3, 4, size=(48, 1))).astype(np.float32)
+    got = np.asarray(_dot01(jnp.asarray(x), seg, contract))
+    seg64 = np.asarray(seg, np.float64)
+    want = x.astype(np.float64) @ (seg64 if contract[1] == (0,) else seg64.T)
+    np.testing.assert_allclose(got, want, rtol=2e-6, atol=1e-30)
 
 
 # -- scheduler end-to-end -----------------------------------------------------
