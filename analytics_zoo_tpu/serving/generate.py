@@ -24,6 +24,19 @@ models/textmodels.py):
   ``warm()`` pre-compiles the whole set from the same
   ``aot.generation_manifest`` the serving warm-up manifest carries, so a
   warm replica serves its first token with zero compiles.
+- **the paged pool is the decode program's to overwrite** (PR 29) —
+  ``pdecode`` DONATES the pool pytree, so a decode quantum updates the KV
+  blocks in place instead of copying the whole pool first.  (``pprefill``
+  and ``pshared`` do not yet, a known partial result: with the second
+  pool's memory freed the TPU compiler emits the prefill programs ten
+  times the size, which a capped compile cache cannot hold — ``PERF.md``
+  sections 6 and 7.)  Every paged program is lowered
+  from the pool's SHAPES (``lane.state_shapes``); the one ``exe(...)``
+  call that takes the live pool and replaces ``lane.state`` with its
+  output is the only code that ever passes it.  A call that fails after
+  the runtime took the buffers leaves nothing to retry on:
+  ``_rebuild_pool`` ends the lane's requests and starts from a zeroed
+  pool.
 - **mesh placement** — lane state buffers are committed with a
   ``NamedSharding`` over the PR 6 serving mesh when the model is sharded
   (slot axis over ``data`` when it divides, replicated otherwise), so the
@@ -312,10 +325,14 @@ class _PagedLane(_Lane):
     per-slot caches, and the per-slot cache geometry lives in host-side
     block tables.  Inactive slots keep their table row zeroed (every
     entry -> the trash block), so their in-program decode writes land
-    harmlessly."""
+    harmlessly.  ``state_shapes`` is the pool's ``ShapeDtypeStruct`` tree,
+    taken once at allocation: programs are lowered from it, never from the
+    live (donated) pool."""
 
     def __init__(self, bucket: int, max_active: int, block_len: int):
         super().__init__(bucket, max_active)
+        self.state_shapes = None
+        self.state_nbytes = 0              # the pool's bytes on the device
         self.ntab = bucket // block_len
         self.tables = np.zeros((max_active, self.ntab), np.int32)
         self.pos = np.zeros((max_active,), np.int32)
@@ -333,6 +350,8 @@ class ContinuousBatcher:
     ``serving-generate`` worker)."""
 
     MAX_WAITING = 1024
+    # programs that take a paged lane's pool and return its successor
+    _POOL_PROGRAMS = ("pprefill", "pshared", "pdecode")
 
     def __init__(self, model, gen: GenerationParams):
         inner = getattr(model, "_model", None)
@@ -377,6 +396,7 @@ class ContinuousBatcher:
         self._pool = None
         self._prefix = None
         self.pool_exhausted = 0
+        self.pool_rebuilds = 0       # pools lost to a failed donated call
         self._exhausted_boundary = False
         if gen.paged:
             missing = [m for m in ("prefill_kv", "prefill_shared",
@@ -420,6 +440,13 @@ class ContinuousBatcher:
         # per-program execution counts (PR 15 resource accounting):
         # scheduler-thread-only, keyed by the manifest-style program name
         self._exec_counts: Dict[str, int] = {}
+        # bytes each compiled paged program aliases from its inputs to its
+        # outputs (memory_analysis, at compile time), and over the calls
+        # of those programs: pool bytes handed in, and how many of them
+        # the program took over in place
+        self._alias_bytes: Dict[tuple, int] = {}
+        self.state_bytes_passed = 0
+        self.state_bytes_aliased = 0
         self.compiles = 0
         self.decode_steps = 0
         self.boundaries = 0          # calls of a decode program
@@ -459,8 +486,9 @@ class ContinuousBatcher:
         from analytics_zoo_tpu.inference import aot
         aot.install_compile_listeners()
         # lane buffers allocated EAGERLY: the warm-up thread and the
-        # generate worker both touch lane.state, and lazy allocation would
-        # let one overwrite the other's freshly-inserted request state.
+        # generate worker both look at lane.state, and lazy allocation
+        # would let one overwrite the other's freshly-inserted request
+        # state.  (A paged lane's warm-up lowers from state_shapes alone.)
         # (Program compiles stay lock-free — a rare duplicate compile is
         # benign, and serializing them would queue a live request behind
         # the whole warm-up set.)
@@ -628,7 +656,18 @@ class ContinuousBatcher:
                 length=K)
             return toks, pools2           # toks: (K, max_active)
 
-        fns = (jax.jit(pprefill), jax.jit(pshared), jax.jit(pdecode))
+        # pdecode donates the pool it replaces: its call site assigns
+        # the output over lane.state, so the buffers are the program's to
+        # update in place.  The two prefill programs would be as safe to
+        # donate into (their call sites replace lane.state too) and are
+        # the follow-up: freed of the second pool's 3 GB, the TPU compiler
+        # emits a gpt2-large prefill as an executable of 26-43 MB against
+        # 2.4-3.2 MB (undonated beside a SMALL pool it does the same), and
+        # a deployment's set of those outgrows a capped persistent compile
+        # cache, so every start would be a cold one (PERF.md sections 6
+        # and 7, PR 29).
+        fns = (jax.jit(pprefill), jax.jit(pshared),
+               jax.jit(pdecode, donate_argnums=(1,)))
         self._programs[key] = fns
         return fns
 
@@ -639,6 +678,9 @@ class ContinuousBatcher:
         exe = self._programs.get(key)
         if exe is None:
             exe = fn.lower(*args).compile()
+            if key[0] in self._POOL_PROGRAMS:
+                self._alias_bytes[key] = int(
+                    exe.memory_analysis().alias_size_in_bytes)
             self._programs[key] = exe
             self.compiles += 1
         return exe
@@ -661,10 +703,17 @@ class ContinuousBatcher:
             return f"paged_decode@{key[1]}"
         return ":".join(str(k) for k in key)
 
-    def _count_exec(self, key: tuple) -> None:
+    def _count_exec(self, key: tuple,
+                    lane: Optional["_PagedLane"] = None) -> None:
+        """One finished call of program ``key``; ``lane`` where the call
+        took that paged lane's pool."""
         # scheduler-thread-only (step/admit run on one thread)
         label = self._program_name(key)
         self._exec_counts[label] = self._exec_counts.get(label, 0) + 1
+        if lane is not None:
+            # raw on both sides: a share above 100 is a miscount to find
+            self.state_bytes_passed += lane.state_nbytes
+            self.state_bytes_aliased += self._alias_bytes.get(key, 0)
 
     def _commit_state(self, state):
         """Commit a lane state buffer over the serving mesh (PR 6): slot
@@ -691,12 +740,16 @@ class ContinuousBatcher:
             return
         import jax
         if isinstance(lane, _PagedLane):
-            # pool pytree: +1 block for the reserved trash row; placed
-            # whole (no slot axis to shard — the pool IS the point)
-            pools = self.inner.init_paged_pools(
-                self._pool.n_blocks + 1, self.gen.block_len,
-                lane.max_active, self.gen.kv_quant)
-            lane.state = jax.device_put(pools)
+            lane.state = self._zeroed_pool(lane)
+            lane.state_shapes = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                lane.state)
+            # as the device lays the leaves out (a TPU pads int8 pools'
+            # scale planes and staging buffers to its tiles), which is
+            # what memory_analysis() counts an aliased leaf as
+            lane.state_nbytes = sum(
+                leaf.on_device_size_in_bytes()
+                for leaf in jax.tree.leaves(lane.state))
             return
         pb = self.gen.prefill_buckets[0]
         prefill, _, _ = self._lane_fns(lane)
@@ -710,6 +763,60 @@ class ContinuousBatcher:
             lambda sd: np.zeros(sd.shape, sd.dtype), state_shapes))
         lane.state = jax.device_put(lane.state) \
             if getattr(self.model, "_mesh", None) is None else lane.state
+
+    def _zeroed_pool(self, lane: "_PagedLane"):
+        """Pool pytree on the device: +1 block for the reserved trash row;
+        placed whole (no slot axis to shard — the pool IS the point)."""
+        import jax
+        return jax.device_put(self.inner.init_paged_pools(
+            self._pool.n_blocks + 1, self.gen.block_len, lane.max_active,
+            self.gen.kv_quant))
+
+    @staticmethod
+    def _pool_lost(lane: "_PagedLane") -> bool:
+        """After an exception out of a paged program: did the call take
+        the pool with it?  True when the runtime already consumed the
+        donated buffers (a leaf is deleted) or the program that was to
+        produce the new ones failed on the device (waiting on a leaf
+        raises).  False for an error raised before execution (arguments,
+        shapes): the pool is untouched."""
+        import jax
+        leaves = jax.tree.leaves(lane.state)
+        if any(leaf.is_deleted() for leaf in leaves):
+            return True
+        try:
+            jax.block_until_ready(leaves)
+        except Exception:  # noqa: BLE001 — the failed program's outputs
+            return True
+        return False
+
+    def _rebuild_pool(self, lane: "_PagedLane", err: Exception,
+                      events: List[GenEvent]) -> None:
+        """The lane's KV is gone (``_pool_lost``): nothing may run on it
+        again.  Every active request ends with a quarantine event that
+        carries the error, blocks and prefix index are released, and the
+        lane starts over from a zeroed pool."""
+        error = f"{type(err).__name__}: {err}"
+        ended = 0
+        for slot, info in enumerate(lane.slots):
+            if info is None:
+                continue
+            req = info.req
+            self.quarantined += 1
+            ended += 1
+            events.append(GenEvent(
+                "quarantine", req.rid, trace_id=req.trace_id,
+                error=f"KV pool lost in a failed call: {error}",
+                t_read=req.t_read, tenant=req.tenant))
+            self._free(lane, slot)
+        if self._prefix is not None:
+            self._prefix.clear()
+        lane.state = self._zeroed_pool(lane)
+        self.pool_rebuilds += 1
+        logger.error(
+            "generate: a paged program failed after taking the KV pool "
+            "(%s); %d active request(s) ended, pool rebuilt (%d so far)",
+            error, ended, self.pool_rebuilds)
 
     @staticmethod
     def _is_pair(res) -> bool:
@@ -1096,18 +1203,40 @@ class ContinuousBatcher:
                     (req, slot, resv))
             else:
                 miss.setdefault(pb, []).append((req, slot, resv))
-        return sum(self._admit_paged_batch(lane, pb, members, events)
-                   for pb, members in miss.items()) \
-            + sum(self._admit_paged_batch(lane, sb, members, events,
-                                          shared=npb)
-                  for (sb, npb), members in hit.items())
+        groups = [(pb, None, members) for pb, members in miss.items()] \
+            + [(sb, npb, members) for (sb, npb), members in hit.items()]
+        admitted, rebuilds = 0, self.pool_rebuilds
+        for i, (pb, npb, members) in enumerate(groups):
+            if self.pool_rebuilds != rebuilds:
+                # an earlier group's call lost the pool
+                self._requeue_reserved(
+                    lane, [m for _, _, ms in groups[i:] for m in ms])
+                break
+            admitted += self._admit_paged_batch(lane, pb, members, events,
+                                                shared=npb)
+        return admitted
+
+    def _requeue_reserved(self, lane: "_PagedLane", members) -> None:
+        """The pool was rebuilt under admissions that had already
+        reserved: what they reserved (shared prefix pages above all)
+        names contents that are gone.  They give slot and blocks back and
+        return to the head of the waiting room, in order, to reserve
+        again at the next boundary (``_take_resume`` is idempotent)."""
+        for _, slot, resv in members:
+            self._release_resv(resv)
+            lane.free.append(slot)
+        with self._waiting_lock:
+            self._waiting.extendleft(req for req, _, _ in reversed(members))
 
     def _admit_paged_batch(self, lane: "_PagedLane", pb: int, members,
                            events, shared: Optional[int] = None) -> int:
         """Prefill + commit one same-bucket paged admission group in ONE
         device call.  ``shared`` = prefix-table bucket for prefix-HIT
         groups (None = full prefill).  Mirrors ``_admit_batch``'s
-        singleton fallback so a poisoned request quarantines alone."""
+        singleton fallback so a poisoned request quarantines alone — as
+        long as the failed call left the pool alive (an error raised
+        before execution); one that took the pool with it ends the
+        group and the lane's active requests (``_rebuild_pool``)."""
         import jax
         bl = self.gen.block_len
         A = lane.max_active
@@ -1156,36 +1285,48 @@ class ContinuousBatcher:
             if shared is None:
                 key = ("pprefill", bb, pb)
                 exe = self._compiled(key, pprefill, self._params(),
-                                     padded, lengths, lane.state, dest,
-                                     slots_arr)
+                                     padded, lengths, lane.state_shapes,
+                                     dest, slots_arr)
                 lane.state, logits0 = exe(self._params(), padded, lengths,
                                           lane.state, dest, slots_arr)
             else:
                 key = ("pshared", bb, pb, shared)
                 exe = self._compiled(key, pshared, self._params(),
                                      padded, lengths, plens, ptab,
-                                     lane.state, dest, slots_arr)
+                                     lane.state_shapes, dest, slots_arr)
                 lane.state, logits0 = exe(self._params(), padded, lengths,
                                           plens, ptab, lane.state, dest,
                                           slots_arr)
-            self._count_exec(key)
+            self._count_exec(key, lane)
             with self.clock.phase("prefill_wait"):
                 logits0 = np.asarray(logits0)
             toks0 = logits0.argmax(axis=-1)
         except Exception as e:  # noqa: BLE001 — batch-level failure
-            if n == 1:
-                req, slot, resv = members[0]
-                self._release_resv(resv)
-                self.quarantined += 1
-                events.append(GenEvent(
-                    "quarantine", req.rid, trace_id=req.trace_id,
-                    error=f"{type(e).__name__}: {e}", t_read=req.t_read,
-                    tenant=req.tenant))
-                lane.free.append(slot)
+            # a call that took the pool with it (its outputs replaced
+            # lane.state, then failed on the device) allows no retry, not
+            # of these members and not on this pool
+            lost = self._pool_lost(lane)
+            if lost or n == 1:
+                for req, slot, resv in members:
+                    self._release_resv(resv)
+                    self.quarantined += 1
+                    events.append(GenEvent(
+                        "quarantine", req.rid, trace_id=req.trace_id,
+                        error=f"{type(e).__name__}: {e}",
+                        t_read=req.t_read, tenant=req.tenant))
+                    lane.free.append(slot)
+                if lost:
+                    self._rebuild_pool(lane, e, events)
                 return 0
-            return sum(self._admit_paged_batch(lane, pb, [m], events,
-                                               shared=shared)
-                       for m in members)
+            done, rebuilds = 0, self.pool_rebuilds
+            for i, m in enumerate(members):
+                if self.pool_rebuilds != rebuilds:
+                    # a retry lost the pool after all
+                    self._requeue_reserved(lane, members[i:])
+                    break
+                done += self._admit_paged_batch(lane, pb, [m], events,
+                                                shared=shared)
+            return done
         admitted = 0
         for j, (req, slot, resv) in enumerate(members):
             ksh, shared_ids, priv, plen = resv
@@ -1421,13 +1562,21 @@ class ContinuousBatcher:
                 _, _, pdecode = self._paged_fns()
                 key = ("pdecode", lane.bucket)
                 exe = self._compiled(key, pdecode, self._params(),
-                                     lane.state, lane.tables, lane.pos,
-                                     tokens)
-                block, lane.state = exe(self._params(), lane.state,
-                                        lane.tables, lane.pos, tokens)
-                self._count_exec(key)
-                clock.to("decode_wait")
-                block = np.asarray(block)
+                                     lane.state_shapes, lane.tables,
+                                     lane.pos, tokens)
+                try:
+                    block, lane.state = exe(self._params(), lane.state,
+                                            lane.tables, lane.pos, tokens)
+                    self._count_exec(key, lane)
+                    clock.to("decode_wait")
+                    block = np.asarray(block)
+                except Exception as e:  # noqa: BLE001 — see _pool_lost
+                    if not self._pool_lost(lane):
+                        raise
+                    # the lane's requests end here, with events the engine
+                    # can act on; the loop goes on from a zeroed pool
+                    self._rebuild_pool(lane, e, events)
+                    continue
                 clock.to("fold")
                 # host cursors advance with the in-scan carry; idle rows
                 # clamp at lane capacity (their writes target the trash
@@ -1586,8 +1735,9 @@ class ContinuousBatcher:
             raise ValueError(f"no lane with bucket {entry.lane_bucket}")
         self._ensure_lane_state(lane)
         if entry.kind.startswith("paged_"):
-            # compile-only (lower().compile() never executes), so the
-            # dummy operands only fix shapes — pools stay untouched
+            # compile-only, from the pool's SHAPES: this thread never
+            # holds the live pool, which the generate thread's programs
+            # donate (a pool mid-call is deleted until it is replaced)
             bl = self.gen.block_len
             A = lane.max_active
             pprefill, pshared, pdecode = self._paged_fns()
@@ -1595,8 +1745,9 @@ class ContinuousBatcher:
             if entry.kind == "paged_decode":
                 key = ("pdecode", lane.bucket)
                 fresh = key not in self._programs
-                self._compiled(key, pdecode, self._params(), lane.state,
-                               lane.tables, lane.pos, lane.tokens)
+                self._compiled(key, pdecode, self._params(),
+                               lane.state_shapes, lane.tables, lane.pos,
+                               lane.tokens)
                 return fresh
             pb = int(entry.prefill_bucket)
             npb_dest = (pb + bl - 1) // bl
@@ -1608,7 +1759,7 @@ class ContinuousBatcher:
                 key = ("pprefill", bb, pb)
                 fresh = key not in self._programs
                 self._compiled(key, pprefill, self._params(), *dummy,
-                               lane.state, dest, slots)
+                               lane.state_shapes, dest, slots)
                 return fresh
             if entry.kind == "paged_shared":
                 npb = int(entry.prefix_blocks or 1)
@@ -1617,7 +1768,7 @@ class ContinuousBatcher:
                 self._compiled(key, pshared, self._params(), *dummy,
                                np.zeros((bb,), np.int32),
                                np.zeros((bb, npb), np.int32),
-                               lane.state, dest, slots)
+                               lane.state_shapes, dest, slots)
                 return fresh
             raise ValueError(f"unknown warm-up entry kind {entry.kind!r}")
         prefill, step, insert = self._lane_fns(lane)
@@ -1748,6 +1899,9 @@ class ContinuousBatcher:
              "first_out_n": self.first_out_n,
              "prefill_positions_real": self.prefill_positions_real,
              "prefill_positions_padded": self.prefill_positions_padded,
+             "state_bytes_passed": self.state_bytes_passed,
+             "state_bytes_aliased": self.state_bytes_aliased,
+             "pool_rebuilds": self.pool_rebuilds,
              "lanes": [{"bucket": lane.bucket,
                         "max_active": lane.max_active,
                         "active": lane.active}

@@ -181,6 +181,14 @@ class PrefixIndex:
             freed += self._evict_one()
         return freed
 
+    def clear(self) -> int:
+        """Drop every entry (and its cache hold): the pages' contents are
+        gone, e.g. the pool was rebuilt after a failed donated call."""
+        freed = 0
+        while self._entries:
+            freed += self._evict_one()
+        return freed
+
     def stats(self) -> Dict:
         return {"entries": len(self._entries), "hits": self.hits,
                 "misses": self.misses, "evictions": self.evictions}
