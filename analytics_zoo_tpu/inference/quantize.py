@@ -208,10 +208,9 @@ def _quantize_w4(W: np.ndarray, group_size: int) -> dict:
 
 
 # -- int8 KV-cache packing (PR 18 paged KV) -----------------------------------
-# The ONE pack/unpack contract shared by the paged-attention kernels
-# (ops/paged_attention.py), the decode append path
-# (models/textmodels.TransformerLM.decode_paged) and the prefill commit
-# program (serving/generate.py): symmetric int8 with one scale per
+# The ONE pack/unpack contract of the paged pool's owner
+# (ops/paged_attention.py: the kernels that read it, the decode append and
+# the prefill commit that write it): symmetric int8 with one scale per
 # (block, head) — same recipe as `_quantize_w8` (scale = absmax/127,
 # round-clip to [-127, 127]) but jnp-traceable, because the quantize
 # happens INSIDE the compiled decode/commit programs as tokens append.

@@ -137,27 +137,36 @@ def save_store(store_dir: str, tree) -> Dict:
     return manifest
 
 
+_SWAP_RETRY_S = 0.5      # how long load_flat waits out a dir swap
+
+
 def load_flat(store_dir: str, mmap: bool = True) -> Dict[str, np.ndarray]:
     """The store's leaves as a ``{path: array}`` dict; with ``mmap`` each
     array is a read-only ``np.memmap`` view (zero bytes read until pages
     fault in, page cache shared across processes).
 
-    Readers can race :func:`save_store`'s atomic dir-swap rewrite: between
-    its two ``os.replace`` calls the store path briefly does not exist
-    (ENOENT), and a manifest read before the swap can pair with a leaf
-    read after it (dtype/shape mismatch → ``ValueError``).  Both windows
-    are microseconds wide and the post-swap store is complete, so the load
-    retries ONCE with a short backoff before letting the error escape —
-    a genuinely missing or corrupt store still fails loudly."""
+    Readers can race :func:`save_store`'s dir-swap rewrite: between its two
+    ``os.replace`` calls the store path does not exist (ENOENT), and a
+    manifest read before the swap can pair with a leaf read after it
+    (dtype/shape mismatch → ``ValueError``).  The writer gives the
+    interpreter lock up inside exactly those calls, so a reader that lost
+    one race is likely to wake into the next swap of a writer that keeps
+    rewriting: the load retries for ``_SWAP_RETRY_S`` in short steps, not
+    once.  The post-swap store is complete, so a retry that finds it reads
+    it whole; a genuinely missing or corrupt store still fails loudly,
+    with its own error, when the budget is spent."""
     # resolve the path once per load: every manifest and leaf read below
     # must refer to the same directory even if the caller's cwd (or a
     # symlink along the way) changes mid-load
     store_dir = os.path.abspath(store_dir)
-    try:
-        return _load_flat_once(store_dir, mmap)
-    except (OSError, ValueError):
-        time.sleep(0.05)
-        return _load_flat_once(store_dir, mmap)
+    deadline = time.monotonic() + _SWAP_RETRY_S
+    while True:
+        try:
+            return _load_flat_once(store_dir, mmap)
+        except (OSError, ValueError):
+            if time.monotonic() >= deadline:
+                raise
+            time.sleep(0.01)
 
 
 def _load_flat_once(store_dir: str, mmap: bool) -> Dict[str, np.ndarray]:

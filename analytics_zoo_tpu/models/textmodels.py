@@ -26,9 +26,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from analytics_zoo_tpu.estimator.estimator import Estimator
-from analytics_zoo_tpu.inference.quantize import kv_pack_int8
 from analytics_zoo_tpu.nn.layers.core import Dense, Dropout, Embedding
-from analytics_zoo_tpu.ops.paged_attention import paged_attention
+from analytics_zoo_tpu.ops import paged_attention as paged
 from analytics_zoo_tpu.nn.layers.crf import CRF
 from analytics_zoo_tpu.nn.layers.recurrent import LSTM, Bidirectional
 from analytics_zoo_tpu.nn.module import Layer
@@ -137,7 +136,8 @@ class TransformerLM(Layer):
     """Decoder-only transformer language model with a KV-cache step API
     (the GPT-style generator the serving plane's continuous batcher
     drives).  Pre-LN blocks, learned positional embeddings, weight-tied
-    output head.
+    output head.  The decoder block is written ONCE (``_blocks``); every
+    forward path below supplies only its attention step.
 
     Monolithic paths: ``call(params, ids)`` -> (B, T, V) logits (teacher
     forcing / training), ``generate`` -> one ``lax.scan`` greedy rollout
@@ -155,25 +155,27 @@ class TransformerLM(Layer):
       leaf keeps a leading batch (slot) axis for ``.at[slot].set``
       insertion.
 
-    Paged-cache paths (PR 18): KV lives in a fixed block POOL instead of
-    per-row monolithic caches; each row carries a block table.
+    The PAGED CONTRACT (PR 18, 30) — what ``ContinuousBatcher(paged=True)``
+    asks of a model.  KV lives in a fixed block pool whose device format
+    belongs to ``ops/paged_attention``; the caller holds the state as an
+    opaque pytree, and each row carries a block table.
 
-    - ``init_paged_pools`` — allocate the zeroed pool pytree (int8 pools
-      carry per-(block, head) scale planes and per-slot f32 staging
-      buffers for the active block).
-    - ``prefill_kv`` — the prompt forward WITHOUT cache allocation:
-      raw per-layer K/V for the scheduler's commit program to scatter
-      into pool blocks.  ``init_decode`` shares the same core, so the
-      paged and monolithic prefills are bitwise-identical.
-    - ``prefill_shared`` — suffix-only prefill for prefix-cache hits:
-      the shared prefix contributes K/V (gathered from the pool by the
-      caller), only the suffix runs through the stack — the prefill-work
-      saving prefix sharing is for.
-    - ``decode_paged`` — one token per row against the pool via
-      ``ops/paged_attention``: append the token's K/V through the block
-      table (int8 mode re-quantizes the row's ACTIVE block from its f32
-      staging copy each step, so values are quantized once from exact
-      inputs — no requantization drift), then attend."""
+    - ``init_paged_pools(n_blocks, block_len, max_active, kv_quant)`` ->
+      the zeroed state.
+    - ``prefill_paged(params, state, prompt, lengths, dest, slots, ...)``
+      -> ``(state, logits0)``: ``init_decode``'s prompt forward (the same
+      core, so both prefills are bitwise-identical) with the K/V committed
+      to the blocks ``dest`` names.
+    - ``prefill_shared_paged(params, state, suffix, lengths, prefix_len,
+      ptab, dest, slots, ...)`` -> ``(state, logits0)``: suffix-only
+      prefill for prefix-cache hits — the shared prefix's K/V is gathered
+      from the blocks ``ptab`` names, only the suffix runs through the
+      stack (the prefill work prefix sharing saves).
+    - ``decode_paged(params, state, block_tables, pos, tokens, ...)`` ->
+      ``(logits, state)``: one token per row, appended through the block
+      table and attended by the ``paged_attention`` kernel.
+    - ``paged_state_bytes(state)`` -> the state's bytes by accounting
+      class (``paged_pool`` / ``scales`` / ``lanes``)."""
 
     def __init__(self, vocab_size: int, hidden: int = 64, n_head: int = 4,
                  n_layers: int = 2, max_len: int = 512,
@@ -239,72 +241,82 @@ class TransformerLM(Layer):
         return jnp.matmul(h, params["embed"].T,
                           preferred_element_type=jnp.float32)
 
+    @staticmethod
+    def _ids(x):
+        """Token ids as an int32 (B, T) array (a trailing unit axis, as a
+        feature column arrives, is dropped)."""
+        x = jnp.asarray(x)
+        if x.ndim == 3 and x.shape[-1] == 1:
+            x = x[..., 0]
+        return x.astype(jnp.int32)
+
+    def _blocks(self, params, x, attend):
+        """The decoder stack, written once: per block LayerNorm, qkv,
+        heads, ``attend``, proj, LayerNorm, fc1-GELU-fc2, then the final
+        LayerNorm.  ``attend(li, q, k, v) -> (o, keep)`` is the calling
+        path's attention step over (..., n_head, head_dim) heads; ``keep``
+        is what that path carries out of layer ``li`` (the new K/V, the
+        updated cache, the pool's leaves).  Returns ``(h, keeps)``."""
+        keeps = []
+        for li, blk in enumerate(params["blocks"]):
+            h = self._ln(blk["ln1"], x)
+            q, k, v = jnp.split(self._lin(blk["qkv"], h), 3, axis=-1)
+            o, keep = attend(li, self._heads(q), self._heads(k),
+                             self._heads(v))
+            keeps.append(keep)
+            x = x + self._lin(blk["proj"], o.reshape(x.shape))
+            h2 = self._ln(blk["ln2"], x)
+            x = x + self._lin(blk["fc2"],
+                              jax.nn.gelu(self._lin(blk["fc1"], h2)))
+        return self._ln(params["ln_f"], x), keeps
+
+    @staticmethod
+    def _attend(q, k, v, mask):
+        """Softmax attention of (B, Q, nh, hd) queries over (B, K, nh, hd)
+        keys under a boolean (B or 1, Q, K) mask."""
+        scale = 1.0 / np.sqrt(q.shape[-1])
+        att = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+        att = jnp.where(mask[:, None], att, -1e30)
+        att = jax.nn.softmax(att, axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", att, v)
+
+    def _last_logits(self, params, h, lengths):
+        # each row's next-token logits live at its LAST REAL position
+        last = jnp.take_along_axis(
+            h, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)[:, 0]
+        return self._logits(params, last)
+
     # -- monolithic forward (teacher forcing / training) ----------------------
     def call(self, params, inputs, *, training=False, rng=None):
-        ids = jnp.asarray(inputs)
-        if ids.ndim == 3 and ids.shape[-1] == 1:
-            ids = ids[..., 0]
-        ids = ids.astype(jnp.int32)
-        B, T = ids.shape
+        ids = self._ids(inputs)
+        T = ids.shape[1]
         x = jnp.take(params["embed"], ids, axis=0) + params["pos"][:T]
-        causal = jnp.tril(jnp.ones((T, T), bool))
-        for blk in params["blocks"]:
-            h = self._ln(blk["ln1"], x)
-            qkv = self._lin(blk["qkv"], h)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            q, k, v = self._heads(q), self._heads(k), self._heads(v)
-            scale = 1.0 / np.sqrt(q.shape[-1])
-            att = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
-            att = jnp.where(causal[None, None], att, -1e30)
-            att = jax.nn.softmax(att, axis=-1)
-            o = jnp.einsum("bhqk,bkhd->bqhd", att, v)
-            x = x + self._lin(blk["proj"],
-                              o.reshape(B, T, self.hidden))
-            h = self._ln(blk["ln2"], x)
-            x = x + self._lin(blk["fc2"],
-                              jax.nn.gelu(self._lin(blk["fc1"], h)))
-        return self._logits(params, self._ln(params["ln_f"], x))
+        causal = jnp.tril(jnp.ones((1, T, T), bool))
+        h, _ = self._blocks(
+            params, x,
+            lambda li, q, k, v: (self._attend(q, k, v, causal), None))
+        return self._logits(params, h)
 
     # -- step-wise decode (PR 12) ---------------------------------------------
     def _prefill_core(self, params, prompt, lengths):
         """Shared prompt forward: the exact math ``init_decode`` has always
-        run, factored out so the paged prefill (PR 18) reuses it and stays
-        BITWISE-identical to the monolithic path.  Returns ``(ks, vs,
-        logits0, lengths)`` with ``ks``/``vs`` per-layer (B, P, nh, hd)."""
-        prompt = jnp.asarray(prompt)
-        if prompt.ndim == 3 and prompt.shape[-1] == 1:
-            prompt = prompt[..., 0]
-        prompt = prompt.astype(jnp.int32)
+        run, shared with the paged prefill (PR 18) so the two stay
+        BITWISE-identical.  Returns ``(ks, vs, logits0, lengths)`` with
+        ``ks``/``vs`` per-layer (B, P, nh, hd)."""
+        prompt = self._ids(prompt)
         B, P = prompt.shape
         lengths = (jnp.full((B,), P, jnp.int32) if lengths is None
                    else jnp.asarray(lengths, jnp.int32))
-        nh, hd = self.n_head, self.hidden // self.n_head
         x = jnp.take(params["embed"], prompt, axis=0) + params["pos"][:P]
         pos_idx = jnp.arange(P)
         # causal within the prompt AND key < row length (padding masked)
         mask = (pos_idx[None, :, None] >= pos_idx[None, None, :]) \
             & (pos_idx[None, None, :] < lengths[:, None, None])  # (B,P,P)
-        ks, vs = [], []
-        for blk in params["blocks"]:
-            h = self._ln(blk["ln1"], x)
-            q, k, v = jnp.split(self._lin(blk["qkv"], h), 3, axis=-1)
-            q, k, v = self._heads(q), self._heads(k), self._heads(v)
-            scale = 1.0 / np.sqrt(hd)
-            att = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
-            att = jnp.where(mask[:, None], att, -1e30)
-            att = jax.nn.softmax(att, axis=-1)
-            o = jnp.einsum("bhqk,bkhd->bqhd", att, v)
-            x = x + self._lin(blk["proj"], o.reshape(B, P, self.hidden))
-            h2 = self._ln(blk["ln2"], x)
-            x = x + self._lin(blk["fc2"],
-                              jax.nn.gelu(self._lin(blk["fc1"], h2)))
-            ks.append(k)
-            vs.append(v)
-        h = self._ln(params["ln_f"], x)
-        # each row's next-token logits live at its LAST REAL position
-        last = jnp.take_along_axis(
-            h, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)[:, 0]
-        return ks, vs, self._logits(params, last), lengths
+        h, kv = self._blocks(
+            params, x,
+            lambda li, q, k, v: (self._attend(q, k, v, mask), (k, v)))
+        ks, vs = map(list, zip(*kv))
+        return ks, vs, self._last_logits(params, h, lengths), lengths
 
     def init_decode(self, params, prompt, lengths=None,
                     cache_len: Optional[int] = None):
@@ -313,10 +325,7 @@ class TransformerLM(Layer):
         length) are masked out of attention and overwritten later by
         generated tokens — the cache layout stays gap-free because the
         cursor starts AT the row's length."""
-        prompt = jnp.asarray(prompt)
-        if prompt.ndim == 3 and prompt.shape[-1] == 1:
-            prompt = prompt[..., 0]
-        B, P = prompt.shape
+        B, P = self._ids(prompt).shape
         C = int(cache_len) if cache_len is not None else int(P)
         if C < P:
             raise ValueError(f"cache_len={C} < prompt bucket {P}")
@@ -333,14 +342,6 @@ class TransformerLM(Layer):
                 jnp.zeros((B, C, nh, hd), jnp.float32).at[:, :P].set(v))
         return state, logits0
 
-    def prefill_kv(self, params, prompt, lengths=None):
-        """Paged prefill: the same prompt forward as ``init_decode`` but
-        WITHOUT allocating caches — returns ``(ks, vs, logits0)`` with
-        per-layer raw (B, P, nh, hd) K/V for the batcher's commit program
-        to quantize/scatter into pool blocks."""
-        ks, vs, logits0, _ = self._prefill_core(params, prompt, lengths)
-        return ks, vs, logits0
-
     def decode_step(self, params, state, tokens):
         """One token for every row: write K/V at the row cursor, attend
         over the written prefix, advance.  (B,)-shaped ``tokens`` in,
@@ -348,10 +349,8 @@ class TransformerLM(Layer):
         cache bucket, no retracing as rows churn."""
         tokens = jnp.asarray(tokens, jnp.int32)
         pos = state["pos"]                         # (B,) cursor
-        B = tokens.shape[0]
         C = state["k"][0].shape[1]
-        nh, hd = self.n_head, self.hidden // self.n_head
-        rows = jnp.arange(B)
+        rows = jnp.arange(tokens.shape[0])
         # clamp the cursor so a full cache row keeps overwriting its last
         # slot instead of indexing out of bounds (the scheduler retires
         # rows at capacity; this is the belt under that suspender)
@@ -359,80 +358,66 @@ class TransformerLM(Layer):
         x = jnp.take(params["embed"], tokens, axis=0) \
             + jnp.take(params["pos"], jnp.minimum(pos, self.max_len - 1),
                        axis=0)                     # (B, H)
-        new_k, new_v = [], []
         key_idx = jnp.arange(C)
-        for li, blk in enumerate(params["blocks"]):
-            h = self._ln(blk["ln1"], x)
-            q, k, v = jnp.split(self._lin(blk["qkv"], h), 3, axis=-1)
-            q, k, v = self._heads(q), self._heads(k), self._heads(v)
+
+        def attend(li, q, k, v):
             kc = state["k"][li].at[rows, wpos].set(k)
             vc = state["v"][li].at[rows, wpos].set(v)
-            scale = 1.0 / np.sqrt(hd)
+            scale = 1.0 / np.sqrt(q.shape[-1])
             att = jnp.einsum("bhd,bkhd->bhk", q, kc) * scale
             valid = key_idx[None] <= wpos[:, None]          # (B, C)
             att = jnp.where(valid[:, None], att, -1e30)
             att = jax.nn.softmax(att, axis=-1)
-            o = jnp.einsum("bhk,bkhd->bhd", att, vc)
-            x = x + self._lin(blk["proj"], o.reshape(B, self.hidden))
-            h2 = self._ln(blk["ln2"], x)
-            x = x + self._lin(blk["fc2"],
-                              jax.nn.gelu(self._lin(blk["fc1"], h2)))
-            new_k.append(kc)
-            new_v.append(vc)
-        logits = self._logits(params, self._ln(params["ln_f"], x))
-        return logits, {"pos": pos + 1, "k": new_k, "v": new_v}
+            return jnp.einsum("bhk,bkhd->bhd", att, vc), (kc, vc)
 
-    # -- paged KV pool (PR 18) ------------------------------------------------
+        h, kv = self._blocks(params, x, attend)
+        ks, vs = map(list, zip(*kv))
+        return self._logits(params, h), {"pos": pos + 1, "k": ks, "v": vs}
+
+    # -- the paged contract (PR 18, 30) ---------------------------------------
     def init_paged_pools(self, n_blocks: int, block_len: int,
                          max_active: int, kv_quant: str = "off"):
-        """Zeroed pool pytree for the paged batcher.  ``n_blocks`` counts
-        the TRASH block (row 0) — the allocator hands out ids 1..n-1.
-        Pool blocks are (block_len, hidden): heads and head_dim folded
-        into one lane axis (``ops/paged_attention`` layout).  int8 mode
-        adds per-(block, head) scale planes and per-slot f32 STAGING
-        buffers holding each row's active (partial) block exactly (kept
-        unfolded — ``kv_pack_int8`` reduces per head), so every append
-        re-quantizes from exact values."""
-        if kv_quant not in ("off", "int8"):
-            raise ValueError(f"kv_quant must be off|int8, got {kv_quant!r}")
-        nh, hd = self.n_head, self.hidden // self.n_head
-        L = self.n_layers
-        kdt = np.int8 if kv_quant == "int8" else np.float32
-        pools = {
-            "k": [np.zeros((n_blocks, block_len, self.hidden), kdt)
-                  for _ in range(L)],
-            "v": [np.zeros((n_blocks, block_len, self.hidden), kdt)
-                  for _ in range(L)],
-        }
-        if kv_quant == "int8":
-            pools["ks"] = [np.zeros((n_blocks, nh), np.float32)
-                           for _ in range(L)]
-            pools["vs"] = [np.zeros((n_blocks, nh), np.float32)
-                           for _ in range(L)]
-            pools["stk"] = [np.zeros((max_active, block_len, nh, hd),
-                                     np.float32) for _ in range(L)]
-            pools["stv"] = [np.zeros((max_active, block_len, nh, hd),
-                                     np.float32) for _ in range(L)]
-        return pools
+        """Zeroed state for the paged batcher (``n_blocks`` counts the
+        trash block): ``ops/paged_attention``'s pool at this model's
+        depth and heads."""
+        return paged.init_pools(self.n_layers, n_blocks, block_len,
+                                self.n_head, self.hidden // self.n_head,
+                                max_active, kv_quant)
 
-    def prefill_shared(self, params, suffix, lengths, prefix_len,
-                       prefix_k, prefix_v):
+    def paged_state_bytes(self, state):
+        """``state``'s bytes (arrays or their shapes) by accounting class
+        for the resource ledger."""
+        return paged.pool_bytes(state)
+
+    def prefill_paged(self, params, state, prompt, lengths, dest, slots, *,
+                      block_len: int, kv_quant: str = "off"):
+        """Paged prefill: ``init_decode``'s prompt forward, its raw K/V
+        committed to the pool blocks ``dest`` (rows, ceil(P / block_len))
+        names instead of parked in caches; ``slots`` (rows,) are the
+        decode slots the rows will run in.  Returns ``(state,
+        logits0)``."""
+        ks, vs, logits0, lengths = self._prefill_core(params, prompt,
+                                                      lengths)
+        return paged.pool_commit(state, ks, vs, lengths, dest, slots,
+                                 block_len=block_len,
+                                 kv_quant=kv_quant), logits0
+
+    def prefill_shared_paged(self, params, state, suffix, lengths,
+                             prefix_len, ptab, dest, slots, *,
+                             block_len: int, kv_quant: str = "off"):
         """Suffix-only prefill for prefix-cache hits: the shared prefix's
-        K/V (``prefix_k``/``prefix_v``, per-layer (B, PL, nh, hd) f32
-        gathered from the pool by the caller) joins attention as extra
-        keys, only the ``suffix`` tokens run through the stack.  Rows'
+        K/V, gathered from the pool blocks ``ptab`` (rows, n) names, joins
+        attention as extra keys; only the ``suffix`` tokens run through
+        the stack, and only their K/V is committed (to ``dest``).  Rows'
         true prefix lengths ``prefix_len`` (B,) mask the gather padding;
-        suffix positions embed at ``prefix_len + i``.  Returns ``(ks, vs,
-        logits0)`` — SUFFIX-only K/V for the commit program."""
-        suffix = jnp.asarray(suffix)
-        if suffix.ndim == 3 and suffix.shape[-1] == 1:
-            suffix = suffix[..., 0]
-        suffix = suffix.astype(jnp.int32)
+        suffix positions embed at ``prefix_len + i``; ``lengths`` are the
+        suffix lengths.  Returns ``(state, logits0)``."""
+        prefix_k, prefix_v = paged.pool_gather(state, ptab, self.n_head)
+        suffix = self._ids(suffix)
         B, S = suffix.shape
-        lengths = jnp.asarray(lengths, jnp.int32)        # suffix lengths
+        lengths = jnp.asarray(lengths, jnp.int32)
         prefix_len = jnp.asarray(prefix_len, jnp.int32)
         PL = prefix_k[0].shape[1]
-        nh, hd = self.n_head, self.hidden // self.n_head
         gpos = jnp.minimum(prefix_len[:, None] + jnp.arange(S),
                            self.max_len - 1)             # (B, S) global pos
         x = jnp.take(params["embed"], suffix, axis=0) \
@@ -446,96 +431,42 @@ class TransformerLM(Layer):
             & (qi[None, None, :] < lengths[:, None, None])   # (B, S, S)
         mask = jnp.concatenate(
             [jnp.broadcast_to(pmask, (B, S, PL)), smask], axis=2)
-        ks, vs = [], []
-        for li, blk in enumerate(params["blocks"]):
-            h = self._ln(blk["ln1"], x)
-            q, k, v = jnp.split(self._lin(blk["qkv"], h), 3, axis=-1)
-            q, k, v = self._heads(q), self._heads(k), self._heads(v)
-            kk = jnp.concatenate(
-                [prefix_k[li].astype(jnp.float32), k], axis=1)
-            vv = jnp.concatenate(
-                [prefix_v[li].astype(jnp.float32), v], axis=1)
-            scale = 1.0 / np.sqrt(hd)
-            att = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * scale
-            att = jnp.where(mask[:, None], att, -1e30)
-            att = jax.nn.softmax(att, axis=-1)
-            o = jnp.einsum("bhqk,bkhd->bqhd", att, vv)
-            x = x + self._lin(blk["proj"], o.reshape(B, S, self.hidden))
-            h2 = self._ln(blk["ln2"], x)
-            x = x + self._lin(blk["fc2"],
-                              jax.nn.gelu(self._lin(blk["fc1"], h2)))
-            ks.append(k)
-            vs.append(v)
-        h = self._ln(params["ln_f"], x)
-        last = jnp.take_along_axis(
-            h, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)[:, 0]
-        return ks, vs, self._logits(params, last)
+
+        def attend(li, q, k, v):
+            kk = jnp.concatenate([prefix_k[li], k], axis=1)
+            vv = jnp.concatenate([prefix_v[li], v], axis=1)
+            return self._attend(q, kk, vv, mask), (k, v)
+
+        h, kv = self._blocks(params, x, attend)
+        ks, vs = map(list, zip(*kv))
+        logits0 = self._last_logits(params, h, lengths)
+        return paged.pool_commit(state, ks, vs, lengths, dest, slots,
+                                 block_len=block_len,
+                                 kv_quant=kv_quant), logits0
 
     def decode_paged(self, params, pstate, block_tables, pos, tokens, *,
                      block_len: int, kv_quant: str = "off", impl=None):
         """One token per row against the block pool: ``decode_step``'s
         math with the cache write routed through each row's block table
-        and the read through ``ops/paged_attention``.  Inactive rows point
+        and the read through the ``paged_attention`` kernel
+        (``ops/paged_attention.pool_append_attend``).  Inactive rows point
         their whole table at the trash block, so their writes land
-        harmlessly.  int8 mode re-packs the row's ACTIVE block from its
-        exact f32 staging copy every step (values quantize once, from
-        exact inputs) and scatters block + scale into the pool.  Returns
-        ``(logits, new_pstate)`` — the caller advances ``pos``."""
+        harmlessly.  Returns ``(logits, new_pstate)`` — the caller
+        advances ``pos``."""
         tokens = jnp.asarray(tokens, jnp.int32)
         pos = jnp.asarray(pos, jnp.int32)
         bt = jnp.asarray(block_tables, jnp.int32)
-        A = tokens.shape[0]
-        T = bt.shape[1]
-        bl = int(block_len)
-        rows = jnp.arange(A)
-        off = pos % bl
-        # clamp like decode_step's cursor: an overshooting row keeps
-        # rewriting its last table entry instead of indexing out of range
-        cur = bt[rows, jnp.minimum(pos // bl, T - 1)]     # (A,) physical id
+        cursor = paged.pool_cursor(bt, pos, int(block_len))
         x = jnp.take(params["embed"], tokens, axis=0) \
             + jnp.take(params["pos"], jnp.minimum(pos, self.max_len - 1),
                        axis=0)
-        quant = kv_quant == "int8"
-        new = {key: [] for key in pstate}
-        for li, blk in enumerate(params["blocks"]):
-            h = self._ln(blk["ln1"], x)
-            q, k, v = jnp.split(self._lin(blk["qkv"], h), 3, axis=-1)
-            q, k, v = self._heads(q), self._heads(k), self._heads(v)
-            if quant:
-                # staging reset on block rollover (off == 0), then append
-                keep = (off != 0)[:, None, None, None]
-                stk = jnp.where(keep, pstate["stk"][li], 0.0) \
-                    .at[rows, off].set(k)
-                stv = jnp.where(keep, pstate["stv"][li], 0.0) \
-                    .at[rows, off].set(v)
-                qk, sk = kv_pack_int8(stk)                # (A,bl,nh,hd)
-                qv, sv = kv_pack_int8(stv)
-                kp = pstate["k"][li].at[cur].set(
-                    qk.reshape(A, bl, self.hidden))
-                vp = pstate["v"][li].at[cur].set(
-                    qv.reshape(A, bl, self.hidden))
-                ksc = pstate["ks"][li].at[cur].set(sk)
-                vsc = pstate["vs"][li].at[cur].set(sv)
-                o = paged_attention(q, kp, vp, bt, pos + 1, ksc, vsc,
-                                    impl=impl)
-                new["ks"].append(ksc)
-                new["vs"].append(vsc)
-                new["stk"].append(stk)
-                new["stv"].append(stv)
-            else:
-                kp = pstate["k"][li].at[cur, off].set(
-                    k.reshape(A, self.hidden))
-                vp = pstate["v"][li].at[cur, off].set(
-                    v.reshape(A, self.hidden))
-                o = paged_attention(q, kp, vp, bt, pos + 1, impl=impl)
-            new["k"].append(kp)
-            new["v"].append(vp)
-            x = x + self._lin(blk["proj"], o.reshape(A, self.hidden))
-            h2 = self._ln(blk["ln2"], x)
-            x = x + self._lin(blk["fc2"],
-                              jax.nn.gelu(self._lin(blk["fc1"], h2)))
-        logits = self._logits(params, self._ln(params["ln_f"], x))
-        return logits, new
+        h, layers = self._blocks(
+            params, x,
+            lambda li, q, k, v: paged.pool_append_attend(
+                pstate, li, q, k, v, cursor, bt, pos, kv_quant=kv_quant,
+                impl=impl))
+        return self._logits(params, h), {
+            name: [leaves[name] for leaves in layers] for name in pstate}
 
     # -- monolithic greedy rollout (batch-in/batch-out baseline) --------------
     def generate(self, params, prompt, max_tokens: int = 32,

@@ -3,8 +3,8 @@
 The continuous batcher's monolithic per-slot KV lanes become a fixed pool
 of ``(n_blocks, block_len, heads * head_dim)`` buffers; each decode row
 owns a BLOCK TABLE mapping its logical cache blocks to physical pool
-blocks (the vLLM paged-attention layout).  This module is the read side:
-one query token per row attends over the row's table-mapped blocks.
+blocks (the vLLM paged-attention layout).  This module owns that pool's
+DEVICE FORMAT: first the read side, then (``init_pools`` on) what writes it.
 
 Pool layout: heads and head_dim are FOLDED into one trailing axis, so a
 pool block is a (block_len, heads * head_dim) tile whose lane dimension is
@@ -58,14 +58,14 @@ the parity tests.
 
 Quantization contract: ``inference/quantize.kv_pack_int8`` /
 ``kv_unpack_int8`` (symmetric, scale = per-(block, head) absmax / 127) —
-the ONE contract shared with the decode append path and the prefill
-commit program.
+the ONE contract the kernel above all, and the append and the commit at
+the end of this file, share.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -73,7 +73,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from analytics_zoo_tpu.inference.quantize import kv_unpack_int8
+from analytics_zoo_tpu.inference.quantize import kv_pack_int8, kv_unpack_int8
 from analytics_zoo_tpu.ops.dispatch import resolve_impl
 
 NEG_INF = -1e30
@@ -401,3 +401,162 @@ def paged_attention(q, k_pool, v_pool, block_tables, lengths,
                                    lengths, k_scale, v_scale)
     return _paged_pallas(q, k_pool, v_pool, block_tables, lengths,
                          k_scale, v_scale, interpret=(mode == "interpret"))
+
+
+# -- the pool's device format: the write side ---------------------------------
+#
+# One owner (PR 30): a model builds its paged contract from these
+# (``models/textmodels.TransformerLM``), and the scheduler
+# (``serving/generate``) hands the pool through as an opaque pytree, never
+# naming a leaf.  ``init_pools`` (the zeroed pool), ``pool_commit`` (a
+# prefill batch's K/V into blocks), ``pool_gather`` (prefix blocks back out
+# as float32 K/V), ``pool_cursor`` + ``pool_append_attend`` (one decode token
+# a row: append, then attend), ``pool_bytes`` (each leaf's accounting class).
+#
+# A pool is a dict of per-layer lists.  ``k`` / ``v``: (n_blocks, block_len,
+# heads * head_dim) blocks, float32 or int8; block 0 is the TRASH block
+# (padding rows and inactive slots write there).  int8 pools add ``ks`` /
+# ``vs``, the (n_blocks, heads) float32 scale planes, and ``stk`` / ``stv``,
+# per-slot (max_active, block_len, heads, head_dim) float32 STAGING copies
+# of each row's active (partial) block, kept unfolded (``kv_pack_int8``
+# reduces per head) so that every append re-quantizes the block from exact
+# values.
+#
+_LEAF_CLASS = {"k": "paged_pool", "v": "paged_pool",
+               "ks": "scales", "vs": "scales",
+               "stk": "lanes", "stv": "lanes"}
+
+
+def init_pools(n_layers: int, n_blocks: int, block_len: int, n_head: int,
+               head_dim: int, max_active: int, kv_quant: str = "off"):
+    """Zeroed host-side pool pytree.  ``n_blocks`` counts the trash block
+    (row 0): an allocator hands out ids 1..n-1."""
+    if kv_quant not in ("off", "int8"):
+        raise ValueError(f"kv_quant must be off|int8, got {kv_quant!r}")
+    quant = kv_quant == "int8"
+    shapes = {"k": ((n_blocks, block_len, n_head * head_dim),
+                    np.int8 if quant else np.float32)}
+    shapes["v"] = shapes["k"]
+    if quant:
+        shapes["ks"] = shapes["vs"] = ((n_blocks, n_head), np.float32)
+        shapes["stk"] = shapes["stv"] = (
+            (max_active, block_len, n_head, head_dim), np.float32)
+    return {name: [np.zeros(shape, dt) for _ in range(n_layers)]
+            for name, (shape, dt) in shapes.items()}
+
+
+def pool_bytes(pools) -> Dict[str, int]:
+    """Bytes of a pool (or of its ``ShapeDtypeStruct`` tree) by accounting
+    class: ``paged_pool`` (the KV blocks), ``scales`` (int8 scale planes),
+    ``lanes`` (per-slot staging buffers)."""
+    out = {"paged_pool": 0, "scales": 0, "lanes": 0}
+    for name, leaves in pools.items():
+        out[_LEAF_CLASS[name]] += sum(
+            int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
+            for leaf in leaves)
+    return out
+
+
+def pool_commit(pools, ks, vs, lengths, dest, slots, *, block_len: int,
+                kv_quant: str = "off"):
+    """Scatter a prefill batch's (length-masked) K/V into pool blocks:
+    ``ks`` / ``vs`` are per-layer (rows, P, heads, head_dim) float32; row
+    j's block t lands at pool id ``dest[j, t]`` (0 = trash, for padding
+    rows and blocks past the row's fill).  int8 mode quantizes per block
+    and parks each row's partial TAIL block in its slot's staging buffer
+    (``slots``; the sentinel ``max_active`` drops padding rows), so decode
+    appends re-quantize from exact values.  Returns the new pool."""
+    bl = int(block_len)
+    npb = dest.shape[1]
+    bb, pb, nh, hd = ks[0].shape
+    pad = npb * bl
+    valid = (jnp.arange(pb)[None, :] < lengths[:, None])[..., None, None]
+    out = {name: list(leaves) for name, leaves in pools.items()}
+    tb = jnp.minimum(lengths // bl, npb - 1)
+    tsel = tb[:, None, None, None, None]
+    fold = (bb, npb, bl, nh * hd)
+    for li in range(len(ks)):
+        k = jnp.where(valid, ks[li], 0.0)
+        v = jnp.where(valid, vs[li], 0.0)
+        if pad > pb:
+            z = jnp.zeros((bb, pad - pb, nh, hd), jnp.float32)
+            k = jnp.concatenate([k, z], axis=1)
+            v = jnp.concatenate([v, z], axis=1)
+        kb = k.reshape(bb, npb, bl, nh, hd)
+        vb = v.reshape(bb, npb, bl, nh, hd)
+        if kv_quant == "int8":
+            qk, sk = kv_pack_int8(kb)
+            qv, sv = kv_pack_int8(vb)
+            out["k"][li] = out["k"][li].at[dest].set(qk.reshape(fold))
+            out["v"][li] = out["v"][li].at[dest].set(qv.reshape(fold))
+            out["ks"][li] = out["ks"][li].at[dest].set(sk)
+            out["vs"][li] = out["vs"][li].at[dest].set(sv)
+            tk = jnp.take_along_axis(kb, tsel, axis=1)[:, 0]
+            tv = jnp.take_along_axis(vb, tsel, axis=1)[:, 0]
+            out["stk"][li] = out["stk"][li].at[slots].set(tk, mode="drop")
+            out["stv"][li] = out["stv"][li].at[slots].set(tv, mode="drop")
+        else:
+            out["k"][li] = out["k"][li].at[dest].set(kb.reshape(fold))
+            out["v"][li] = out["v"][li].at[dest].set(vb.reshape(fold))
+    return out
+
+
+def pool_gather(pools, tables, n_head: int):
+    """The blocks ``tables`` (rows, n) names, back out of the pool as
+    float32 K/V in logical order: two per-layer lists of (rows, n *
+    block_len, heads, head_dim), dequantized when the pool is int8 —
+    what a suffix-only prefill attends its shared prefix through."""
+    def layer(name, scales, li):
+        blocks = _gather_dequant(pools[name][li],
+                                 pools[scales][li] if scales in pools
+                                 else None, tables, n_head)
+        A, T, bl, nh, hd = blocks.shape
+        return blocks.reshape(A, T * bl, nh, hd)
+
+    n_layers = len(pools["k"])
+    return ([layer("k", "ks", li) for li in range(n_layers)],
+            [layer("v", "vs", li) for li in range(n_layers)])
+
+
+def pool_cursor(block_tables, pos, block_len: int):
+    """Where each row's next token lands: ``(rows, cur, off)`` = row ids,
+    the physical block under the cursor, the offset inside it.  A cursor
+    past the table's end keeps rewriting its last entry instead of indexing
+    out of range (inactive rows point their whole table at the trash
+    block)."""
+    rows = jnp.arange(pos.shape[0])
+    off = pos % block_len
+    cur = block_tables[rows, jnp.minimum(pos // block_len,
+                                         block_tables.shape[1] - 1)]
+    return rows, cur, off
+
+
+def pool_append_attend(pools, li: int, q, k, v, cursor, block_tables, pos,
+                       *, kv_quant: str = "off",
+                       impl: Optional[str] = None):
+    """Layer ``li`` of one decode step: append the token's ``k`` / ``v``
+    (rows, heads, head_dim) at ``cursor`` (``pool_cursor``), then attend
+    ``q`` over the row's table.  int8 mode re-packs the row's ACTIVE block
+    from its exact float32 staging copy (reset on block rollover), so a
+    value is quantized once, from exact inputs.  Returns ``(o, leaves)``:
+    the attention output and the layer's new leaf of every pool part."""
+    rows, cur, off = cursor
+    A, nh, hd = k.shape
+    if kv_quant == "int8":
+        keep = (off != 0)[:, None, None, None]
+        stk = jnp.where(keep, pools["stk"][li], 0.0).at[rows, off].set(k)
+        stv = jnp.where(keep, pools["stv"][li], 0.0).at[rows, off].set(v)
+        qk, sk = kv_pack_int8(stk)                    # (A, bl, nh, hd)
+        qv, sv = kv_pack_int8(stv)
+        fold = (A, stk.shape[1], nh * hd)
+        new = {"k": pools["k"][li].at[cur].set(qk.reshape(fold)),
+               "v": pools["v"][li].at[cur].set(qv.reshape(fold)),
+               "ks": pools["ks"][li].at[cur].set(sk),
+               "vs": pools["vs"][li].at[cur].set(sv),
+               "stk": stk, "stv": stv}
+    else:
+        new = {"k": pools["k"][li].at[cur, off].set(k.reshape(A, nh * hd)),
+               "v": pools["v"][li].at[cur, off].set(v.reshape(A, nh * hd))}
+    o = paged_attention(q, new["k"], new["v"], block_tables, pos + 1,
+                        new.get("ks"), new.get("vs"), impl=impl)
+    return o, new

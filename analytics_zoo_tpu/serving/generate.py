@@ -37,6 +37,13 @@ models/textmodels.py):
   the runtime took the buffers leaves nothing to retry on:
   ``_rebuild_pool`` ends the lane's requests and starts from a zeroed
   pool.
+- **one seam to the model** (PR 30) — a paged model's state is an
+  opaque pytree here: the model creates it, commits prefills into it,
+  decodes against it and sorts its bytes for the ledger
+  (``_PAGED_CONTRACT``); its device format belongs to
+  ``ops/paged_attention``.  The scheduler owns slots, blocks and time.
+  Each paged program's argument tuple is written once (``_paged_args``),
+  for lowering and for the call.
 - **mesh placement** — lane state buffers are committed with a
   ``NamedSharding`` over the PR 6 serving mesh when the model is sharded
   (slot axis over ``data`` when it divides, replicated otherwise), so the
@@ -131,7 +138,7 @@ class GenerationParams:
       instead of per-slot monolithic lanes; each slot holds a block
       table, admission is bounded by free blocks, and prompts sharing a
       registered prefix share its resident pages.  Needs a model with
-      the paged decode API (``models/textmodels.TransformerLM``).
+      the paged contract (``models/textmodels.TransformerLM``).
     - ``block_len`` — tokens per pool block (pow-2).
     - ``pool_blocks`` — usable pool blocks (default: enough for every
       slot at full lane capacity, i.e. ``max_active_slots * bucket /
@@ -350,8 +357,14 @@ class ContinuousBatcher:
     ``serving-generate`` worker)."""
 
     MAX_WAITING = 1024
-    # programs that take a paged lane's pool and return its successor
+    # programs that take a paged lane's pool and return its successor, in
+    # the order ``_paged_fns`` returns their jit functions
     _POOL_PROGRAMS = ("pprefill", "pshared", "pdecode")
+    # what ``paged=True`` asks of a model: the pool is ITS state, opaque
+    # here (``models/textmodels.TransformerLM`` documents the contract)
+    _PAGED_CONTRACT = ("init_paged_pools", "prefill_paged",
+                       "prefill_shared_paged", "decode_paged",
+                       "paged_state_bytes")
 
     def __init__(self, model, gen: GenerationParams):
         inner = getattr(model, "_model", None)
@@ -399,14 +412,12 @@ class ContinuousBatcher:
         self.pool_rebuilds = 0       # pools lost to a failed donated call
         self._exhausted_boundary = False
         if gen.paged:
-            missing = [m for m in ("prefill_kv", "prefill_shared",
-                                   "decode_paged", "init_paged_pools",
-                                   "n_head")
+            missing = [m for m in self._PAGED_CONTRACT
                        if not hasattr(inner, m)]
             if missing:
                 raise ValueError(
                     "generation.paged=true needs a model with the paged "
-                    "decode API (models/textmodels.TransformerLM); "
+                    "contract (models/textmodels.TransformerLM); "
                     f"missing: {missing}")
             bucket = max(usable)
             if gen.block_len > bucket:
@@ -546,108 +557,37 @@ class ContinuousBatcher:
         return fns
 
     def _paged_fns(self):
-        """The three paged-mode jit functions (PR 18): ``pprefill``
-        (prompt forward + block commit in ONE program, so raw prompt K/V
-        never leaves the device), ``pshared`` (suffix-only prefill over
-        pool-resident prefix blocks + commit) and ``pdecode``
-        (decode_quantum paged decode steps under one scan)."""
+        """The three paged-mode jit functions (PR 18), thin closures over
+        the model's paged contract: ``pprefill`` (prompt forward + block
+        commit in ONE program, so raw prompt K/V never leaves the device),
+        ``pshared`` (suffix-only prefill over pool-resident prefix blocks
+        + commit) and ``pdecode`` (decode_quantum paged decode steps under
+        one scan).  ``pools`` is the model's state, opaque here."""
         key = ("pfns",)
         fns = self._programs.get(key)
         if fns is not None:
             return fns
         import jax
         import jax.numpy as jnp
-        from analytics_zoo_tpu.inference.quantize import (kv_pack_int8,
-                                                          kv_unpack_int8)
         inner = self.inner
-        bl = self.gen.block_len
-        kq = self.gen.kv_quant
+        fmt = dict(block_len=self.gen.block_len, kv_quant=self.gen.kv_quant)
         K = self.gen.decode_quantum
 
-        def commit(pools, ks, vs, lengths, dest, slots):
-            """Scatter the batch's (length-masked) K/V into pool blocks:
-            row j's block t lands at pool id ``dest[j, t]`` (0 = trash,
-            for padding rows and blocks past the row's fill).  int8 mode
-            quantizes per block and parks each row's partial TAIL block
-            in its slot's f32 staging buffer (``slots``; the sentinel
-            ``max_active`` drops padding rows), so decode appends
-            re-quantize from exact values."""
-            npb = dest.shape[1]
-            bb, pb, nh, hd = ks[0].shape
-            pad = npb * bl
-            valid = (jnp.arange(pb)[None, :]
-                     < lengths[:, None])[..., None, None]
-            out = {k2: list(v2) for k2, v2 in pools.items()}
-            tb = jnp.minimum(lengths // bl, npb - 1)
-            tsel = tb[:, None, None, None, None]
-            for li in range(len(ks)):
-                k = jnp.where(valid, ks[li], 0.0)
-                v = jnp.where(valid, vs[li], 0.0)
-                if pad > pb:
-                    z = jnp.zeros((bb, pad - pb, nh, hd), jnp.float32)
-                    k = jnp.concatenate([k, z], axis=1)
-                    v = jnp.concatenate([v, z], axis=1)
-                kb = k.reshape(bb, npb, bl, nh, hd)
-                vb = v.reshape(bb, npb, bl, nh, hd)
-                # pool blocks fold (nh, hd) into one lane axis
-                # (ops/paged_attention layout)
-                fold = (bb, npb, bl, nh * hd)
-                if kq == "int8":
-                    qk, sk = kv_pack_int8(kb)
-                    qv, sv = kv_pack_int8(vb)
-                    out["k"][li] = out["k"][li].at[dest].set(
-                        qk.reshape(fold))
-                    out["v"][li] = out["v"][li].at[dest].set(
-                        qv.reshape(fold))
-                    out["ks"][li] = out["ks"][li].at[dest].set(sk)
-                    out["vs"][li] = out["vs"][li].at[dest].set(sv)
-                    tk = jnp.take_along_axis(kb, tsel, axis=1)[:, 0]
-                    tv = jnp.take_along_axis(vb, tsel, axis=1)[:, 0]
-                    out["stk"][li] = out["stk"][li].at[slots].set(
-                        tk, mode="drop")
-                    out["stv"][li] = out["stv"][li].at[slots].set(
-                        tv, mode="drop")
-                else:
-                    out["k"][li] = out["k"][li].at[dest].set(
-                        kb.reshape(fold))
-                    out["v"][li] = out["v"][li].at[dest].set(
-                        vb.reshape(fold))
-            return out
-
         def pprefill(p, prompt, lengths, pools, dest, slots):
-            ks, vs, logits0 = inner.prefill_kv(p, prompt, lengths)
-            return commit(pools, ks, vs, lengths, dest, slots), logits0
+            return inner.prefill_paged(p, pools, prompt, lengths, dest,
+                                       slots, **fmt)
 
         def pshared(p, suffix, slens, prefix_len, ptab, pools, dest,
                     slots):
-            npb = ptab.shape[1]
-            bb = suffix.shape[0]
-            nh = inner.n_head
-            pk, pv = [], []
-            for li in range(len(pools["k"])):
-                # gather the prefix blocks and unfold their lane axis
-                k = jnp.take(pools["k"][li], ptab, axis=0) \
-                    .reshape(bb, npb, bl, nh, -1)
-                v = jnp.take(pools["v"][li], ptab, axis=0) \
-                    .reshape(bb, npb, bl, nh, -1)
-                if kq == "int8":
-                    k = kv_unpack_int8(
-                        k, jnp.take(pools["ks"][li], ptab, axis=0))
-                    v = kv_unpack_int8(
-                        v, jnp.take(pools["vs"][li], ptab, axis=0))
-                pk.append(k.astype(jnp.float32)
-                          .reshape(bb, npb * bl, nh, -1))
-                pv.append(v.astype(jnp.float32)
-                          .reshape(bb, npb * bl, nh, -1))
-            ks, vs, logits0 = inner.prefill_shared(p, suffix, slens,
-                                                   prefix_len, pk, pv)
-            return commit(pools, ks, vs, slens, dest, slots), logits0
+            return inner.prefill_shared_paged(p, pools, suffix, slens,
+                                              prefix_len, ptab, dest,
+                                              slots, **fmt)
 
         def pdecode(p, pools, tables, pos, tokens):
             def body(carry, _):
                 pl_, po_, tok = carry
-                logits, pl2 = inner.decode_paged(
-                    p, pl_, tables, po_, tok, block_len=bl, kv_quant=kq)
+                logits, pl2 = inner.decode_paged(p, pl_, tables, po_, tok,
+                                                 **fmt)
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 return (pl2, po_ + 1, nxt), nxt
 
@@ -671,12 +611,13 @@ class ContinuousBatcher:
         self._programs[key] = fns
         return fns
 
-    def _compiled(self, key: tuple, fn, *args):
+    def _compiled(self, key: tuple, lane: _Lane):
         """AOT-compiled executable for one fixed-shape program, compiled
         exactly once; ``warm()`` walks the same path, so a warmed program
         is the very executable the hot path runs."""
         exe = self._programs.get(key)
         if exe is None:
+            fn, args = self._lowering(key, lane)
             exe = fn.lower(*args).compile()
             if key[0] in self._POOL_PROGRAMS:
                 self._alias_bytes[key] = int(
@@ -684,6 +625,76 @@ class ContinuousBatcher:
             self._programs[key] = exe
             self.compiles += 1
         return exe
+
+    def _lowering(self, key: tuple, lane: _Lane):
+        """Program ``key``'s jit function and the arguments it is lowered
+        from.  A paged program lowers from the very tuple its call passes
+        (``_paged_args``) over the pool's SHAPES: a live pool may be
+        mid-call on the generate thread, donated and deleted."""
+        import jax
+        kind = key[0]
+        if kind in self._POOL_PROGRAMS:
+            fn = self._paged_fns()[self._POOL_PROGRAMS.index(kind)]
+            return fn, self._paged_args(key, lane, lane.state_shapes)
+        prefill, step, insert = self._lane_fns(lane)
+        if kind == "prefill":
+            _, bb, pb, _ = key
+            return prefill, (self._params(), np.zeros((bb, pb), np.int32),
+                             np.ones((bb,), np.int32))
+        if kind == "decode_step":
+            return step, (self._params(), lane.state, lane.tokens)
+        # insert: the prefilled sub-state it copies rows from, derived
+        # abstractly (its shapes do not depend on the prompt bucket)
+        shapes = jax.eval_shape(
+            prefill, self._params(),
+            jax.ShapeDtypeStruct((key[1], self.gen.prefill_buckets[0]),
+                                 np.int32),
+            jax.ShapeDtypeStruct((key[1],), np.int32))
+        sub = shapes[0] if self._is_pair(shapes) else shapes
+        return insert, (lane.state, sub, np.int32(0), np.int32(0))
+
+    def _paged_args(self, key: tuple, lane: "_PagedLane", state,
+                    members=()) -> tuple:
+        """The argument tuple of paged program ``key``, written once: the
+        program is lowered from it (``state`` = the pool's shapes, no
+        ``members``: a batch of zeros) and called with it (the live pool,
+        the admission group's ``(req, slot, resv)`` members).  A prefill
+        batch: right-padded prompts (for ``pshared`` the suffixes, with
+        each row's prefix length and prefix block table), their lengths,
+        the pool blocks each row's K/V lands in (``dest``; 0 = the trash
+        block) and the rows' decode slots (``max_active`` = the sentinel
+        that drops a padding row)."""
+        if key[0] == "pdecode":
+            return (self._params(), state, lane.tables, lane.pos,
+                    lane.tokens)
+        _, bb, pb, *npb = key
+        bl = self.gen.block_len
+        padded = np.zeros((bb, pb), np.int32)
+        lengths = np.ones((bb,), np.int32)
+        dest = np.zeros((bb, (pb + bl - 1) // bl), np.int32)
+        slots = np.full((bb,), lane.max_active, np.int32)
+        prefix = (np.zeros((bb,), np.int32),
+                  np.zeros((bb, npb[0]), np.int32)) if npb else ()
+        for j, (req, slot, (ksh, shared_ids, priv, _)) in enumerate(members):
+            # on a prefix hit only the suffix is prefilled, into the
+            # blocks behind the ksh shared ones
+            suffix = self._concat_prompt(req)[ksh * bl:]
+            padded[j, :suffix.size] = suffix
+            lengths[j] = suffix.size
+            if prefix:
+                prefix[0][j] = ksh * bl
+                prefix[1][j, :ksh] = shared_ids
+            nfill = (suffix.size + bl - 1) // bl
+            dest[j, :nfill] = priv[:nfill]
+            slots[j] = slot
+        if members:
+            # padding rows replicate row 0's prompt; their dest stays at
+            # the trash block and their slot at the drop sentinel, so
+            # nothing they compute is ever committed
+            for a in (padded, lengths, *prefix):
+                a[len(members):] = a[0]
+        return (self._params(), padded, lengths, *prefix, state, dest,
+                slots)
 
     @staticmethod
     def _program_name(key: tuple) -> str:
@@ -801,13 +812,9 @@ class ContinuousBatcher:
         for slot, info in enumerate(lane.slots):
             if info is None:
                 continue
-            req = info.req
-            self.quarantined += 1
             ended += 1
-            events.append(GenEvent(
-                "quarantine", req.rid, trace_id=req.trace_id,
-                error=f"KV pool lost in a failed call: {error}",
-                t_read=req.t_read, tenant=req.tenant))
+            self._quarantine(
+                info.req, f"KV pool lost in a failed call: {error}", events)
             self._free(lane, slot)
         if self._prefix is not None:
             self._prefix.clear()
@@ -827,6 +834,14 @@ class ContinuousBatcher:
                 and getattr(res[1], "ndim", 0) == 2)
 
     # -- admission ------------------------------------------------------------
+    def _quarantine(self, req: GenRequest, error: str,
+                    events: List[GenEvent]) -> None:
+        """End ``req`` alone, with the error its client will read."""
+        self.quarantined += 1
+        events.append(GenEvent(
+            "quarantine", req.rid, trace_id=req.trace_id, error=error,
+            t_read=req.t_read, tenant=req.tenant))
+
     def submit(self, req: GenRequest) -> bool:
         """Queue one request for the next step boundary.  False = waiting
         room full (caller should leave the record staged / backpressure)."""
@@ -1015,11 +1030,9 @@ class ContinuousBatcher:
             lengths[j] = lengths[0]
         self.prefill_positions_real += int(lengths[:n].sum())
         self.prefill_positions_padded += bb * pb
-        prefill, _, insert = self._lane_fns(lane)
         try:
             self._ensure_lane_state(lane)
-            exe = self._compiled(("prefill", bb, pb, lane.bucket), prefill,
-                                 self._params(), padded, lengths)
+            exe = self._compiled(("prefill", bb, pb, lane.bucket), lane)
             res = exe(self._params(), padded, lengths)
             self._count_exec(("prefill", bb, pb, lane.bucket))
             if self._is_pair(res):
@@ -1032,16 +1045,11 @@ class ContinuousBatcher:
                 toks0 = logits0.argmax(axis=-1)
             else:
                 sub, toks0 = res, None
-            ins = self._compiled(("insert", bb, lane.bucket), insert,
-                                 lane.state, sub, np.int32(0), np.int32(0))
+            ins = self._compiled(("insert", bb, lane.bucket), lane)
         except Exception as e:  # noqa: BLE001 — batch-level failure
             if n == 1:
                 req, slot = members[0]
-                self.quarantined += 1
-                events.append(GenEvent(
-                    "quarantine", req.rid, trace_id=req.trace_id,
-                    error=f"{type(e).__name__}: {e}", t_read=req.t_read,
-                    tenant=req.tenant))
+                self._quarantine(req, f"{type(e).__name__}: {e}", events)
                 lane.free.append(slot)
                 return 0
             # isolate the poison: singleton admissions, per-slot blast
@@ -1055,11 +1063,7 @@ class ContinuousBatcher:
                                  np.int32(slot))
                 self._count_exec(("insert", bb, lane.bucket))
             except Exception as e:  # noqa: BLE001 — per-row insert failure
-                self.quarantined += 1
-                events.append(GenEvent(
-                    "quarantine", req.rid, trace_id=req.trace_id,
-                    error=f"{type(e).__name__}: {e}", t_read=req.t_read,
-                    tenant=req.tenant))
+                self._quarantine(req, f"{type(e).__name__}: {e}", events)
                 lane.free.append(slot)
                 continue
             info = _Slot(req, budget=self._budget_for(req, lane))
@@ -1128,74 +1132,25 @@ class ContinuousBatcher:
         if priv:
             self._pool.release(priv)
 
-    def _admit_paged(self, events: List[GenEvent]) -> int:
-        """Paged admission: like ``_admit`` but gated on pool blocks as
-        well as free slots, grouped into prefix-MISS batches (full
-        prefill, one program per (batch, prompt bucket)) and prefix-HIT
-        batches (suffix-only prefill, one program per (batch, suffix
-        bucket, prefix-table bucket))."""
+    def _admit_paged(self, grabbed, events: List[GenEvent]) -> int:
+        """Paged admission of what ``_grab`` claimed (slots AND pool
+        blocks): grouped into prefix-MISS batches (full prefill, one
+        program per (batch, prompt bucket)) and prefix-HIT batches
+        (suffix-only prefill, one program per (batch, suffix bucket,
+        prefix-table bucket))."""
         lane: _PagedLane = self._lanes[0]
         bl = self.gen.block_len
-        grabbed: List[tuple] = []        # (req, slot, resv)
-        while True:
-            with self._waiting_lock:
-                req = self._waiting.popleft() if self._waiting else None
-            if req is None:
-                break
-            req.t_admit = time.monotonic()   # a requeue stamps it again
-            if self._expired(req.deadline_ns):
-                self.shed += 1
-                events.append(GenEvent(
-                    "shed", req.rid, trace_id=req.trace_id,
-                    t_read=req.t_read, tenant=req.tenant))
-                continue
-            err = self._validate(req)
-            if err is not None:
-                self.quarantined += 1
-                events.append(GenEvent(
-                    "quarantine", req.rid, trace_id=req.trace_id,
-                    error=f"ValueError: {err}", t_read=req.t_read,
-                    tenant=req.tenant))
-                continue
-            if req.resume_tokens:
-                self._take_resume(req, events)
-            if self._pick_lane(req) is None and req.resume_tokens:
-                self._downgrade_resume(
-                    req, "resume prefix exceeds lane capacity", events)
-            if self._pick_lane(req) is None:
-                self.quarantined += 1
-                events.append(GenEvent(
-                    "quarantine", req.rid, trace_id=req.trace_id,
-                    error="ValueError: no decode lane holds prompt + "
-                          f"max_tokens (buckets {self.gen.bucket_lens})",
-                    t_read=req.t_read, tenant=req.tenant))
-                continue
-            if not lane.free:
-                with self._waiting_lock:
-                    self._waiting.appendleft(req)
-                break
-            resv = self._reserve(lane, req)
-            if resv is None:
-                with self._waiting_lock:
-                    self._waiting.appendleft(req)
-                break
-            grabbed.append((req, lane.free.popleft(), resv))
-        if not grabbed:
-            return 0
         miss: Dict[int, list] = {}
         hit: Dict[tuple, list] = {}
-        for req, slot, resv in grabbed:
+        for req, _, slot, resv in grabbed:
             ksh, _, _, plen = resv
             pb = self._prefill_bucket(plen - ksh * bl)
             if pb is None:               # defensive, as in _admit
                 self._release_resv(resv)
-                self.quarantined += 1
-                events.append(GenEvent(
-                    "quarantine", req.rid, trace_id=req.trace_id,
-                    error=f"ValueError: no prefill bucket holds prompt "
-                          f"length {plen} (buckets "
-                          f"{self.gen.prefill_buckets})",
-                    t_read=req.t_read, tenant=req.tenant))
+                self._quarantine(
+                    req, f"ValueError: no prefill bucket holds prompt "
+                         f"length {plen} (buckets "
+                         f"{self.gen.prefill_buckets})", events)
                 lane.free.append(slot)
                 continue
             if ksh:
@@ -1237,66 +1192,16 @@ class ContinuousBatcher:
         long as the failed call left the pool alive (an error raised
         before execution); one that took the pool with it ends the
         group and the lane's active requests (``_rebuild_pool``)."""
-        import jax
         bl = self.gen.block_len
-        A = lane.max_active
         n = len(members)
         bb = self._batch_bucket(n)
-        npb_dest = (pb + bl - 1) // bl
-        padded = np.zeros((bb, pb), np.int32)
-        lengths = np.ones((bb,), np.int32)
-        dest = np.zeros((bb, npb_dest), np.int32)
-        slots_arr = np.full((bb,), A, np.int32)     # A = drop sentinel
-        if shared is not None:
-            ptab = np.zeros((bb, shared), np.int32)
-            plens = np.zeros((bb,), np.int32)
-        for j, (req, slot, resv) in enumerate(members):
-            ksh, shared_ids, priv, plen = resv
-            prompt = self._concat_prompt(req)
-            table = list(shared_ids) + list(priv)
-            if shared is not None:
-                suffix = prompt[ksh * bl:]
-                padded[j, :suffix.size] = suffix
-                lengths[j] = suffix.size
-                ptab[j, :ksh] = shared_ids
-                plens[j] = ksh * bl
-                nfill = (suffix.size + bl - 1) // bl
-                dest[j, :nfill] = table[ksh:ksh + nfill]
-            else:
-                padded[j, :plen] = prompt
-                lengths[j] = plen
-                nfill = (plen + bl - 1) // bl
-                dest[j, :nfill] = table[:nfill]
-            slots_arr[j] = slot
-        for j in range(n, bb):
-            # padding rows replicate row 0's prompt; their dest stays at
-            # the trash block and their slot at the drop sentinel, so
-            # nothing they compute is ever committed
-            padded[j] = padded[0]
-            lengths[j] = lengths[0]
-            if shared is not None:
-                ptab[j] = ptab[0]
-                plens[j] = plens[0]
-        self.prefill_positions_real += int(lengths[:n].sum())
+        key = ("pprefill", bb, pb) if shared is None \
+            else ("pshared", bb, pb, shared)
+        args = self._paged_args(key, lane, lane.state, members)
+        self.prefill_positions_real += int(args[2][:n].sum())   # lengths
         self.prefill_positions_padded += bb * pb
-        pprefill, pshared, _ = self._paged_fns()
         try:
-            self._ensure_lane_state(lane)
-            if shared is None:
-                key = ("pprefill", bb, pb)
-                exe = self._compiled(key, pprefill, self._params(),
-                                     padded, lengths, lane.state_shapes,
-                                     dest, slots_arr)
-                lane.state, logits0 = exe(self._params(), padded, lengths,
-                                          lane.state, dest, slots_arr)
-            else:
-                key = ("pshared", bb, pb, shared)
-                exe = self._compiled(key, pshared, self._params(),
-                                     padded, lengths, plens, ptab,
-                                     lane.state_shapes, dest, slots_arr)
-                lane.state, logits0 = exe(self._params(), padded, lengths,
-                                          plens, ptab, lane.state, dest,
-                                          slots_arr)
+            lane.state, logits0 = self._compiled(key, lane)(*args)
             self._count_exec(key, lane)
             with self.clock.phase("prefill_wait"):
                 logits0 = np.asarray(logits0)
@@ -1309,11 +1214,8 @@ class ContinuousBatcher:
             if lost or n == 1:
                 for req, slot, resv in members:
                     self._release_resv(resv)
-                    self.quarantined += 1
-                    events.append(GenEvent(
-                        "quarantine", req.rid, trace_id=req.trace_id,
-                        error=f"{type(e).__name__}: {e}",
-                        t_read=req.t_read, tenant=req.tenant))
+                    self._quarantine(req, f"{type(e).__name__}: {e}",
+                                     events)
                     lane.free.append(slot)
                 if lost:
                     self._rebuild_pool(lane, e, events)
@@ -1355,13 +1257,16 @@ class ContinuousBatcher:
             self._account_token(lane, slot, info, int(toks0[j]), events)
         return admitted
 
-    def _admit(self, events: List[GenEvent]) -> int:
-        """Claim free slots for waiting requests and admit them in
-        batched prefill groups.  Stops at the first head-of-line request
-        whose lane is full (FIFO; retried next boundary)."""
-        if self._pool is not None:
-            return self._admit_paged(events)
-        grabbed: List[tuple] = []        # (req, lane, slot)
+    def _grab(self, events: List[GenEvent]) -> List[tuple]:
+        """The admission grab loop, paged or not: pop waiting requests in
+        order; each is stamped, shed if expired, validated, its resume
+        prefix normalised, given its lane (or quarantined) and a free slot
+        there — on a paged lane its pool blocks too (``_reserve``).  Stops
+        at the first head-of-line request whose lane is full or whose
+        blocks the pool cannot give: it returns to the head of the waiting
+        room (FIFO; retried next boundary).  Returns ``(req, lane, slot,
+        resv)`` tuples, ``resv`` the paged reservation."""
+        grabbed: List[tuple] = []
         while True:
             with self._waiting_lock:
                 req = self._waiting.popleft() if self._waiting else None
@@ -1376,11 +1281,7 @@ class ContinuousBatcher:
                 continue
             err = self._validate(req)
             if err is not None:
-                self.quarantined += 1
-                events.append(GenEvent(
-                    "quarantine", req.rid, trace_id=req.trace_id,
-                    error=f"ValueError: {err}", t_read=req.t_read,
-                    tenant=req.tenant))
+                self._quarantine(req, f"ValueError: {err}", events)
                 continue
             if req.resume_tokens:
                 self._take_resume(req, events)
@@ -1392,25 +1293,35 @@ class ContinuousBatcher:
                     req, "resume prefix exceeds lane capacity", events)
                 lane = self._pick_lane(req)
             if lane is None:
-                self.quarantined += 1
-                events.append(GenEvent(
-                    "quarantine", req.rid, trace_id=req.trace_id,
-                    error="ValueError: no decode lane holds prompt + "
-                          f"max_tokens (buckets {self.gen.bucket_lens})",
-                    t_read=req.t_read, tenant=req.tenant))
+                self._quarantine(
+                    req, "ValueError: no decode lane holds prompt + "
+                         f"max_tokens (buckets {self.gen.bucket_lens})",
+                    events)
                 continue
-            if not lane.free:
-                # every slot of the right lane busy: the request stays at
-                # the head for the next boundary (FIFO per lane is close
-                # enough across lanes at this queue depth)
+            resv = None
+            if lane.free:
+                resv = () if self._pool is None \
+                    else self._reserve(lane, req)
+            if resv is None:
+                # every slot of the right lane busy (FIFO per lane is
+                # close enough across lanes at this queue depth), or the
+                # pool exhausted: the request stays at the head
                 with self._waiting_lock:
                     self._waiting.appendleft(req)
                 break
-            grabbed.append((req, lane, lane.free.popleft()))
+            grabbed.append((req, lane, lane.free.popleft(), resv))
+        return grabbed
+
+    def _admit(self, events: List[GenEvent]) -> int:
+        """Claim free slots for waiting requests (``_grab``) and admit
+        them in batched prefill groups."""
+        grabbed = self._grab(events)
         if not grabbed:
             return 0
+        if self._pool is not None:
+            return self._admit_paged(grabbed, events)
         groups: Dict[tuple, list] = {}
-        for req, lane, slot in grabbed:
+        for req, lane, slot, _ in grabbed:
             prompt_len = int(np.asarray(req.prompt).reshape(-1).size) \
                 + len(req.resume_tokens or ())
             pb = self._prefill_bucket(prompt_len)
@@ -1424,13 +1335,10 @@ class ContinuousBatcher:
                 # max_prompt_len, so this is unreachable from config —
                 # but an uncovered prompt must quarantine, not crash the
                 # worker with its slot claimed
-                self.quarantined += 1
-                events.append(GenEvent(
-                    "quarantine", req.rid, trace_id=req.trace_id,
-                    error=f"ValueError: no prefill bucket holds prompt "
-                          f"length {prompt_len} (buckets "
-                          f"{self.gen.prefill_buckets})",
-                    t_read=req.t_read, tenant=req.tenant))
+                self._quarantine(
+                    req, f"ValueError: no prefill bucket holds prompt "
+                         f"length {prompt_len} (buckets "
+                         f"{self.gen.prefill_buckets})", events)
                 lane.free.append(slot)
                 continue
             groups.setdefault((lane.bucket, pb), (lane, pb, []))[2] \
@@ -1557,16 +1465,12 @@ class ContinuousBatcher:
             if lane.active == 0:
                 continue
             clock.to("dispatch")
-            tokens = lane.tokens
             if isinstance(lane, _PagedLane):
-                _, _, pdecode = self._paged_fns()
                 key = ("pdecode", lane.bucket)
-                exe = self._compiled(key, pdecode, self._params(),
-                                     lane.state_shapes, lane.tables,
-                                     lane.pos, tokens)
+                exe = self._compiled(key, lane)
                 try:
-                    block, lane.state = exe(self._params(), lane.state,
-                                            lane.tables, lane.pos, tokens)
+                    block, lane.state = exe(
+                        *self._paged_args(key, lane, lane.state))
                     self._count_exec(key, lane)
                     clock.to("decode_wait")
                     block = np.asarray(block)
@@ -1586,12 +1490,9 @@ class ContinuousBatcher:
                     lane.pos + np.int32(block.shape[0]),
                     np.int32(lane.bucket)).astype(np.int32)
             else:
-                _, step, _ = self._lane_fns(lane)
                 key = ("decode_step", lane.bucket)
-                exe = self._compiled(key, step,
-                                     self._params(), lane.state, tokens)
-                block, lane.state = exe(self._params(), lane.state,
-                                        tokens)
+                block, lane.state = self._compiled(key, lane)(
+                    self._params(), lane.state, lane.tokens)
                 self._count_exec(key)
                 clock.to("decode_wait")
                 block = np.asarray(block)      # (decode_quantum, A)
@@ -1729,82 +1630,24 @@ class ContinuousBatcher:
                                   for k in after}}
 
     def _warm_entry(self, entry, lanes: Dict[int, "_Lane"]) -> bool:
-        import jax
+        """Compile one manifest entry's program; False = it was there."""
         lane = lanes.get(entry.lane_bucket)
         if lane is None:
             raise ValueError(f"no lane with bucket {entry.lane_bucket}")
         self._ensure_lane_state(lane)
-        if entry.kind.startswith("paged_"):
-            # compile-only, from the pool's SHAPES: this thread never
-            # holds the live pool, which the generate thread's programs
-            # donate (a pool mid-call is deleted until it is replaced)
-            bl = self.gen.block_len
-            A = lane.max_active
-            pprefill, pshared, pdecode = self._paged_fns()
-            bb = int(entry.prefill_batch or 1)
-            if entry.kind == "paged_decode":
-                key = ("pdecode", lane.bucket)
-                fresh = key not in self._programs
-                self._compiled(key, pdecode, self._params(),
-                               lane.state_shapes, lane.tables, lane.pos,
-                               lane.tokens)
-                return fresh
-            pb = int(entry.prefill_bucket)
-            npb_dest = (pb + bl - 1) // bl
-            dummy = (np.zeros((bb, pb), np.int32),
-                     np.ones((bb,), np.int32))
-            dest = np.zeros((bb, npb_dest), np.int32)
-            slots = np.full((bb,), A, np.int32)
-            if entry.kind == "paged_prefill":
-                key = ("pprefill", bb, pb)
-                fresh = key not in self._programs
-                self._compiled(key, pprefill, self._params(), *dummy,
-                               lane.state_shapes, dest, slots)
-                return fresh
-            if entry.kind == "paged_shared":
-                npb = int(entry.prefix_blocks or 1)
-                key = ("pshared", bb, pb, npb)
-                fresh = key not in self._programs
-                self._compiled(key, pshared, self._params(), *dummy,
-                               np.zeros((bb,), np.int32),
-                               np.zeros((bb, npb), np.int32),
-                               lane.state_shapes, dest, slots)
-                return fresh
+        bb, pb = int(entry.prefill_batch or 1), entry.prefill_bucket
+        key = {"paged_decode": ("pdecode", lane.bucket),
+               "paged_prefill": ("pprefill", bb, pb),
+               "paged_shared": ("pshared", bb, pb,
+                                int(entry.prefix_blocks or 1)),
+               "prefill": ("prefill", bb, pb, lane.bucket),
+               "decode_step": ("decode_step", lane.bucket),
+               "insert": ("insert", bb, lane.bucket)}.get(entry.kind)
+        if key is None:
             raise ValueError(f"unknown warm-up entry kind {entry.kind!r}")
-        prefill, step, insert = self._lane_fns(lane)
-        if entry.kind == "prefill":
-            pb = int(entry.prefill_bucket)
-            bb = int(entry.prefill_batch or 1)
-            key = ("prefill", bb, pb, lane.bucket)
-            fresh = key not in self._programs
-            dummy = np.zeros((bb, pb), np.int32)
-            self._compiled(key, prefill, self._params(), dummy,
-                           np.ones((bb,), np.int32))
-            return fresh
-        if entry.kind == "decode_step":
-            key = ("decode_step", lane.bucket)
-            fresh = key not in self._programs
-            self._compiled(key, step, self._params(), lane.state,
-                           lane.tokens)
-            return fresh
-        if entry.kind == "insert":
-            # insert needs a prefilled sub-state: derive it abstractly so
-            # warming never runs a real prefill
-            bb = int(entry.prefill_batch or 1)
-            key = ("insert", bb, lane.bucket)
-            fresh = key not in self._programs
-            pb = self.gen.prefill_buckets[0]
-            shapes = jax.eval_shape(
-                prefill, self._params(),
-                jax.ShapeDtypeStruct((bb, pb), np.int32),
-                jax.ShapeDtypeStruct((bb,), np.int32))
-            sub_shapes = shapes[0] if self._is_pair(shapes) else shapes
-            sub = jax.tree.map(lambda sd: np.zeros(sd.shape, sd.dtype),
-                               sub_shapes)
-            self._compiled(key, insert, lane.state, sub, np.int32(0),
-                           np.int32(0))
-            return fresh
-        raise ValueError(f"unknown warm-up entry kind {entry.kind!r}")
+        fresh = key not in self._programs
+        self._compiled(key, lane)
+        return fresh
 
     # -- observability --------------------------------------------------------
     @staticmethod
@@ -1820,41 +1663,33 @@ class ContinuousBatcher:
 
     def state_bytes_doc(self) -> Dict:
         """The ``kv_state`` ledger component, decomposed (PR 18):
-        ``lanes`` (monolithic per-slot caches + int8 staging buffers —
-        everything slot-shaped), ``paged_pool`` (the shared KV block
-        pool), ``scales`` (int8 per-block scale planes) and ``aux``
+        ``lanes`` (monolithic per-slot caches + a paged model's per-slot
+        state — everything slot-shaped), ``paged_pool`` (the shared KV
+        block pool), ``scales`` (int8 per-block scale planes) and ``aux``
         (per-slot host-side scheduler state: token cursors, block
         tables, position cursors — the PR 18 bugfix: these were never
         counted for unallocated lanes, so the gauge could under-report).
         Derived from leaf shapes/dtypes, so exact wherever jax placed
         the buffers."""
         import jax
-        lanes_b = pool_b = scales_b = aux_b = 0
+        doc = {"lanes": 0, "paged_pool": 0, "scales": 0, "aux": 0}
         for lane in self._lanes:
-            aux_b += int(lane.tokens.nbytes)
+            doc["aux"] += int(lane.tokens.nbytes)
             if isinstance(lane, _PagedLane):
-                aux_b += int(lane.tables.nbytes) + int(lane.pos.nbytes)
-            if lane.state is None:
-                continue
-            if isinstance(lane, _PagedLane):
-                for part, leaves in lane.state.items():
-                    nb = self._leaf_bytes(leaves)
-                    if part in ("k", "v"):
-                        pool_b += nb
-                    elif part in ("ks", "vs"):
-                        scales_b += nb
-                    else:                # stk/stv: per-slot staging
-                        lanes_b += nb
-            else:
-                lanes_b += self._leaf_bytes(
+                doc["aux"] += int(lane.tables.nbytes) + int(lane.pos.nbytes)
+                # the model sorts its own state into the three classes
+                for part, nb in self.inner.paged_state_bytes(
+                        lane.state_shapes).items():
+                    doc[part] += int(nb)
+            elif lane.state is not None:
+                doc["lanes"] += self._leaf_bytes(
                     jax.tree_util.tree_leaves(lane.state))
         # snapshot spool bytes (PR 20): host/disk-side, but pinned BY the
         # generation plane — the engine mirrors the spool size here so
         # the ledger's aux component owns continuity state too
-        aux_b += int(self.snapshot_bytes)
-        return {"lanes": lanes_b, "paged_pool": pool_b,
-                "scales": scales_b, "aux": aux_b,
-                "total": lanes_b + pool_b + scales_b + aux_b}
+        doc["aux"] += int(self.snapshot_bytes)
+        doc["total"] = sum(doc.values())
+        return doc
 
     def state_bytes(self) -> int:
         """Bytes pinned by decode state — the ``kv_state`` component of

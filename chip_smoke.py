@@ -607,9 +607,17 @@ def leg_generate(cfg: dict, expect_mosaic: bool = True) -> dict:
                 cin.enqueue_tensor(rid, tokens, wire="bin")
         results = OutputQueue(queue).query_many(
             [r for r in rids if r not in http_rids], timeout_s=300.0)
+        for rid in http_rids:
+            status, body = _http("GET",
+                                 f"{base}/v1/result/{rid}?timeout_s=60")
+            check(status == 200 and "value" in body,
+                  f"GET /v1/result/{rid} -> {status} {str(body)[:200]}")
+            results[rid] = body
         # a late arrival repeating every FULL KV block of a finished
         # request's prompt (what the prefix index keys on): admitted
-        # through the prefix cache, suffix-only prefill
+        # through the prefix cache, suffix-only prefill.  (Every result is
+        # in by now, the gateway's too: a donor admitted at the same
+        # boundary as its sharer has registered nothing yet.)
         donor = max(prompts, key=lambda r: len(prompts[r]))
         shared = len(prompts[donor]) // cfg["block_len"] * cfg["block_len"]
         check(shared > 0, "the longest prompt fills no KV block")
@@ -621,12 +629,6 @@ def leg_generate(cfg: dict, expect_mosaic: bool = True) -> dict:
             prompts["gen-shared"].astype("<f4")), wire="bin")
         results.update(OutputQueue(queue).query_many(["gen-shared"],
                                                      timeout_s=300.0))
-        for rid in http_rids:
-            status, body = _http("GET",
-                                 f"{base}/v1/result/{rid}?timeout_s=60")
-            check(status == 200 and "value" in body,
-                  f"GET /v1/result/{rid} -> {status} {str(body)[:200]}")
-            results[rid] = body
 
         served = {}
         for rid in rids:

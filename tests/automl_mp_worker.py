@@ -6,7 +6,8 @@ jax.local_devices() so every trial trains process-locally (no cross-process
 collectives inside trials) — the MultiProcessSearchEngine contract.  Runs an
 AutoTS search with distributed=True and prints one JSON line: the per-trial
 metrics (identical on every process after the allgather), the best config,
-how many trials THIS process executed, and the search wall time.
+how many trials THIS process executed, the wall-clock window its local
+trials ran in, and the search wall time.
 
 Run: python tests/automl_mp_worker.py <coordinator> <num_procs> <pid>
 """
@@ -86,8 +87,11 @@ def main():
     orig_train_one = TimeSequencePredictor._train_one
 
     def counting(self, cfg, df_):
-        calls.append(1)
-        return orig_train_one(self, cfg, df_)
+        t_start = time.time()
+        try:
+            return orig_train_one(self, cfg, df_)
+        finally:
+            calls.append((t_start, time.time()))
 
     TimeSequencePredictor._train_one = counting
     t0 = time.time()
@@ -101,6 +105,9 @@ def main():
         "trials": engine_trials,
         "best": {k: pipe.config[k] for k in ("lstm_units", "lr")},
         "local_trial_count": len(calls) - 1,   # minus the best retrain
+        # epoch seconds (one host, one clock): first local trial's start,
+        # last local trial's end; the retrain comes after the merge
+        "trial_window": [calls[0][0], calls[-2][1]],
         "search_seconds": round(dt, 2),
     }))
 

@@ -38,7 +38,12 @@ def _run_example(name, argv):
     env = dict(os.environ)
     flags = env.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
-        env["XLA_FLAGS"] = flags + " --xla_force_host_platform_device_count=8"
+        flags += " --xla_force_host_platform_device_count=8"
+    # eight device threads on a host the suite's other workers saturate
+    # can take longer than XLA's 40 s to meet in an all-reduce, and XLA
+    # then aborts the process (seen in PRs 29 and 30): give them time
+    env["XLA_FLAGS"] = flags \
+        + " --xla_cpu_collective_call_terminate_timeout_seconds=600"
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, cwd=REPO, env=env, timeout=900)
     assert r.returncode == 0, (f"example {name} failed:\n"

@@ -15,6 +15,9 @@ Covers the four layers the tentpole touched:
   ``state_bytes`` ledger golden (the PR 18 aux bugfix), zero steady-state
   compiles after warm-up, the paged warm-up manifest, and the fleet
   ``kv_pool`` aggregation.
+- the seam between the two (PR 30): a model with another state layout
+  behind the paged contract is served by the scheduler as it stands, and
+  ``TransformerLM``'s five forward paths agree bitwise.
 """
 
 import numpy as np
@@ -410,6 +413,151 @@ def test_paged_zero_steady_compiles_after_warm():
     c1 = aot.COMPILE_STATS.snapshot()
     assert c1["compile_requests"] == c0["compile_requests"], \
         "steady-state paged traffic compiled"
+
+
+# -- the seam between scheduler and model (PR 30) -----------------------------
+
+def _fused_kv_lm(**kw):
+    """A model that keeps the paged contract with ANOTHER state layout:
+    one fused (n_blocks, block_len, 2, hidden) K|V array a layer, plus a
+    per-slot leaf that is no KV at all (a stand-in for recurrent state:
+    how many positions each slot has seen).  The math is ``TransformerLM``'s,
+    so its tokens are known."""
+    import jax.numpy as jnp
+    from analytics_zoo_tpu.models.textmodels import TransformerLM
+
+    class FusedKVLM(TransformerLM):
+        @staticmethod
+        def _split(state):
+            return {"k": [kv[:, :, 0] for kv in state["kv"]],
+                    "v": [kv[:, :, 1] for kv in state["kv"]]}
+
+        @staticmethod
+        def _fuse(pools, seen):
+            return {"kv": [jnp.stack([k, v], axis=2)
+                           for k, v in zip(pools["k"], pools["v"])],
+                    "seen": seen}
+
+        def init_paged_pools(self, n_blocks, block_len, max_active,
+                             kv_quant="off"):
+            assert kv_quant == "off"
+            return {"kv": [np.zeros((n_blocks, block_len, 2, self.hidden),
+                                    np.float32)
+                           for _ in range(self.n_layers)],
+                    "seen": np.zeros((max_active,), np.int32)}
+
+        def paged_state_bytes(self, state):
+            return {"paged_pool": sum(int(np.prod(kv.shape)) * 4
+                                      for kv in state["kv"]),
+                    "lanes": int(np.prod(state["seen"].shape)) * 4}
+
+        def prefill_paged(self, params, state, prompt, lengths, dest,
+                          slots, **fmt):
+            pools, logits0 = super().prefill_paged(
+                params, self._split(state), prompt, lengths, dest, slots,
+                **fmt)
+            seen = state["seen"].at[slots].set(lengths, mode="drop")
+            return self._fuse(pools, seen), logits0
+
+        def prefill_shared_paged(self, params, state, suffix, lengths,
+                                 prefix_len, ptab, dest, slots, **fmt):
+            pools, logits0 = super().prefill_shared_paged(
+                params, self._split(state), suffix, lengths, prefix_len,
+                ptab, dest, slots, **fmt)
+            seen = state["seen"].at[slots].set(prefix_len + lengths,
+                                               mode="drop")
+            return self._fuse(pools, seen), logits0
+
+        def decode_paged(self, params, state, block_tables, pos, tokens,
+                         **fmt):
+            logits, pools = super().decode_paged(
+                params, self._split(state), block_tables, pos, tokens,
+                **fmt)
+            return logits, self._fuse(pools, state["seen"] + 1)
+
+    return FusedKVLM(**kw)
+
+
+def test_scheduler_serves_a_model_with_another_state_layout():
+    """``ContinuousBatcher(paged=True)`` unmodified over ``_fused_kv_lm``:
+    warm-up, admission in batches (prefix hits among them), decode,
+    finish, pool release, the ledger — the scheduler names no leaf of the
+    state, so the tokens are ``TransformerLM``'s, nothing compiles after
+    warm-up and ``pdecode`` takes every leaf over in place."""
+    import jax
+    from analytics_zoo_tpu.inference import aot
+    from analytics_zoo_tpu.inference.inference_model import InferenceModel
+    im, lm = _im(n_layers=2)
+    fused = _fused_kv_lm(vocab_size=64, hidden=32, n_head=2, n_layers=2,
+                         max_len=64)
+    fim = InferenceModel().do_load_model(fused, im._params, {})
+    reqs = _shared_reqs()
+    want = _drive(_batcher(im, paged=True, block_len=8, **GEO), reqs, "t-")
+    b = _batcher(fim, paged=True, block_len=8, **GEO)
+    lane = b._lanes[0]
+    assert set(lane.state) == {"kv", "seen"}
+    doc = b.warm()
+    assert doc["failed"] == 0, doc["errors"]
+    assert _drive(b, reqs, "f0-") == want
+    c0 = aot.COMPILE_STATS.snapshot()
+    assert _drive(b, reqs, "f1-") == want
+    assert aot.COMPILE_STATS.snapshot()["compile_requests"] \
+        == c0["compile_requests"], "steady-state traffic compiled"
+    s = b.stats()
+    assert s["pool"]["prefix_hits"] > 0 and s["quarantined"] == 0
+    assert b.active == 0
+    b._prefix.clear()           # what is still held is the prefix index's
+    assert b.stats()["pool"]["free_blocks"] == s["pool"]["blocks"]
+    # the state is the model's: its extra leaf lived through every program
+    assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(lane.state))
+    assert int(np.asarray(lane.state["seen"]).max()) > 0
+    nbytes = sum(leaf.nbytes for leaf in jax.tree.leaves(lane.state))
+    assert b._alias_bytes[("pdecode", lane.bucket)] == nbytes \
+        == lane.state_nbytes
+    assert s["state_bytes_aliased"] == s["boundaries"] * nbytes
+    n_blocks = b._pool.n_blocks + 1
+    kv = 2 * n_blocks * 8 * 2 * 32 * 4
+    ledger = b.state_bytes_doc()
+    assert (ledger["paged_pool"], ledger["scales"], ledger["lanes"]) \
+        == (kv, 0, b.gen.max_active_slots * 4)
+    assert ledger["total"] == kv + ledger["lanes"] + ledger["aux"]
+
+
+@pytest.mark.parametrize("lengths", [(8, 8), (5, 8), (3, 1)],
+                         ids=["full", "ragged", "short"])
+def test_the_five_forward_paths_agree(lengths):
+    """One decoder block, five attention steps: ``call``'s logits at a
+    row's last position = ``init_decode``'s ``logits0`` =
+    ``prefill_paged``'s, and ``decode_step`` = ``decode_paged`` logit for
+    logit over 8 steps (float pool, XLA reference, bitwise on the CPU)."""
+    import jax
+    import jax.numpy as jnp
+    _, lm = _im(n_layers=2)
+    params = lm.build(jax.random.PRNGKey(3))
+    B, P, bl, ntab = 2, 8, 4, 8
+    g = np.random.default_rng(7)
+    prompt = g.integers(1, 64, (B, P)).astype(np.int32)
+    lens = np.asarray(lengths, np.int32)
+    full = np.asarray(lm.call(params, prompt))
+    state, logits0 = lm.init_decode(params, prompt, lens,
+                                    cache_len=ntab * bl)
+    tables = 1 + np.arange(B * ntab, dtype=np.int32).reshape(B, ntab)
+    pools = jax.device_put(lm.init_paged_pools(1 + B * ntab, bl, B))
+    pstate, plogits0 = lm.prefill_paged(
+        params, pools, prompt, lens, tables[:, :P // bl],
+        np.arange(B, dtype=np.int32), block_len=bl)
+    np.testing.assert_array_equal(np.asarray(logits0),
+                                  full[np.arange(B), lens - 1])
+    np.testing.assert_array_equal(np.asarray(plogits0), np.asarray(logits0))
+    tok, pos = jnp.argmax(logits0, axis=-1).astype(jnp.int32), lens
+    for step in range(8):
+        logits, state = lm.decode_step(params, state, tok)
+        plogits, pstate = lm.decode_paged(params, pstate, tables, pos, tok,
+                                          block_len=bl, impl="xla")
+        np.testing.assert_array_equal(
+            np.asarray(plogits), np.asarray(logits),
+            err_msg=f"decode_paged left decode_step at step {step}")
+        tok, pos = jnp.argmax(logits, axis=-1).astype(jnp.int32), pos + 1
 
 
 # -- ledger golden (the state_bytes aux bugfix) -------------------------------

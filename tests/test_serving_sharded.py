@@ -242,8 +242,12 @@ def test_explicit_batch_mode_never_tensor_shards(ctx, caplog):
 
 def test_engine_sharded_auto_end_to_end_with_quarantine(ctx):
     """The PR 1-5 pipeline contracts survive the sharded predict: results
-    match the single-chip engine bitwise, a poisoned record quarantines
-    alone, and drain flushes the dispatched in-flight work."""
+    match the single-chip engine's, a poisoned record quarantines alone,
+    and drain flushes the dispatched in-flight work.  Same classes, float-
+    equal scores: the two engines coalesce the ten records into batches as
+    their timing has it, and a row's float32 score moves by an ulp with
+    the batch it was computed in.  (The bitwise claim is the model's, where
+    the batch is fixed: ``test_sharded_do_predict_bitwise_f32``.)"""
     model = _mlp(dim=4, classes=3)
     xs = [np.random.default_rng(i).normal(size=(4,)).astype(np.float32)
           for i in range(10)]
@@ -267,7 +271,10 @@ def test_engine_sharded_auto_end_to_end_with_quarantine(ctx):
     assert im.mesh_info()["sharded_calls"] > 0
     assert OutputQueue.is_error(got_auto["poison"])     # quarantined alone
     for u in (f"r{i}" for i in range(10)):
-        assert got_auto[u]["value"] == got_off[u]["value"]
+        auto, off = got_auto[u]["value"], got_off[u]["value"]
+        assert [c for c, _ in auto] == [c for c, _ in off]
+        assert [p for _, p in auto] == pytest.approx([p for _, p in off],
+                                                     rel=1e-5)
     assert s.dead_lettered == 1 and s.total_records == 10
 
 
