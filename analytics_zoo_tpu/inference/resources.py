@@ -10,7 +10,10 @@ source of truth the optimizing PR introduced:
 - **weights** — ``quantize.weight_bytes`` over the model's live params
   (+ state) tree: every leaf at its STORED dtype, so an int4-quantized
   deployment reads ~8x below its float twin (the PR 14 structural claim,
-  now a live gauge instead of a bench printout).
+  now a live gauge instead of a bench printout).  The generation
+  scheduler's operand copies (``ContinuousBatcher.operand_bytes``: the
+  matmul weights rounded once to the type the backend multiplies in, held
+  BESIDE that tree) are resident weights too and count here.
 - **kv_state** — the generation scheduler's committed lane buffers
   (``ContinuousBatcher.state_bytes()``): fixed ``(max_active, bucket)``
   buffers per lane, the exact allocation PR 12's bucket geometry pins.
@@ -59,6 +62,14 @@ class ResourceLedger:
 
     # -- components ----------------------------------------------------------
     def weights_bytes(self) -> int:
+        # + the scheduler's operand copies of the weights, held beside the
+        # model's tree (0 where its programs read that tree itself; its
+        # ``generation`` stats name them).  Read live: the form is made at
+        # the first program, not at construction.
+        return self._tree_weights_bytes() \
+            + int(getattr(self.batcher, "operand_bytes", 0) or 0)
+
+    def _tree_weights_bytes(self) -> int:
         epoch = getattr(self.model, "_aot_epoch", None)
         if self._weights_cache is not None \
                 and self._weights_cache[0] == epoch:

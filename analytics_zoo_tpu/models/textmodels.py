@@ -19,6 +19,7 @@ step a churning slot batch through one compiled program per cache bucket.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -132,6 +133,16 @@ class _TaggerModel(Layer):
         return outs[0] if len(outs) == 1 else tuple(outs)
 
 
+@functools.partial(jax.jit, static_argnames=("dtype",))
+def _round_operands(src, dtype):
+    """``TransformerLM.matmul_operands``' one program: the weights rounded
+    to ``dtype``, the head transposed to (hidden, vocab)."""
+    out = jax.tree.map(lambda w: w.astype(dtype), src)
+    if "head" in out:
+        out["head"] = out["head"].T
+    return out
+
+
 class TransformerLM(Layer):
     """Decoder-only transformer language model with a KV-cache step API
     (the GPT-style generator the serving plane's continuous batcher
@@ -175,7 +186,19 @@ class TransformerLM(Layer):
       ``(logits, state)``: one token per row, appended through the block
       table and attended by the ``paged_attention`` kernel.
     - ``paged_state_bytes(state)`` -> the state's bytes by accounting
-      class (``paged_pool`` / ``scales`` / ``lanes``)."""
+      class (``paged_pool`` / ``scales`` / ``lanes``).
+
+    WHICH TREE ``params`` IS (PR 31).  Every path takes the float32 tree
+    ``build`` returns; the step-wise ones (``init_decode``,
+    ``decode_step``, the three paged programs) are served
+    ``matmul_operands(params, dtype)`` instead, the optional sixth method
+    of the contract: the same tree with each matmul weight rounded ONCE
+    to the type the backend's matmul would round it to in every call
+    (``ops/dispatch.matmul_operand_dtype``: bfloat16 on a TPU at default
+    precision, None = the float32 tree itself on a CPU), and the tied
+    head as a leaf of its own.  ``_lin`` / ``_logits`` tell the two apart
+    by the weight's dtype: there is one decoder block and no flag.
+    Training, checkpoints and the weight store only ever see float32."""
 
     def __init__(self, vocab_size: int, hidden: int = 64, n_head: int = 4,
                  n_layers: int = 2, max_len: int = 512,
@@ -227,9 +250,18 @@ class TransformerLM(Layer):
         return (x - mu) * jax.lax.rsqrt(var + eps) * p["g"] + p["b"]
 
     @staticmethod
-    def _lin(p, x):
-        return jnp.matmul(x, p["W"],
-                          preferred_element_type=jnp.float32) + p["b"]
+    def _mxu(x, W):
+        """``x @ W`` accumulated in float32.  A ``W`` that arrives in
+        bfloat16 is a ``matmul_operands`` leaf, rounded once at load: the
+        activation is rounded the way the MXU pass over float32 operands
+        rounds it, so the products are that pass's own.  A float32 ``W``
+        is multiplied as it is."""
+        if W.dtype == jnp.bfloat16:
+            x = x.astype(jnp.bfloat16)
+        return jnp.matmul(x, W, preferred_element_type=jnp.float32)
+
+    def _lin(self, p, x):
+        return self._mxu(x, p["W"]) + p["b"]
 
     def _heads(self, x):
         # (..., H) -> (..., n_head, head_dim)
@@ -237,9 +269,10 @@ class TransformerLM(Layer):
                                          self.hidden // self.n_head))
 
     def _logits(self, params, h):
-        # weight-tied head: logits = h @ embed.T
-        return jnp.matmul(h, params["embed"].T,
-                          preferred_element_type=jnp.float32)
+        # weight-tied head: logits = h @ embed.T (the operand tree brings
+        # the transpose ready-made as ``head``)
+        head = params.get("head")
+        return self._mxu(h, params["embed"].T if head is None else head)
 
     @staticmethod
     def _ids(x):
@@ -285,6 +318,40 @@ class TransformerLM(Layer):
         last = jnp.take_along_axis(
             h, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)[:, 0]
         return self._logits(params, last)
+
+    # -- the weights' operand form (PR 31) ------------------------------------
+    _MATMULS = ("qkv", "proj", "fc1", "fc2")
+
+    def matmul_operands(self, params, dtype):
+        """The tree the step-wise programs read, made ONCE per weight
+        load: every float32 ``blocks[i].{qkv,proj,fc1,fc2}.W`` rounded to
+        ``dtype`` (what ``ops/dispatch.matmul_operand_dtype`` says the
+        backend's matmul rounds it to on every call anyway), and one new
+        leaf ``head``, the tied output head ``embed.T`` in ``dtype`` laid
+        out (hidden, vocab) as ``_logits`` multiplies it.  Every other
+        leaf (``embed`` for the gather, ``pos``, LayerNorm, biases) is the
+        SAME array as in ``params``, shared; a ``W`` that is not float32
+        stays as it is; the float32 ``W``s are not in the tree.  The
+        copies keep their source's sharding.  ``dtype=None`` is the
+        identity: ``params`` itself."""
+        if dtype is None:
+            return params
+
+        def f32(a):
+            return a.dtype == jnp.float32
+
+        src = {"blocks": [{n: blk[n]["W"] for n in self._MATMULS
+                           if f32(blk[n]["W"])}
+                          for blk in params["blocks"]]}
+        if f32(params["embed"]):
+            src["head"] = params["embed"]
+        cast = _round_operands(src, dtype)
+        tree = dict(params, blocks=[
+            dict(blk, **{n: dict(blk[n], W=w) for n, w in ws.items()})
+            for blk, ws in zip(params["blocks"], cast["blocks"])])
+        if "head" in cast:
+            tree["head"] = cast["head"]
+        return tree
 
     # -- monolithic forward (teacher forcing / training) ----------------------
     def call(self, params, inputs, *, training=False, rng=None):

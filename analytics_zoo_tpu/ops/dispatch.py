@@ -31,6 +31,23 @@ def on_tpu() -> bool:
         "kernel path for it — choose impl/interpret explicitly")
 
 
+def matmul_operand_dtype():
+    """The type a float32 matmul's operands are rounded to by the backend
+    itself, or None where it multiplies them as they are.  On a TPU, at
+    jax's default matmul precision (``jax_default_matmul_precision`` unset
+    or one of its single-pass bfloat16 names), a float32 dot is ONE
+    bfloat16 MXU pass: a weight rounded to bfloat16 once gives the same
+    products.  A CPU, or a user who asked jax for more (``float32``,
+    ``highest``, ``BF16_BF16_F32_X3`` ...), gets None: nothing may be
+    rounded ahead."""
+    if not on_tpu():
+        return None
+    if jax.config.jax_default_matmul_precision not in (
+            None, "default", "bfloat16", "BF16_BF16_F32"):
+        return None
+    return jax.numpy.bfloat16
+
+
 def resolve_impl(impl: Optional[str]) -> str:
     """``auto``/None -> ``pallas`` on TPU, ``xla`` on CPU; an explicit
     ``pallas`` | ``xla`` | ``interpret`` wins."""

@@ -458,6 +458,13 @@ class ContinuousBatcher:
         self._alias_bytes: Dict[tuple, int] = {}
         self.state_bytes_passed = 0
         self.state_bytes_aliased = 0
+        # the weights' operand form (``_params``): the tree and the rule it
+        # was made from, the form, how often it was made and the bytes of the
+        # copies it holds beside the model's own tree (0: it IS that tree)
+        self._operand_lock = threading.Lock()
+        self._operand_src, self._operand_form = (None, None), None
+        self.operand_builds = 0
+        self.operand_bytes = 0
         self.compiles = 0
         self.decode_steps = 0
         self.boundaries = 0          # calls of a decode program
@@ -508,7 +515,42 @@ class ContinuousBatcher:
 
     # -- program construction (compile-once) ----------------------------------
     def _params(self):
-        return self.model._params
+        """The tree every program is lowered from and called with: the
+        model's weights in their OPERAND FORM (``matmul_operands``: the
+        matmul weights in the type the backend's matmul rounds them to,
+        rounded once instead of in every call; the float32 tree itself
+        where the backend rounds nothing).  Made once for the tree
+        ``model._params`` currently IS under the rule
+        (``matmul_operand_dtype``) now in force, again when a load or a
+        sharding replaces that tree or the rule changes (a user who turns
+        jax to ``highest`` gets float32 operands from the next call on);
+        a model without the method is served with its parameters as they
+        are."""
+        params = self.model._params
+        operands = getattr(self.inner, "matmul_operands", None)
+        if operands is None:
+            return params
+        from analytics_zoo_tpu.ops import dispatch
+        dtype = dispatch.matmul_operand_dtype()
+        # warm-up thread and generate thread both come through here
+        with self._operand_lock:
+            src, had = self._operand_src
+            if src is not params or had is not dtype:
+                import jax
+                form = operands(params, dtype)
+                held = {id(leaf) for leaf in jax.tree.leaves(params)}
+                self.operand_bytes = sum(
+                    int(leaf.nbytes) for leaf in jax.tree.leaves(form)
+                    if id(leaf) not in held)
+                if self.operand_builds and had is not dtype:
+                    # executables compiled for the other form take
+                    # neither these operands nor the new precision
+                    self._programs = {
+                        k: v for k, v in self._programs.items()
+                        if k[0] in ("fns", "pfns")}
+                self.operand_builds += 1
+                self._operand_src, self._operand_form = (params, dtype), form
+            return self._operand_form
 
     def _jit_key_fns(self, lane_bucket: int):
         import jax
@@ -615,6 +657,9 @@ class ContinuousBatcher:
         """AOT-compiled executable for one fixed-shape program, compiled
         exactly once; ``warm()`` walks the same path, so a warmed program
         is the very executable the hot path runs."""
+        # a changed matmul rule drops the other form's executables HERE,
+        # before the look-up, not between it and the call
+        self._params()
         exe = self._programs.get(key)
         if exe is None:
             fn, args = self._lowering(key, lane)
@@ -1737,6 +1782,8 @@ class ContinuousBatcher:
              "state_bytes_passed": self.state_bytes_passed,
              "state_bytes_aliased": self.state_bytes_aliased,
              "pool_rebuilds": self.pool_rebuilds,
+             "operand_builds": self.operand_builds,
+             "operand_bytes": self.operand_bytes,
              "lanes": [{"bucket": lane.bucket,
                         "max_active": lane.max_active,
                         "active": lane.active}
