@@ -1,0 +1,678 @@
+"""A decoder with latent attention, a learned sparse selection over it and a
+routed expert layer that holds its chip's share of the experts (PR 32).
+
+``LatentMoELM`` is the block of the DeepSeek-V3.2 / GLM-5 family
+(``model_type`` ``glm_moe_dsa``), served through the same paged contract as
+``models/textmodels.TransformerLM`` (its docstring is the contract's text):
+
+- pre-norm residual blocks with RMS norm, no biases, SwiGLU feed-forward;
+- **MLA**: queries through a low-rank bottleneck (``q_a`` -> RMS norm ->
+  ``q_b``), keys and values through ONE latent row a token (``kv_a`` -> ``c_kv``
+  | ``k_r``), rotary positions (interleaved pairs) on ``qk_rope_head_dim`` dims
+  of the query and on ``k_r``, which all heads share.  Prefill EXPANDS the
+  latent rows to per-head keys and values (``kv_b``); decode ABSORBS ``kv_b``
+  into the query and the output, so the cache holds ``c_kv`` and ``k_r`` only;
+- **DSA**: an indexer (``index_n_heads`` x ``index_head_dim``) scores every
+  earlier token and attention runs over the ``index_topk`` best only.  Prefill
+  masks the dense scores (an exact k-th-largest threshold a query); decode
+  gathers the selected rows through the block table;
+- **routed FFN**: sigmoid scores over ALL ``n_routed_experts``, the
+  ``num_experts_per_tok`` largest of score + selection bias, normalised and
+  scaled; this process computes the pairs that land on the experts it HOLDS
+  (``experts_held = (first, count)``: a chip's share of an expert-parallel
+  deployment), no pair dropped at any imbalance (pairs sorted by expert, slabs
+  of them through ``jax.lax.ragged_dot`` until none is left), plus the shared
+  expert; what the absent experts would add is left out.
+
+The decoder block is written once (``_blocks``); the paths supply their
+attention step.  The pool's device format (one ``[c_kv | k_r]`` row and one
+indexer key a token a layer) belongs to ``ops/paged_attention``.  Weights are
+built in ``dtype`` (bfloat16 as served; the router and the norms float32), so
+``matmul_operands`` is the tree itself.
+
+Counters (the contract's optional ``paged_counters``): the programs add to a
+small int32 leaf of the state; see ``COUNTERS``.
+
+Not built: the multi-token-prediction layer (the base model's logits do not
+depend on it), the indexer's FP8 / Hadamard rotation (quantisation aids).
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from analytics_zoo_tpu.nn.module import Layer
+from analytics_zoo_tpu.ops import paged_attention as paged
+
+NEG_INF = -1e30
+_QUERY_BLOCK = 256      # prefill queries attended at once ...
+_KEY_CHUNK = 4096       # ... over this many keys at once: bounds (heads, q, keys)
+_PAIR_SLAB = 2048       # token-expert pairs one grouped matmul takes
+_LIMB = 30              # a counter is (hi, lo) int32 with lo < 2 ** _LIMB
+
+# What the programs count, in the order of the state's ``counters`` leaf.
+# ``moe_*`` over every expert-layer call of every program, real tokens only
+# (no padding, no idle slot), except the two marked (decode): a prefill call
+# touches every expert, so they describe the decode step alone.  ``dsa_*``
+# over decode rows only (a prefill's selection is a mask, not a gather).
+COUNTERS = (
+    "moe_pairs",            # token-expert pairs routed, over all experts
+    "moe_pairs_held",       # ... that landed on an expert held here
+    "moe_pairs_busiest",    # ... on the busiest held expert, summed a call
+    "moe_experts_touched",  # (decode) held experts with >= 1 pair, summed
+    "moe_layer_steps",      # (decode) expert-layer calls
+    "dsa_keys_selected",    # keys attention read, summed a row a layer
+    "dsa_keys_context",     # keys in context there (selected <= context)
+)
+
+
+def _scope(name):
+    return jax.named_scope("zoo.lm." + name)
+
+
+def _no_counts():
+    return jnp.zeros((len(COUNTERS),), jnp.int32)
+
+
+class LatentMoELM(Layer):
+    """See the module docstring.  Constructor arguments carry the names of
+    the published ``config.json``; ``from_config`` reads one."""
+
+    def __init__(self, vocab_size: int, hidden_size: int,
+                 num_hidden_layers: int, first_k_dense_replace: int,
+                 intermediate_size: int, moe_intermediate_size: int,
+                 n_routed_experts: int, num_experts_per_tok: int,
+                 num_attention_heads: int, q_lora_rank: int,
+                 kv_lora_rank: int, qk_nope_head_dim: int,
+                 qk_rope_head_dim: int, v_head_dim: int, index_n_heads: int,
+                 index_head_dim: int, index_topk: int,
+                 n_shared_experts: int = 1,
+                 routed_scaling_factor: float = 2.5,
+                 rope_theta: float = 1e6, rms_norm_eps: float = 1e-5,
+                 max_position_embeddings: int = 8192,
+                 experts_held: Optional[Tuple[int, int]] = None,
+                 dtype: str = "bfloat16", initializer_range: float = 0.02,
+                 **kwargs):
+        super().__init__(**kwargs)
+        self.vocab_size = int(vocab_size)
+        self.hidden = int(hidden_size)
+        self.n_layers = int(num_hidden_layers)
+        self.n_dense = int(first_k_dense_replace)
+        self.dense_width = int(intermediate_size)
+        self.expert_width = int(moe_intermediate_size)
+        self.n_experts = int(n_routed_experts)
+        self.top_k = int(num_experts_per_tok)
+        self.n_shared = int(n_shared_experts)
+        self.route_scale = float(routed_scaling_factor)
+        self.n_head = int(num_attention_heads)
+        self.q_rank, self.kv_rank = int(q_lora_rank), int(kv_lora_rank)
+        self.nope, self.rope = int(qk_nope_head_dim), int(qk_rope_head_dim)
+        self.v_dim = int(v_head_dim)
+        self.index_heads = int(index_n_heads)
+        self.index_dim = int(index_head_dim)
+        self.index_topk = int(index_topk)
+        self.theta, self.eps = float(rope_theta), float(rms_norm_eps)
+        self.max_len = int(max_position_embeddings)
+        first, count = experts_held or (0, self.n_experts)
+        if not (0 <= first and count >= 1
+                and first + count <= self.n_experts):
+            raise ValueError(f"experts_held={experts_held} outside "
+                             f"{self.n_experts} experts")
+        if self.rope % 2 or self.rope > self.index_dim:
+            raise ValueError(f"qk_rope_head_dim={self.rope} must be even "
+                             f"and <= index_head_dim={self.index_dim}")
+        self.experts_held = (int(first), int(count))
+        self.dtype = jnp.dtype(dtype)
+        self.std = float(initializer_range)
+        self.kv_width = paged.latent_width(self.kv_rank, self.rope)
+        self._declared_input_shape = (None,)
+
+    @classmethod
+    def from_config(cls, cfg: dict, **overrides) -> "LatentMoELM":
+        """From a published ``config.json`` as a configuration file cuts it
+        (the sizing guide's convention): ``n_routed_experts`` counts the
+        experts HELD here when ``published.n_routed_experts`` gives the
+        router's width, ``deployment.chip`` says which share (chip c holds
+        experts ``c * held ...``).  Keys the class does not know are not
+        read."""
+        import inspect
+        known = set(inspect.signature(cls.__init__).parameters) - {"self"}
+        kw = {k: v for k, v in cfg.items() if k in known}
+        kw.setdefault("rope_theta",
+                      (cfg.get("rope_parameters") or {}).get("rope_theta",
+                                                             1e6))
+        held = int(cfg["n_routed_experts"])
+        total = int((cfg.get("published") or {}).get("n_routed_experts",
+                                                     held))
+        chip = int((cfg.get("deployment") or {}).get("chip", 0))
+        kw.update(n_routed_experts=total, experts_held=(chip * held, held))
+        kw.update(overrides)
+        return cls(**kw)
+
+    # -- weights --------------------------------------------------------------
+    def build(self, rng, input_shape=None):
+        """Random weights from ``rng`` (normal, ``initializer_range``), in
+        ``dtype``; norm gains 1 + 0.1 n and the selection bias 0.02 n so that
+        neither is a no-op; the router and every norm in float32."""
+        H, dt, std = self.hidden, self.dtype, self.std
+        nh, ih, idim = self.n_head, self.index_heads, self.index_dim
+        keys = iter(jax.random.split(rng, 4 + 32 * self.n_layers))
+
+        def w(*shape, dtype=dt, scale=std):
+            return (scale * jax.random.normal(next(keys), shape,
+                                              jnp.float32)).astype(dtype)
+
+        def gain(n):
+            return 1.0 + w(n, dtype=jnp.float32, scale=0.1)
+
+        def ffn(prefix, lead, width):
+            return {prefix + "gate": w(*lead, H, width),
+                    prefix + "up": w(*lead, H, width),
+                    prefix + "down": w(*lead, width, H)}
+
+        blocks = []
+        for li in range(self.n_layers):
+            blk = {
+                "ln1": gain(H), "ln2": gain(H),
+                "q_a": w(H, self.q_rank), "q_a_ln": gain(self.q_rank),
+                "q_b": w(self.q_rank, nh * (self.nope + self.rope)),
+                "kv_a": w(H, self.kv_rank + self.rope),
+                "kv_a_ln": gain(self.kv_rank),
+                "kv_b": w(self.kv_rank, nh * (self.nope + self.v_dim)),
+                "o": w(nh * self.v_dim, H),
+                "wq_b": w(self.q_rank, ih * idim), "wk": w(H, idim),
+                "k_ln": {"g": gain(idim),
+                         "b": w(idim, dtype=jnp.float32, scale=0.1)},
+                "w_proj": w(H, ih)}
+            if li < self.n_dense:
+                blk.update(ffn("", (), self.dense_width))
+            else:
+                blk["router"] = w(H, self.n_experts, dtype=jnp.float32)
+                blk["e_bias"] = w(self.n_experts, dtype=jnp.float32)
+                blk.update(ffn("w_", (self.experts_held[1],),
+                               self.expert_width))
+                blk.update(ffn("s_", (), self.n_shared * self.expert_width))
+            blocks.append(blk)
+        return {"embed": w(self.vocab_size, H), "ln_f": gain(H),
+                "head": w(H, self.vocab_size), "blocks": blocks}
+
+    def matmul_operands(self, params, dtype):
+        """The tree is built in its operand type: nothing to round."""
+        return params
+
+    # -- shared pieces --------------------------------------------------------
+    def _mm(self, x, W, out=jnp.float32):
+        return jnp.matmul(x.astype(W.dtype), W, preferred_element_type=out)
+
+    def _ein(self, spec, a, b):
+        return jnp.einsum(spec, a.astype(self.dtype), b.astype(self.dtype),
+                          preferred_element_type=jnp.float32)
+
+    def _rms(self, g, x):
+        return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
+                                 + self.eps) * g
+
+    def _rotary(self, x, pos):
+        """Interleaved rotary on the LAST axis of ``x`` (T, ..., d): the pair
+        (2i, 2i+1) turns by ``pos * theta ** (-2i / d)``."""
+        d = x.shape[-1]
+        ang = pos.astype(jnp.float32)[:, None] \
+            * self.theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+        ang = ang.reshape((x.shape[0],) + (1,) * (x.ndim - 2) + (d // 2,))
+        cos, sin = jnp.cos(ang), jnp.sin(ang)
+        a, b = x[..., 0::2], x[..., 1::2]
+        return jnp.stack([a * cos - b * sin, a * sin + b * cos],
+                         axis=-1).reshape(x.shape)
+
+    def _swiglu(self, h, gate, up, down):
+        return self._mm(jax.nn.silu(self._mm(h, gate)) * self._mm(h, up),
+                        down)
+
+    @staticmethod
+    def _ids(x):
+        x = jnp.asarray(x)
+        if x.ndim == 3 and x.shape[-1] == 1:
+            x = x[..., 0]
+        return x.astype(jnp.int32)
+
+    def _moe(self, blk, h, valid, decode: bool):
+        """The routed layer over tokens ``h`` (T, H), of which ``valid``
+        (T,) are real.  Returns ``(y, counts)``: routed (held experts only)
+        plus shared output, and this call's ``COUNTERS`` increments."""
+        T, H = h.shape
+        k, (first, count) = self.top_k, self.experts_held
+        with _scope("moe_route"):
+            s = jax.nn.sigmoid(jnp.matmul(
+                h, blk["router"], precision=jax.lax.Precision.HIGHEST))
+            _, idx = jax.lax.top_k(s + blk["e_bias"], k)          # (T, k)
+            g = jnp.take_along_axis(s, idx, axis=-1)
+            g = self.route_scale * g / (g.sum(-1, keepdims=True) + 1e-20)
+            local = idx - first
+            held = (local >= 0) & (local < count) & valid[:, None]
+            # pairs sorted by held expert; what is not held sorts behind
+            key = jnp.where(held, local, count).reshape(-1)
+            order = jnp.argsort(key)
+            tok, gate = order // k, g.reshape(-1)[order]
+            sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
+            ends = jnp.cumsum(sizes)
+            total = ends[-1]
+        with _scope("moe_experts"):
+            R = min(_PAIR_SLAB, T * k)
+            # the pair list is padded so that its last slab is whole
+            pad = (-(T * k)) % R
+            tok = jnp.concatenate([tok, jnp.zeros((pad,), tok.dtype)])
+            gate = jnp.concatenate([gate, jnp.zeros((pad,), gate.dtype)])
+
+            def slab(i, y):
+                # pairs [lo, lo + R) of the sorted list: each expert's rows
+                # inside the slab are one group of the grouped matmul
+                lo = i * R
+                rows = jax.lax.dynamic_slice(tok, (lo,), (R,))
+                wts = jax.lax.dynamic_slice(gate, (lo,), (R,))
+                gs = jnp.clip(ends, lo, lo + R) \
+                    - jnp.clip(ends - sizes, lo, lo + R)
+                x = jnp.take(h, rows, axis=0).astype(self.dtype)
+
+                def gmm(a, W):
+                    return jax.lax.ragged_dot(
+                        a, W, gs, preferred_element_type=jnp.float32)
+
+                mid = jax.nn.silu(gmm(x, blk["w_gate"])) * gmm(x, blk["w_up"])
+                out = gmm(mid.astype(self.dtype), blk["w_down"])
+                live = (lo + jnp.arange(R) < total)[:, None]
+                return y.at[rows].add(
+                    jnp.where(live, out * wts[:, None], 0.0))
+
+            y = jax.lax.fori_loop(0, (total + R - 1) // R, slab,
+                                  jnp.zeros((T, H), jnp.float32))
+            y = y + self._swiglu(h, blk["s_gate"], blk["s_up"],
+                                 blk["s_down"])
+        step = jnp.int32(1 if decode else 0)
+        counts = jnp.stack([
+            valid.sum().astype(jnp.int32) * k, total, sizes.max(),
+            step * (sizes > 0).sum().astype(jnp.int32), step,
+            jnp.int32(0), jnp.int32(0)])
+        return y, counts
+
+    def _blocks(self, params, x, pos, valid, attend, decode: bool = False):
+        """The decoder stack, written once, over tokens ``x`` (T, H) at
+        positions ``pos`` (T,).  ``attend(li, blk, q, c_kv, k_r, q_i, k_i,
+        w_i) -> (o, keep, counts)`` is the calling path's attention step:
+        rotary-applied queries (T, heads, nope + rope), this token's latent
+        row (normalised ``c_kv``, rotary-applied ``k_r``), the indexer's
+        queries (T, index heads, index dim), key and head weights; ``o`` is
+        (T, heads * v_head_dim), ``keep`` what the path carries out of the
+        layer.  Returns ``(h, keeps, counts)``."""
+        nh, T = self.n_head, x.shape[0]
+        keeps, counts = [], _no_counts()
+        for li, blk in enumerate(params["blocks"]):
+            h = self._rms(blk["ln1"], x)
+            with _scope("mla"):
+                c_q = self._rms(blk["q_a_ln"], self._mm(h, blk["q_a"]))
+                q = self._mm(c_q, blk["q_b"]).reshape(T, nh, -1)
+                q = jnp.concatenate(
+                    [q[..., :self.nope],
+                     self._rotary(q[..., self.nope:], pos)], axis=-1)
+                kv = self._mm(h, blk["kv_a"])
+                c_kv = self._rms(blk["kv_a_ln"], kv[:, :self.kv_rank])
+                k_r = self._rotary(kv[:, self.kv_rank:], pos)
+            with _scope("dsa_index"):
+                r = self.rope
+
+                def rot_first(a):
+                    return jnp.concatenate(
+                        [self._rotary(a[..., :r], pos), a[..., r:]], axis=-1)
+
+                q_i = rot_first(self._mm(c_q, blk["wq_b"]).reshape(
+                    T, self.index_heads, self.index_dim))
+                k_i = self._mm(h, blk["wk"])
+                mu = k_i.mean(-1, keepdims=True)
+                var = ((k_i - mu) ** 2).mean(-1, keepdims=True)
+                k_i = rot_first((k_i - mu) * jax.lax.rsqrt(var + 1e-6)
+                                * blk["k_ln"]["g"] + blk["k_ln"]["b"])
+                w_i = self._mm(h, blk["w_proj"]) \
+                    * (self.index_heads ** -0.5 * self.index_dim ** -0.5)
+            o, keep, c = attend(li, blk, q, c_kv, k_r, q_i, k_i, w_i)
+            keeps.append(keep)
+            counts = counts + c
+            x = x + self._mm(o, blk["o"])
+            h2 = self._rms(blk["ln2"], x)
+            if "router" in blk:
+                y, c = self._moe(blk, h2, valid, decode)
+                counts = counts + c
+            else:
+                y = self._swiglu(h2, blk["gate"], blk["up"], blk["down"])
+            x = x + y
+        return self._rms(params["ln_f"], x), keeps, counts
+
+    def _index_scores(self, q_i, k_i, w_i):
+        """``I[t, s] = sum_j w[t, j] relu(q_i[t, j] . k_i[s])`` for queries
+        (Q, heads, dim) against keys (S, dim): (Q, S)."""
+        dots = jax.nn.relu(self._ein("qjd,sd->qjs", q_i, k_i))
+        return jnp.einsum("qjs,qj->qs", dots, w_i)
+
+    @staticmethod
+    def _topk_mask(score, ok, k: int):
+        """The ``k`` largest of each row of ``score`` (Q, S) among ``ok``,
+        as a mask (all of ``ok`` where it has fewer than ``k``; among equal
+        scores the earlier key first, as ``lax.top_k`` has it): the exact
+        k-th largest found bit by bit over the floats' ordered integer
+        images, 32 counting passes and no sort."""
+        if k >= score.shape[-1]:
+            return ok
+        bits = jax.lax.bitcast_convert_type(score, jnp.int32)
+        # monotone image of the float order in unsigned integers, >= 1
+        u = jax.lax.bitcast_convert_type(
+            bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF)), jnp.uint32) \
+            ^ jnp.uint32(0x80000000)
+        u = jnp.where(ok, jnp.maximum(u, jnp.uint32(1)), jnp.uint32(0))
+
+        def bit(i, thr):
+            cand = thr | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(
+                jnp.uint32)))
+            enough = (u >= cand[:, None]).sum(-1) >= k
+            return jnp.where(enough, cand, thr)
+
+        thr = jax.lax.fori_loop(
+            0, 32, bit, jnp.zeros((score.shape[0],), jnp.uint32))[:, None]
+        above = u > thr
+        tie = ok & (u == thr)
+        room = k - above.sum(-1, keepdims=True)
+        return above | (tie & (jnp.cumsum(tie, axis=-1) <= room))
+
+    def _attend_chunks(self, q, k, v, allowed, key_pos, last, scale):
+        """Softmax attention of queries ``q`` (Q, heads, d) over keys ``k`` /
+        ``v`` (S, heads, d) under the mask ``allowed`` (Q, S), a chunk of
+        ``_KEY_CHUNK`` keys at a time with a running maximum, so that no
+        (heads, Q, S) array exists at once (the TPU compiler serves a softmax
+        over 8,192 keys at a twentieth of the speed of two over 4,096:
+        PERF.md, PR 32).  A chunk whose keys all lie after the queries' last
+        position ``last`` is skipped.  Returns (Q, heads * d_v)."""
+        dt, n = self.dtype, k.shape[0]
+        parts = []
+        for lo in range(0, n, _KEY_CHUNK):
+            hi = min(lo + _KEY_CHUNK, n)
+
+            def chunk(lo=lo, hi=hi):
+                att = self._ein("qhd,shd->hqs", q, k[lo:hi]) * scale
+                att = jnp.where(allowed[None, :, lo:hi], att, NEG_INF)
+                m = att.max(-1)
+                e = jnp.exp(att - m[..., None]).astype(dt)
+                return (m, e.sum(-1, dtype=jnp.float32),
+                        self._ein("hqs,shd->hqd", e, v[lo:hi]))
+
+            def skip():
+                hq = (q.shape[1], q.shape[0])
+                return (jnp.full(hq, NEG_INF, jnp.float32),
+                        jnp.zeros(hq, jnp.float32),
+                        jnp.zeros(hq + (v.shape[-1],), jnp.float32))
+
+            parts.append(chunk() if lo == 0 else jax.lax.cond(
+                jnp.min(key_pos[lo:hi]) <= last, chunk, skip))
+        top = functools.reduce(jnp.maximum, [m for m, _, _ in parts])
+        den = sum(jnp.exp(m - top) * s for m, s, _ in parts)
+        out = sum(jnp.exp(m - top)[..., None] * o for m, _, o in parts)
+        return (out / den[..., None]).transpose(1, 0, 2).reshape(
+            q.shape[0], -1)
+
+    def _softmax_scale(self):
+        return 1.0 / np.sqrt(self.nope + self.rope)
+
+    # -- prefill: one sequence, expanded attention over masked dense scores ---
+    def _forward_row(self, params, ids, length, base=0, prefix=None,
+                     counted=None):
+        """One sequence through the stack: ``ids`` (S,) right-padded tokens
+        of which ``length`` are real, at positions ``base + i``; ``prefix``
+        = per-layer ``(kv rows, ik rows)`` of ``base`` earlier tokens
+        (padded to PL rows) that join attention as keys; the first
+        ``counted`` tokens (default ``length``) enter the counters and the
+        expert layer's groups.  Returns ``(h (S, H), kvs, iks, counts)``:
+        the final hidden states and this sequence's per-layer cache rows."""
+        S = ids.shape[0]
+        qb = min(_QUERY_BLOCK, S)
+        if S % qb:
+            raise ValueError(f"prefill length {S} is no multiple of {qb}")
+        nh, dt = self.n_head, self.dtype
+        pos = base + jnp.arange(S)
+        PL = 0 if prefix is None else prefix[0][0].shape[0]
+        key_pos = jnp.concatenate([jnp.arange(PL), pos])
+        key_ok = jnp.concatenate([jnp.arange(PL) < base,
+                                  jnp.arange(S) < length])
+        scale = self._softmax_scale()
+
+        def attend(li, blk, q, c_kv, k_r, q_i, k_i, w_i):
+            # through the cache's type, as decode will read them back
+            kv_rows = paged.latent_rows(c_kv, k_r, self.kv_width, dt)
+            ik_rows = k_i.astype(dt)
+            keys_kv, keys_ik = kv_rows, ik_rows
+            if prefix is not None:
+                keys_kv = jnp.concatenate([prefix[0][li], kv_rows])
+                keys_ik = jnp.concatenate([prefix[1][li], ik_rows])
+            c = keys_kv[:, :self.kv_rank]
+            kr = keys_kv[:, self.kv_rank:self.kv_rank + self.rope]
+            with _scope("mla"):
+                kvb = self._mm(c, blk["kv_b"], out=dt).reshape(
+                    c.shape[0], nh, self.nope + self.v_dim)
+                k = jnp.concatenate(
+                    [kvb[..., :self.nope],
+                     jnp.broadcast_to(kr[:, None], (c.shape[0], nh,
+                                                    self.rope))], axis=-1)
+                v = kvb[..., self.nope:]
+
+            def block(args):
+                qq, qi, wi, t = args
+                with _scope("dsa_index"):
+                    score = self._index_scores(qi, keys_ik, wi)
+                with _scope("dsa_select"):
+                    ok = (key_pos[None, :] <= t[:, None]) & key_ok[None, :]
+                    allowed = self._topk_mask(score, ok, self.index_topk)
+                with _scope("mla"):
+                    return self._attend_chunks(qq, k, v, allowed, key_pos,
+                                               t[-1], scale)
+
+            def blocked(a):
+                return a.reshape((S // qb, qb) + a.shape[1:])
+
+            o = jax.lax.map(block, (blocked(q), blocked(q_i), blocked(w_i),
+                                    blocked(pos)))
+            return (o.reshape(S, nh * self.v_dim), (kv_rows, ik_rows),
+                    _no_counts())
+
+        x = jnp.take(params["embed"], ids, axis=0).astype(jnp.float32)
+        h, keeps, counts = self._blocks(
+            params, x, pos,
+            jnp.arange(S) < (length if counted is None else counted), attend)
+        kvs, iks = map(list, zip(*keeps))
+        return h, kvs, iks, counts
+
+    def call(self, params, inputs, *, training=False, rng=None):
+        """Teacher-forced logits (B, T, V), a sequence at a time."""
+        ids = self._ids(inputs)
+
+        def row(seq):
+            h, _, _, _ = self._forward_row(params, seq, seq.shape[0])
+            return self._mm(h, params["head"])
+
+        return jax.lax.map(row, ids)
+
+    # -- decode: absorbed attention over the selected rows --------------------
+    def decode_paged(self, params, state, block_tables, pos, tokens, *,
+                     block_len: int, kv_quant: str = "off", impl=None):
+        """One token a row against the latent pool (the contract's decode
+        step; the selected rows come through the block table by an XLA
+        gather, so ``impl`` has nothing to choose).  Returns ``(logits,
+        state)``."""
+        nh, bl = self.n_head, int(block_len)
+        bt = jnp.asarray(block_tables, jnp.int32)
+        pos = jnp.asarray(pos, jnp.int32)
+        cursor = paged.pool_cursor(bt, pos, bl)
+        active = bt[:, 0] != 0           # an idle slot's table is all trash
+        S = bt.shape[1] * bl
+        kk = min(self.index_topk, S)
+        scale = self._softmax_scale()
+        in_ctx = jnp.arange(S)[None, :] <= pos[:, None]            # (A, S)
+
+        def attend(li, blk, q, c_kv, k_r, q_i, k_i, w_i):
+            kv_pool, ik_pool = paged.latent_append(
+                state, li, paged.latent_rows(c_kv, k_r, self.kv_width,
+                                             self.dtype), k_i, cursor)
+            with _scope("dsa_index"):
+                keys = paged.latent_gather(ik_pool, bt)            # (A, S, d)
+                dots = jax.nn.relu(self._ein("ajd,asd->ajs", q_i, keys))
+                score = jnp.einsum("ajs,aj->as", dots, w_i)
+            with _scope("dsa_select"):
+                _, sel = jax.lax.top_k(jnp.where(in_ctx, score, -jnp.inf),
+                                       kk)
+                sel_ok = jnp.take_along_axis(in_ctx, sel, axis=1)
+                rows = paged.latent_select(kv_pool, bt, sel, bl)   # (A,kk,W)
+            with _scope("mla"):
+                c = rows[..., :self.kv_rank]
+                kr = rows[..., self.kv_rank:self.kv_rank + self.rope]
+                kvb = blk["kv_b"].reshape(self.kv_rank, nh,
+                                          self.nope + self.v_dim)
+                q_abs = self._ein("ahd,rhd->ahr", q[..., :self.nope],
+                                  kvb[..., :self.nope])
+                att = (self._ein("ahr,asr->ahs", q_abs, c)
+                       + self._ein("ahd,asd->ahs", q[..., self.nope:], kr)) \
+                    * scale
+                att = jnp.where(sel_ok[:, None], att, NEG_INF)
+                p = jax.nn.softmax(att, axis=-1)
+                lat = self._ein("ahs,asr->ahr", p, c)
+                o = self._ein("ahr,rhd->ahd", lat, kvb[..., self.nope:])
+            counts = _no_counts() \
+                .at[COUNTERS.index("dsa_keys_selected")].set(
+                    (sel_ok & active[:, None]).sum().astype(jnp.int32)) \
+                .at[COUNTERS.index("dsa_keys_context")].set(
+                    jnp.where(active, pos + 1, 0).sum().astype(jnp.int32))
+            return o.reshape(-1, nh * self.v_dim), (kv_pool, ik_pool), counts
+
+        x = jnp.take(params["embed"], jnp.asarray(tokens, jnp.int32),
+                     axis=0).astype(jnp.float32)
+        h, keeps, counts = self._blocks(params, x, pos, active, attend,
+                                        decode=True)
+        kvs, iks = map(list, zip(*keeps))
+        return self._mm(h, params["head"]), dict(
+            state, kv=kvs, ik=iks,
+            counters=self._bump(state["counters"], counts))
+
+    @staticmethod
+    def _bump(counters, counts):
+        """Add a call's ``counts`` (each < 2 ** 30) to the (n, 2) int32
+        ``(hi, lo)`` counters, which so hold 61 bits without int64."""
+        lo = counters[:, 1] + counts
+        return jnp.stack([counters[:, 0] + (lo >> _LIMB),
+                          lo & ((1 << _LIMB) - 1)], axis=1)
+
+    # -- the paged contract ---------------------------------------------------
+    def init_paged_pools(self, n_blocks: int, block_len: int,
+                         max_active: int, kv_quant: str = "off"):
+        """Zeroed state: ``ops/paged_attention``'s latent pool at this
+        model's depth and widths, and the counters."""
+        if kv_quant != "off":
+            raise ValueError("the latent pool has no quantised format")
+        return dict(paged.init_latent_pools(
+            self.n_layers, n_blocks, block_len, self.kv_rank, self.rope,
+            self.index_dim, self.dtype),
+            counters=np.zeros((len(COUNTERS), 2), np.int32))
+
+    def paged_state_bytes(self, state):
+        out = paged.pool_bytes({k: v for k, v in state.items()
+                                if k != "counters"})
+        out["lanes"] += int(np.prod(state["counters"].shape)) * 4
+        return out
+
+    def paged_counters(self, state):
+        """``COUNTERS`` as Python ints, read from the state on the host (one
+        small transfer; the caller owns the state, which must not be in a
+        call's hands)."""
+        c = np.asarray(state["counters"]).astype(np.int64)
+        return {name: int((c[i, 0] << _LIMB) + c[i, 1])
+                for i, name in enumerate(COUNTERS)}
+
+    def _prefill(self, params, state, prompt, lengths, dest, block_len,
+                 prefix_len=None, ptab=None):
+        """Rows in sequence inside ONE program (``lax.scan`` carries the
+        state), so a batch's temporaries are one row's."""
+        prompt = self._ids(prompt)
+        lengths = jnp.asarray(lengths, jnp.int32)
+        shared = ptab is not None
+        xs = (prompt, lengths, jnp.asarray(dest, jnp.int32)) + (
+            (jnp.asarray(prefix_len, jnp.int32),
+             jnp.asarray(ptab, jnp.int32)) if shared else ())
+
+        def row(st, x):
+            ids, n, dst = x[:3]
+            base, prefix = 0, None
+            if shared:
+                base, tab = x[3], x[4][None]
+                prefix = ([paged.latent_gather(p, tab)[0] for p in st["kv"]],
+                          [paged.latent_gather(p, tab)[0] for p in st["ik"]])
+            # a batch's padding row lands in the trash block: not counted
+            h, kvs, iks, counts = self._forward_row(
+                params, ids, n, base, prefix, jnp.where(dst[0] != 0, n, 0))
+            st = dict(paged.latent_commit(st, kvs, iks, dst,
+                                          block_len=block_len),
+                      counters=self._bump(st["counters"], counts))
+            last = jnp.take(h, jnp.maximum(n - 1, 0), axis=0)
+            return st, self._mm(last, params["head"])
+
+        return jax.lax.scan(row, state, xs)
+
+    def prefill_paged(self, params, state, prompt, lengths, dest, slots, *,
+                      block_len: int, kv_quant: str = "off"):
+        return self._prefill(params, state, prompt, lengths, dest,
+                             block_len)
+
+    def prefill_shared_paged(self, params, state, suffix, lengths,
+                             prefix_len, ptab, dest, slots, *,
+                             block_len: int, kv_quant: str = "off"):
+        return self._prefill(params, state, suffix, lengths, dest,
+                             block_len, prefix_len, ptab)
+
+    # -- contiguous caches (what ``ContinuousBatcher.__init__`` asks of every
+    # model; the paged decode over one block a row) ---------------------------
+    def init_decode(self, params, prompt, lengths=None,
+                    cache_len: Optional[int] = None):
+        prompt = self._ids(prompt)
+        B, P = prompt.shape
+        C = int(cache_len) if cache_len is not None else P
+        if C < P:
+            raise ValueError(f"cache_len={C} < prompt bucket {P}")
+        lengths = jnp.full((B,), P, jnp.int32) if lengths is None \
+            else jnp.asarray(lengths, jnp.int32)
+
+        def row(x):
+            ids, n = x
+            h, kvs, iks, _ = self._forward_row(params, ids, n)
+            last = jnp.take(h, jnp.maximum(n - 1, 0), axis=0)
+            return kvs, iks, self._mm(last, params["head"])
+
+        kvs, iks, logits0 = jax.lax.map(row, (prompt, lengths))
+
+        def cache(rows):
+            return jnp.zeros((B, C, rows.shape[-1]), rows.dtype) \
+                .at[:, :P].set(rows)
+
+        return {"pos": lengths, "kv": [cache(r) for r in kvs],
+                "ik": [cache(r) for r in iks]}, logits0
+
+    def decode_step(self, params, state, tokens):
+        B, C = state["kv"][0].shape[:2]
+
+        def pool(cache):       # row b's cache is block b + 1, block 0 trash
+            return jnp.concatenate([jnp.zeros_like(cache[:1]), cache])
+
+        pstate = {"kv": [pool(c) for c in state["kv"]],
+                  "ik": [pool(c) for c in state["ik"]],
+                  "counters": jnp.zeros((len(COUNTERS), 2), jnp.int32)}
+        logits, new = self.decode_paged(
+            params, pstate, 1 + jnp.arange(B)[:, None],
+            jnp.minimum(state["pos"], C - 1), tokens, block_len=C)
+        return logits, {"pos": state["pos"] + 1,
+                        "kv": [p[1:] for p in new["kv"]],
+                        "ik": [p[1:] for p in new["ik"]]}

@@ -412,6 +412,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, lengths,
 # prefill batch's K/V into blocks), ``pool_gather`` (prefix blocks back out
 # as float32 K/V), ``pool_cursor`` + ``pool_append_attend`` (one decode token
 # a row: append, then attend), ``pool_bytes`` (each leaf's accounting class).
+# A second format, one latent row a token, follows at the end of the file.
 #
 # A pool is a dict of per-layer lists.  ``k`` / ``v``: (n_blocks, block_len,
 # heads * head_dim) blocks, float32 or int8; block 0 is the TRASH block
@@ -424,7 +425,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, lengths,
 #
 _LEAF_CLASS = {"k": "paged_pool", "v": "paged_pool",
                "ks": "scales", "vs": "scales",
-               "stk": "lanes", "stv": "lanes"}
+               "stk": "lanes", "stv": "lanes",
+               "kv": "paged_pool", "ik": "paged_pool"}   # the latent format
 
 
 def init_pools(n_layers: int, n_blocks: int, block_len: int, n_head: int,
@@ -560,3 +562,97 @@ def pool_append_attend(pools, li: int, q, k, v, cursor, block_tables, pos,
     o = paged_attention(q, new["k"], new["v"], block_tables, pos + 1,
                         new.get("ks"), new.get("vs"), impl=impl)
     return o, new
+
+
+# -- the LATENT pool format (PR 32) -------------------------------------------
+#
+# A latent-attention model (MLA) with a learned selection (an indexer) keeps
+# ONE compressed row a token a layer, not per-head K and V, in two pools:
+#
+# - ``kv``: (n_blocks, block_len, latent_width(latent, rope)) rows of
+#   ``[c_kv (latent) | k_r (rope) | zeros]``, padded up to a multiple of the
+#   128 lanes (512 + 64 -> 640: 64 lanes = 128 B a token a layer of padding
+#   in bfloat16, 9.1 % of the two pools' 1,408 useful bytes), so that the
+#   rows a decode step selects come out of the pool in ONE gather;
+# - ``ik``: (n_blocks, block_len, index_dim) rows of the indexer's key
+#   (128 wide: a lane tile as it is), a pool of its own because a decode step
+#   reads EVERY live token's index key and only the selected tokens' latent
+#   rows.
+#
+# Block 0 is the trash block, as in the K/V format; the cache's dtype is the
+# model's (bfloat16 as served).  ``init_latent_pools`` (zeroed),
+# ``latent_commit`` (one prefilled sequence into its blocks),
+# ``latent_gather`` (whole blocks back out, in table order: the indexer's
+# keys of a decode row, a shared prefix's rows), ``latent_append`` (one decode
+# token a row), ``latent_select`` (the selected tokens' rows through the block
+# table); ``pool_cursor`` and ``pool_bytes`` serve both formats.
+
+def latent_width(latent_dim: int, rope_dim: int) -> int:
+    """Lanes of a ``kv`` row: ``latent_dim + rope_dim`` rounded up to 128."""
+    return -(-(latent_dim + rope_dim) // _LANE) * _LANE
+
+
+def init_latent_pools(n_layers: int, n_blocks: int, block_len: int,
+                      latent_dim: int, rope_dim: int, index_dim: int, dtype):
+    """Zeroed host-side latent pool pytree (``n_blocks`` counts the trash
+    block)."""
+    width = latent_width(latent_dim, rope_dim)
+    return {"kv": [np.zeros((n_blocks, block_len, width), dtype)
+                   for _ in range(n_layers)],
+            "ik": [np.zeros((n_blocks, block_len, index_dim), dtype)
+                   for _ in range(n_layers)]}
+
+
+def latent_rows(c_kv, k_r, width: int, dtype):
+    """``[c_kv | k_r | zeros]`` rows of the ``kv`` pool, (..., width)."""
+    pad = width - c_kv.shape[-1] - k_r.shape[-1]
+    return jnp.concatenate(
+        [c_kv, k_r, jnp.zeros(c_kv.shape[:-1] + (pad,), c_kv.dtype)],
+        axis=-1).astype(dtype)
+
+
+def latent_commit(pools, kvs, iks, dest, *, block_len: int):
+    """ONE prefilled sequence into the pool: ``kvs`` / ``iks`` are per-layer
+    (P, width) / (P, index_dim) rows, block t of which lands at pool id
+    ``dest[t]`` (0 = trash, for blocks past the sequence's fill).  Returns
+    the new pool."""
+    bl, npb = int(block_len), dest.shape[0]
+
+    def blocks(rows):
+        pad = npb * bl - rows.shape[0]
+        if pad:
+            rows = jnp.concatenate(
+                [rows, jnp.zeros((pad, rows.shape[1]), rows.dtype)])
+        return rows.reshape(npb, bl, rows.shape[1])
+
+    return dict(pools,
+                kv=[p.at[dest].set(blocks(r).astype(p.dtype))
+                    for p, r in zip(pools["kv"], kvs)],
+                ik=[p.at[dest].set(blocks(r).astype(p.dtype))
+                    for p, r in zip(pools["ik"], iks)])
+
+
+def latent_gather(pool, tables):
+    """The blocks ``tables`` (rows, n) names out of one layer's ``kv`` or
+    ``ik`` pool, in table order: (rows, n * block_len, width)."""
+    blocks = jnp.take(pool, tables, axis=0)
+    return blocks.reshape(blocks.shape[0], -1, blocks.shape[-1])
+
+
+def latent_append(pools, li: int, kv_rows, ik_rows, cursor):
+    """Layer ``li`` of one decode step: each row's token row into the block
+    under its ``cursor`` (``pool_cursor``).  Returns the layer's two new
+    leaves ``(kv, ik)``."""
+    _, cur, off = cursor
+    kv, ik = pools["kv"][li], pools["ik"][li]
+    return (kv.at[cur, off].set(kv_rows.astype(kv.dtype)),
+            ik.at[cur, off].set(ik_rows.astype(ik.dtype)))
+
+
+def latent_select(pool, block_tables, sel, block_len: int):
+    """The ``kv`` rows of the cache positions ``sel`` (rows, k) of each row,
+    through its block table: (rows, k, width).  The sparse selection inside
+    paged attention: only these rows are read."""
+    blk = jnp.take_along_axis(block_tables, sel // block_len, axis=1)
+    flat = blk * block_len + sel % block_len
+    return jnp.take(pool.reshape(-1, pool.shape[-1]), flat, axis=0)

@@ -989,6 +989,20 @@ class ClusterServing:
                 child = phase_g.labels(phase=name)
                 child.add_function(fn)
                 self._gauge_fns.append((child, fn))
+            # what the model's programs count on the device (PR 32): the
+            # scheduler's last reading, none for a model without counters
+            if self._batcher.model_counters:
+                model_g = reg.gauge(
+                    "serving_generate_model_counter",
+                    "Cumulative device-side counters of the served model's "
+                    "programs (the paged contract's paged_counters), as "
+                    "the scheduler last read them", labels=("name",))
+                for name in self._batcher.model_counters:
+                    fn = (lambda n=name, b=self._batcher:
+                          float(b.model_counters[n]))
+                    child = model_g.labels(name=name)
+                    child.add_function(fn)
+                    self._gauge_fns.append((child, fn))
             self._last_steps = 0
             self._tps_window = (time.monotonic(), 0)   # (t0, tokens0)
             # generation continuity (PR 20): where checkpoints spool

@@ -365,6 +365,7 @@ class ContinuousBatcher:
     _PAGED_CONTRACT = ("init_paged_pools", "prefill_paged",
                        "prefill_shared_paged", "decode_paged",
                        "paged_state_bytes")
+    # (optional beside them: ``matmul_operands``, ``paged_counters``)
 
     def __init__(self, model, gen: GenerationParams):
         inner = getattr(model, "_model", None)
@@ -512,6 +513,17 @@ class ContinuousBatcher:
         # the whole warm-up set.)
         for lane in self._lanes:
             self._ensure_lane_state(lane)
+        # what the model's programs count on the device (the paged
+        # contract's OPTIONAL ``paged_counters(state) -> {name: number}``,
+        # e.g. expert load): read on this thread where ``fold`` has just
+        # waited for a decode call's tokens, never while a call holds the
+        # donated state; ``stats()`` publishes the last reading as
+        # ``model.<name>``.  Empty for a model without the method.
+        self._model_counters = getattr(inner, "paged_counters", None) \
+            if gen.paged else None
+        self.model_counters: Dict[str, float] = {} \
+            if self._model_counters is None \
+            else self._model_counters(self._lanes[0].state)
 
     # -- program construction (compile-once) ----------------------------------
     def _params(self):
@@ -1534,6 +1546,8 @@ class ContinuousBatcher:
                 lane.pos = np.minimum(
                     lane.pos + np.int32(block.shape[0]),
                     np.int32(lane.bucket)).astype(np.int32)
+                if self._model_counters is not None:
+                    self.model_counters = self._model_counters(lane.state)
             else:
                 key = ("decode_step", lane.bucket)
                 block, lane.state = self._compiled(key, lane)(
@@ -1795,6 +1809,8 @@ class ContinuousBatcher:
             d["phase_s." + name] = seconds[name]
             d["phase_n." + name] = counts[name]
         d["loop_s"] = sum(seconds.values())
+        for name, value in self.model_counters.items():
+            d["model." + name] = value
         if self._pool is not None:
             pool = {"blocks": self._pool.n_blocks,
                     "block_len": self._pool.block_len,
