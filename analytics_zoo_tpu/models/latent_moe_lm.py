@@ -505,8 +505,10 @@ class LatentMoELM(Layer):
                      block_len: int, kv_quant: str = "off", impl=None):
         """One token a row against the latent pool (the contract's decode
         step; the selected rows come through the block table by an XLA
-        gather, so ``impl`` has nothing to choose).  Returns ``(logits,
-        state)``."""
+        gather, so ``impl`` has nothing to choose).  That gather is the
+        selection's only one: whether a selected position is in context and
+        where its row lies are computed from ``pos`` and the table.  Returns
+        ``(logits, state)``."""
         nh, bl = self.n_head, int(block_len)
         bt = jnp.asarray(block_tables, jnp.int32)
         pos = jnp.asarray(pos, jnp.int32)
@@ -528,7 +530,7 @@ class LatentMoELM(Layer):
             with _scope("dsa_select"):
                 _, sel = jax.lax.top_k(jnp.where(in_ctx, score, -jnp.inf),
                                        kk)
-                sel_ok = jnp.take_along_axis(in_ctx, sel, axis=1)
+                sel_ok = sel <= pos[:, None]        # in_ctx at sel
                 rows = paged.latent_select(kv_pool, bt, sel, bl)   # (A,kk,W)
             with _scope("mla"):
                 c = rows[..., :self.kv_rank]

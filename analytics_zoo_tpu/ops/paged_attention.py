@@ -585,7 +585,8 @@ def pool_append_attend(pools, li: int, q, k, v, cursor, block_tables, pos,
 # ``latent_gather`` (whole blocks back out, in table order: the indexer's
 # keys of a decode row, a shared prefix's rows), ``latent_append`` (one decode
 # token a row), ``latent_select`` (the selected tokens' rows through the block
-# table); ``pool_cursor`` and ``pool_bytes`` serve both formats.
+# table, their addresses computed from it); ``pool_cursor`` and ``pool_bytes``
+# serve both formats.
 
 def latent_width(latent_dim: int, rope_dim: int) -> int:
     """Lanes of a ``kv`` row: ``latent_dim + rope_dim`` rounded up to 128."""
@@ -652,7 +653,14 @@ def latent_append(pools, li: int, kv_rows, ik_rows, cursor):
 def latent_select(pool, block_tables, sel, block_len: int):
     """The ``kv`` rows of the cache positions ``sel`` (rows, k) of each row,
     through its block table: (rows, k, width).  The sparse selection inside
-    paged attention: only these rows are read."""
-    blk = jnp.take_along_axis(block_tables, sel // block_len, axis=1)
+    paged attention: only these rows are read, in the stage's ONE gather.
+    A row's address is computed, not looked up: position ``s`` lies under
+    table entry ``s // block_len``, and that entry's block id is the sum of
+    the row's table under the mask ``s // block_len == arange(n_table)``
+    (int32, so exact; a reduce over (rows, n_table, k) that is fused and
+    never stored)."""
+    entry = jnp.arange(block_tables.shape[1], dtype=sel.dtype)
+    hit = (sel // block_len)[:, None, :] == entry[None, :, None]
+    blk = jnp.where(hit, block_tables[:, :, None], 0).sum(axis=1)
     flat = blk * block_len + sel % block_len
     return jnp.take(pool.reshape(-1, pool.shape[-1]), flat, axis=0)

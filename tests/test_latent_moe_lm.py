@@ -418,6 +418,247 @@ def test_the_keys_used_are_the_top_k(k):
     np.testing.assert_array_equal(picked & ok, want)
 
 
+# -- (c2) the decode selection computes its mask and row addresses (PR 34) ------
+
+def _selection_draw(block_len, n_table):
+    """Four rows over a pool whose every row holds its own flat address:
+    shuffled block ids (up to 4 x ``n_table``: past 256 at the widest table),
+    the last row an idle slot (its table all trash), and 24 selected
+    positions a row with position 0, a block's last position, the row's own
+    position and the lane's last position (beyond ``pos`` for three rows)
+    among them."""
+    g = np.random.default_rng(1000 * block_len + n_table)
+    rows, S = 4, n_table * block_len
+    n_blocks = 1 + rows * n_table
+    pool = np.arange(n_blocks * block_len, dtype=np.float32)[:, None] \
+        + np.arange(8, dtype=np.float32) / 8
+    tables = (1 + g.permutation(rows * n_table)).reshape(rows, n_table) \
+        .astype(np.int32)
+    tables[-1] = 0
+    pos = np.array([0, S // 2, S - 1, S // 3], np.int32)
+    sel = np.stack([g.permutation(S)[:min(24, S)] for _ in range(rows)]) \
+        .astype(np.int32)
+    sel[:, 0], sel[:, 1], sel[:, 2], sel[:, 3] = 0, block_len - 1, pos, S - 1
+    return pool.reshape(n_blocks, block_len, 8), tables, pos, sel
+
+
+@pytest.mark.parametrize("n_table", [1, 8, 128])
+@pytest.mark.parametrize("block_len", [16, 64])
+def test_the_selection_computes_what_the_lookups_read(block_len, n_table):
+    """``paged.latent_select`` (block id = the row's table summed under
+    ``sel // block_len == arange(n_table)``) names the rows a NumPy walk of
+    the block table names, and ``sel <= pos`` is ``arange(S) <= pos`` read
+    at ``sel``: the same integers and booleans as the two
+    ``take_along_axis`` of PR 32 on the same draws."""
+    import jax
+    import jax.numpy as jnp
+    from analytics_zoo_tpu.ops import paged_attention as paged
+    pool, tables, pos, sel = _selection_draw(block_len, n_table)
+    walked = np.stack([[pool[tables[r, s // block_len], s % block_len]
+                        for s in sel[r]] for r in range(len(sel))])
+    got = np.asarray(jax.jit(functools.partial(
+        paged.latent_select, block_len=block_len))(pool, tables, sel))
+    np.testing.assert_array_equal(got, walked)
+    looked_up = jnp.take_along_axis(jnp.asarray(tables), sel // block_len,
+                                    axis=1) * block_len + sel % block_len
+    np.testing.assert_array_equal(got[..., 0], np.asarray(looked_up))
+    assert got[-1, :, 0].max() < block_len    # the idle slot: trash rows
+    in_ctx = jnp.arange(n_table * block_len)[None, :] <= pos[:, None]
+    was = np.asarray(jnp.take_along_axis(in_ctx, sel, axis=1))
+    np.testing.assert_array_equal(np.asarray(sel <= pos[:, None]), was)
+    assert was[:, 2].all() and list(was[:, 3]) == [n_table * block_len == 1,
+                                                   False, True, False]
+
+
+def _lookup_oracle_class():
+    """``LatentMoELM`` with PR 32's decode step: the selection's mask and
+    block ids LOOKED UP by ``take_along_axis`` (what the package no longer
+    does), the rest of ``decode_paged`` line for line."""
+    import jax
+    import jax.numpy as jnp
+    from analytics_zoo_tpu.models import latent_moe_lm as mod
+    from analytics_zoo_tpu.ops import paged_attention as paged
+
+    class Oracle(mod.LatentMoELM):
+        def decode_paged(self, params, state, block_tables, pos, tokens, *,
+                         block_len, kv_quant="off", impl=None):
+            nh, bl = self.n_head, int(block_len)
+            bt = jnp.asarray(block_tables, jnp.int32)
+            pos = jnp.asarray(pos, jnp.int32)
+            cursor = paged.pool_cursor(bt, pos, bl)
+            active = bt[:, 0] != 0
+            S = bt.shape[1] * bl
+            kk = min(self.index_topk, S)
+            scale = self._softmax_scale()
+            in_ctx = jnp.arange(S)[None, :] <= pos[:, None]
+
+            def attend(li, blk, q, c_kv, k_r, q_i, k_i, w_i):
+                kv_pool, ik_pool = paged.latent_append(
+                    state, li, paged.latent_rows(c_kv, k_r, self.kv_width,
+                                                 self.dtype), k_i, cursor)
+                with mod._scope("dsa_index"):
+                    keys = paged.latent_gather(ik_pool, bt)
+                    dots = jax.nn.relu(self._ein("ajd,asd->ajs", q_i, keys))
+                    score = jnp.einsum("ajs,aj->as", dots, w_i)
+                with mod._scope("dsa_select"):
+                    _, sel = jax.lax.top_k(
+                        jnp.where(in_ctx, score, -jnp.inf), kk)
+                    sel_ok = jnp.take_along_axis(in_ctx, sel, axis=1)
+                    blk_id = jnp.take_along_axis(bt, sel // bl, axis=1)
+                    rows = jnp.take(kv_pool.reshape(-1, kv_pool.shape[-1]),
+                                    blk_id * bl + sel % bl, axis=0)
+                with mod._scope("mla"):
+                    c = rows[..., :self.kv_rank]
+                    kr = rows[..., self.kv_rank:self.kv_rank + self.rope]
+                    kvb = blk["kv_b"].reshape(self.kv_rank, nh,
+                                              self.nope + self.v_dim)
+                    q_abs = self._ein("ahd,rhd->ahr", q[..., :self.nope],
+                                      kvb[..., :self.nope])
+                    att = (self._ein("ahr,asr->ahs", q_abs, c)
+                           + self._ein("ahd,asd->ahs", q[..., self.nope:],
+                                       kr)) * scale
+                    att = jnp.where(sel_ok[:, None], att, mod.NEG_INF)
+                    p = jax.nn.softmax(att, axis=-1)
+                    lat = self._ein("ahs,asr->ahr", p, c)
+                    o = self._ein("ahr,rhd->ahd", lat, kvb[..., self.nope:])
+                counts = mod._no_counts() \
+                    .at[mod.COUNTERS.index("dsa_keys_selected")].set(
+                        (sel_ok & active[:, None]).sum().astype(jnp.int32)) \
+                    .at[mod.COUNTERS.index("dsa_keys_context")].set(
+                        jnp.where(active, pos + 1, 0).sum().astype(
+                            jnp.int32))
+                return (o.reshape(-1, nh * self.v_dim), (kv_pool, ik_pool),
+                        counts)
+
+            x = jnp.take(params["embed"], jnp.asarray(tokens, jnp.int32),
+                         axis=0).astype(jnp.float32)
+            h, keeps, counts = self._blocks(params, x, pos, active, attend,
+                                            decode=True)
+            kvs, iks = map(list, zip(*keeps))
+            return self._mm(h, params["head"]), dict(
+                state, kv=kvs, ik=iks,
+                counters=self._bump(state["counters"], counts))
+
+    return Oracle
+
+
+def _lookup_oracle():
+    """The oracle at the toy configuration: ``_lm()``'s weights serve it."""
+    return _lm(_lookup_oracle_class())[0]
+
+
+def _decode_rows():
+    """Three rows after a prefill, contexts 16, 3 and 9 around ``index_topk``
+    6, the last one's slot idle since (its table all trash)."""
+    lm, params, _ = _lm()
+    prompt = np.random.default_rng(34).integers(1, 97, (3, 16)) \
+        .astype(np.int32)
+    lens = np.array([16, 3, 9], np.int32)
+    state, tables, logits0 = _prefilled(lm, params, prompt, lens)
+    tables = tables.copy()
+    tables[2] = 0
+    return (lm, params, state, tables, lens,
+            np.asarray(logits0).argmax(-1).astype(np.int32))
+
+
+def _gathers_by_stage(fn, *args):
+    """``{stage: [(operand shape, operand dtype) of each gather]}`` over the
+    jaxpr of ``fn`` and its sub-jaxprs; the stage is the innermost
+    ``zoo.lm.<stage>`` scope, ``-`` outside every one."""
+    import re
+
+    import jax
+    out = {}
+
+    def walk(jaxpr, outer):
+        for eqn in jaxpr.eqns:
+            stack = f"{outer}/{eqn.source_info.name_stack}"
+            if eqn.primitive.name == "gather":
+                stage = re.findall(r"zoo\.lm\.(\w+)", stack)
+                out.setdefault(stage[-1] if stage else "-", []).append(
+                    (tuple(eqn.invars[0].aval.shape),
+                     str(eqn.invars[0].aval.dtype)))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, stack)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr, "")
+    return out
+
+
+def test_a_decode_layers_selection_holds_one_gather():
+    """By stage and operand over the decode step's jaxpr:
+    ``zoo.lm.dsa_select`` holds ONE gather a layer, the selected rows out of
+    the ``kv`` pool laid flat; no gather anywhere reads booleans, and the
+    block table is gathered once, outside every stage, by ``pool_cursor``
+    (rotary's strided slices and the router's are gathers too, so the count
+    is by stage).  The oracle holds the two lookups a layer more."""
+    lm, params, state, tables, pos, tok = _decode_rows()
+    L, kv = lm.n_layers, state["kv"][0].shape
+
+    def gathers(model):
+        return _gathers_by_stage(
+            lambda p, s: model.decode_paged(p, s, tables, pos, tok,
+                                            block_len=4), params, state)
+
+    got, was = gathers(lm), gathers(_lookup_oracle())
+    rows_read = ((kv[0] * kv[1], kv[2]), "float32")
+    table_read = (tables.shape, "int32")
+    flat = [op for ops in got.values() for op in ops]
+    assert got["dsa_select"] == [rows_read] * L
+    assert flat.count(table_read) == 1 and table_read in got["-"]
+    assert not [op for op in flat if op[1] == "bool"]
+    extra = list(was["dsa_select"])
+    for op in got["dsa_select"]:
+        extra.remove(op)
+    assert sorted(extra) == sorted([(tables.shape[:1] + (tables.shape[1] * 4,),
+                                     "bool"), table_read] * L)
+    assert {k: v for k, v in was.items() if k != "dsa_select"} \
+        == {k: v for k, v in got.items() if k != "dsa_select"}
+
+
+@pytest.mark.parametrize("path", ["paged", "contiguous"])
+def test_decode_is_the_lookup_oracle_bit_for_bit(path):
+    """Four decode steps of the package's step and of the oracle that looks
+    the mask and the block ids up: logits and every leaf of the state (both
+    pools, the counters) are the same BITS, since only integers and booleans
+    are come by another way.  ``paged``: contexts 16 and 3 crossing
+    ``index_topk`` and an idle slot; ``contiguous``: ``decode_step``'s
+    one-entry table."""
+    import jax
+    oracle = _lookup_oracle()
+    if path == "paged":
+        lm, params, state, tables, pos, tok = _decode_rows()
+
+        def steps(model):
+            f = jax.jit(functools.partial(model.decode_paged, block_len=4))
+            return lambda st, po, tk: f(params, st, tables, po, tk)
+    else:
+        lm, params, _ = _lm()
+        prompt = np.random.default_rng(35).integers(1, 97, (2, 16)) \
+            .astype(np.int32)
+        pos = np.array([16, 7], np.int32)      # the state carries its own
+        state, logits0 = jax.jit(functools.partial(
+            lm.init_decode, cache_len=32))(params, prompt, pos)
+        tok = np.asarray(logits0).argmax(-1).astype(np.int32)
+
+        def steps(model):
+            f = jax.jit(model.decode_step)
+            return lambda st, po, tk: f(params, st, tk)
+    step, ostep, ostate = steps(lm), steps(oracle), state
+    for _ in range(4):
+        logits, state = step(state, pos, tok)
+        ologits, ostate = ostep(ostate, pos, tok)
+        np.testing.assert_array_equal(np.asarray(logits),
+                                      np.asarray(ologits))
+        jax.tree_util.tree_map(np.testing.assert_array_equal, state, ostate)
+        tok, pos = np.asarray(logits).argmax(-1).astype(np.int32), pos + 1
+    if path == "paged":
+        # a layer: min(context, 6) keys of the rows at 16.. and 3.., the
+        # idle slot's none
+        assert lm.paged_counters(state)["dsa_keys_selected"] \
+            == lm.n_layers * (4 * 6 + (4 + 5 + 6 + 6))
+
+
 # -- (d), (e) the expert layer -------------------------------------------------
 
 def test_the_shares_add_up_to_the_uncut_layer():
