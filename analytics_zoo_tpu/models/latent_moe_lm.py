@@ -39,21 +39,20 @@ depend on it), the indexer's FP8 / Hadamard rotation (quantisation aids).
 
 from __future__ import annotations
 
-import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from analytics_zoo_tpu.models import lm_common as common
+from analytics_zoo_tpu.models.lm_common import NEG_INF, scope as _scope
 from analytics_zoo_tpu.nn.module import Layer
 from analytics_zoo_tpu.ops import paged_attention as paged
 
-NEG_INF = -1e30
 _QUERY_BLOCK = 256      # prefill queries attended at once ...
 _KEY_CHUNK = 4096       # ... over this many keys at once: bounds (heads, q, keys)
 _PAIR_SLAB = 2048       # token-expert pairs one grouped matmul takes
-_LIMB = 30              # a counter is (hi, lo) int32 with lo < 2 ** _LIMB
 
 # What the programs count, in the order of the state's ``counters`` leaf.
 # ``moe_*`` over every expert-layer call of every program, real tokens only
@@ -71,12 +70,8 @@ COUNTERS = (
 )
 
 
-def _scope(name):
-    return jax.named_scope("zoo.lm." + name)
-
-
 def _no_counts():
-    return jnp.zeros((len(COUNTERS),), jnp.int32)
+    return common.no_counts(COUNTERS)
 
 
 class LatentMoELM(Layer):
@@ -205,40 +200,21 @@ class LatentMoELM(Layer):
         """The tree is built in its operand type: nothing to round."""
         return params
 
-    # -- shared pieces --------------------------------------------------------
-    def _mm(self, x, W, out=jnp.float32):
-        return jnp.matmul(x.astype(W.dtype), W, preferred_element_type=out)
+    # -- shared pieces (``models/lm_common``, at this model's settings) --------
+    _mm = staticmethod(common.mm)
+    _swiglu = staticmethod(common.swiglu)
+    _ids = staticmethod(common.ids)
+    _topk_mask = staticmethod(common.topk_mask)
+    _bump = staticmethod(common.bump)
 
     def _ein(self, spec, a, b):
-        return jnp.einsum(spec, a.astype(self.dtype), b.astype(self.dtype),
-                          preferred_element_type=jnp.float32)
+        return common.ein(spec, a, b, self.dtype)
 
     def _rms(self, g, x):
-        return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
-                                 + self.eps) * g
+        return common.rms(g, x, self.eps)
 
     def _rotary(self, x, pos):
-        """Interleaved rotary on the LAST axis of ``x`` (T, ..., d): the pair
-        (2i, 2i+1) turns by ``pos * theta ** (-2i / d)``."""
-        d = x.shape[-1]
-        ang = pos.astype(jnp.float32)[:, None] \
-            * self.theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-        ang = ang.reshape((x.shape[0],) + (1,) * (x.ndim - 2) + (d // 2,))
-        cos, sin = jnp.cos(ang), jnp.sin(ang)
-        a, b = x[..., 0::2], x[..., 1::2]
-        return jnp.stack([a * cos - b * sin, a * sin + b * cos],
-                         axis=-1).reshape(x.shape)
-
-    def _swiglu(self, h, gate, up, down):
-        return self._mm(jax.nn.silu(self._mm(h, gate)) * self._mm(h, up),
-                        down)
-
-    @staticmethod
-    def _ids(x):
-        x = jnp.asarray(x)
-        if x.ndim == 3 and x.shape[-1] == 1:
-            x = x[..., 0]
-        return x.astype(jnp.int32)
+        return common.rotary(x, pos, self.theta)
 
     def _moe(self, blk, h, valid, decode: bool):
         """The routed layer over tokens ``h`` (T, H), of which ``valid``
@@ -356,69 +332,9 @@ class LatentMoELM(Layer):
         dots = jax.nn.relu(self._ein("qjd,sd->qjs", q_i, k_i))
         return jnp.einsum("qjs,qj->qs", dots, w_i)
 
-    @staticmethod
-    def _topk_mask(score, ok, k: int):
-        """The ``k`` largest of each row of ``score`` (Q, S) among ``ok``,
-        as a mask (all of ``ok`` where it has fewer than ``k``; among equal
-        scores the earlier key first, as ``lax.top_k`` has it): the exact
-        k-th largest found bit by bit over the floats' ordered integer
-        images, 32 counting passes and no sort."""
-        if k >= score.shape[-1]:
-            return ok
-        bits = jax.lax.bitcast_convert_type(score, jnp.int32)
-        # monotone image of the float order in unsigned integers, >= 1
-        u = jax.lax.bitcast_convert_type(
-            bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF)), jnp.uint32) \
-            ^ jnp.uint32(0x80000000)
-        u = jnp.where(ok, jnp.maximum(u, jnp.uint32(1)), jnp.uint32(0))
-
-        def bit(i, thr):
-            cand = thr | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(
-                jnp.uint32)))
-            enough = (u >= cand[:, None]).sum(-1) >= k
-            return jnp.where(enough, cand, thr)
-
-        thr = jax.lax.fori_loop(
-            0, 32, bit, jnp.zeros((score.shape[0],), jnp.uint32))[:, None]
-        above = u > thr
-        tie = ok & (u == thr)
-        room = k - above.sum(-1, keepdims=True)
-        return above | (tie & (jnp.cumsum(tie, axis=-1) <= room))
-
     def _attend_chunks(self, q, k, v, allowed, key_pos, last, scale):
-        """Softmax attention of queries ``q`` (Q, heads, d) over keys ``k`` /
-        ``v`` (S, heads, d) under the mask ``allowed`` (Q, S), a chunk of
-        ``_KEY_CHUNK`` keys at a time with a running maximum, so that no
-        (heads, Q, S) array exists at once (the TPU compiler serves a softmax
-        over 8,192 keys at a twentieth of the speed of two over 4,096:
-        PERF.md, PR 32).  A chunk whose keys all lie after the queries' last
-        position ``last`` is skipped.  Returns (Q, heads * d_v)."""
-        dt, n = self.dtype, k.shape[0]
-        parts = []
-        for lo in range(0, n, _KEY_CHUNK):
-            hi = min(lo + _KEY_CHUNK, n)
-
-            def chunk(lo=lo, hi=hi):
-                att = self._ein("qhd,shd->hqs", q, k[lo:hi]) * scale
-                att = jnp.where(allowed[None, :, lo:hi], att, NEG_INF)
-                m = att.max(-1)
-                e = jnp.exp(att - m[..., None]).astype(dt)
-                return (m, e.sum(-1, dtype=jnp.float32),
-                        self._ein("hqs,shd->hqd", e, v[lo:hi]))
-
-            def skip():
-                hq = (q.shape[1], q.shape[0])
-                return (jnp.full(hq, NEG_INF, jnp.float32),
-                        jnp.zeros(hq, jnp.float32),
-                        jnp.zeros(hq + (v.shape[-1],), jnp.float32))
-
-            parts.append(chunk() if lo == 0 else jax.lax.cond(
-                jnp.min(key_pos[lo:hi]) <= last, chunk, skip))
-        top = functools.reduce(jnp.maximum, [m for m, _, _ in parts])
-        den = sum(jnp.exp(m - top) * s for m, s, _ in parts)
-        out = sum(jnp.exp(m - top)[..., None] * o for m, _, o in parts)
-        return (out / den[..., None]).transpose(1, 0, 2).reshape(
-            q.shape[0], -1)
+        return common.attend_chunks(q, k, v, allowed, key_pos, last, scale,
+                                    self.dtype, _KEY_CHUNK)
 
     def _softmax_scale(self):
         return 1.0 / np.sqrt(self.nope + self.rope)
@@ -472,8 +388,9 @@ class LatentMoELM(Layer):
                     ok = (key_pos[None, :] <= t[:, None]) & key_ok[None, :]
                     allowed = self._topk_mask(score, ok, self.index_topk)
                 with _scope("mla"):
-                    return self._attend_chunks(qq, k, v, allowed, key_pos,
-                                               t[-1], scale)
+                    return self._attend_chunks(
+                        qq, k, v, lambda lo, hi: allowed[None, :, lo:hi],
+                        key_pos, t[-1], scale)
 
             def blocked(a):
                 return a.reshape((S // qb, qb) + a.shape[1:])
@@ -562,14 +479,6 @@ class LatentMoELM(Layer):
             state, kv=kvs, ik=iks,
             counters=self._bump(state["counters"], counts))
 
-    @staticmethod
-    def _bump(counters, counts):
-        """Add a call's ``counts`` (each < 2 ** 30) to the (n, 2) int32
-        ``(hi, lo)`` counters, which so hold 61 bits without int64."""
-        lo = counters[:, 1] + counts
-        return jnp.stack([counters[:, 0] + (lo >> _LIMB),
-                          lo & ((1 << _LIMB) - 1)], axis=1)
-
     # -- the paged contract ---------------------------------------------------
     def init_paged_pools(self, n_blocks: int, block_len: int,
                          max_active: int, kv_quant: str = "off"):
@@ -592,9 +501,7 @@ class LatentMoELM(Layer):
         """``COUNTERS`` as Python ints, read from the state on the host (one
         small transfer; the caller owns the state, which must not be in a
         call's hands)."""
-        c = np.asarray(state["counters"]).astype(np.int64)
-        return {name: int((c[i, 0] << _LIMB) + c[i, 1])
-                for i, name in enumerate(COUNTERS)}
+        return common.read_counters(state["counters"], COUNTERS)
 
     def _prefill(self, params, state, prompt, lengths, dest, block_len,
                  prefix_len=None, ptab=None):

@@ -1,0 +1,172 @@
+"""What the served decoder classes share (PR 35): the pieces of
+``models/latent_moe_lm.LatentMoELM`` that ``models/sparse_linear_lm.
+SparseLinearLM`` would otherwise copy, as plain functions of their arrays.
+
+- ``rms`` / ``rotary`` / ``rotary_half`` / ``mm`` / ``ein`` / ``swiglu`` /
+  ``ids``: the block's arithmetic (RMS norm, rotary over interleaved or
+  half-split pairs, matmuls in the weights' type that accumulate in float32,
+  the gated feed-forward, token ids out of a batch);
+- ``topk_mask``: the exact k-th largest of every row as a mask, no sort;
+- ``attend_chunks``: softmax attention a chunk of keys at a time under a
+  running maximum;
+- ``bump`` / ``read_counters`` / ``no_counts``: the device-side counters a
+  class publishes through the paged contract's ``paged_counters`` (an int32
+  ``(n, 2)`` leaf of the state, 61 bits a counter without int64);
+- ``scope``: the ``zoo.lm.<stage>`` name a stage's operations carry in the
+  compiled HLO.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+NEG_INF = -1e30
+LIMB = 30               # a counter is (hi, lo) int32 with lo < 2 ** LIMB
+
+
+def scope(name):
+    return jax.named_scope("zoo.lm." + name)
+
+
+def mm(x, W, out=jnp.float32):
+    return jnp.matmul(x.astype(W.dtype), W, preferred_element_type=out)
+
+
+def ein(spec, a, b, dtype):
+    return jnp.einsum(spec, a.astype(dtype), b.astype(dtype),
+                      preferred_element_type=jnp.float32)
+
+
+def rms(g, x, eps):
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
+                             + eps) * g
+
+
+def rotary(x, pos, theta):
+    """Interleaved rotary on the LAST axis of ``x`` (T, ..., d): the pair
+    (2i, 2i+1) turns by ``pos * theta ** (-2i / d)``."""
+    d = x.shape[-1]
+    ang = pos.astype(jnp.float32)[:, None] \
+        * theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = ang.reshape((x.shape[0],) + (1,) * (x.ndim - 2) + (d // 2,))
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    a, b = x[..., 0::2], x[..., 1::2]
+    return jnp.stack([a * cos - b * sin, a * sin + b * cos],
+                     axis=-1).reshape(x.shape)
+
+
+def rotary_half(x, pos, theta):
+    """Half-split rotary on the LAST axis of ``x`` (T, ..., d): the pair
+    (i, i + d / 2) turns by ``pos * theta ** (-2i / d)`` (the ``rotate_half``
+    form: two contiguous halves of the lanes, no stride-2 shuffle)."""
+    d = x.shape[-1]
+    ang = pos.astype(jnp.float32)[:, None] \
+        * theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = ang.reshape((x.shape[0],) + (1,) * (x.ndim - 2) + (d // 2,))
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    a, b = x[..., :d // 2], x[..., d // 2:]
+    return jnp.concatenate([a * cos - b * sin, a * sin + b * cos], axis=-1)
+
+
+def swiglu(h, gate, up, down):
+    return mm(jax.nn.silu(mm(h, gate)) * mm(h, up), down)
+
+
+def ids(x):
+    x = jnp.asarray(x)
+    if x.ndim == 3 and x.shape[-1] == 1:
+        x = x[..., 0]
+    return x.astype(jnp.int32)
+
+
+def topk_mask(score, ok, k: int):
+    """The ``k`` largest of each row of ``score`` (Q, S) among ``ok``,
+    as a mask (all of ``ok`` where it has fewer than ``k``; among equal
+    scores the earlier key first, as ``lax.top_k`` has it): the exact
+    k-th largest found bit by bit over the floats' ordered integer
+    images, 32 counting passes and no sort."""
+    if k >= score.shape[-1]:
+        return ok
+    bits = jax.lax.bitcast_convert_type(score, jnp.int32)
+    # monotone image of the float order in unsigned integers, >= 1
+    u = jax.lax.bitcast_convert_type(
+        bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF)), jnp.uint32) \
+        ^ jnp.uint32(0x80000000)
+    u = jnp.where(ok, jnp.maximum(u, jnp.uint32(1)), jnp.uint32(0))
+
+    def bit(i, thr):
+        cand = thr | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(
+            jnp.uint32)))
+        enough = (u >= cand[:, None]).sum(-1) >= k
+        return jnp.where(enough, cand, thr)
+
+    thr = jax.lax.fori_loop(
+        0, 32, bit, jnp.zeros((score.shape[0],), jnp.uint32))[:, None]
+    above = u > thr
+    tie = ok & (u == thr)
+    room = k - above.sum(-1, keepdims=True)
+    return above | (tie & (jnp.cumsum(tie, axis=-1) <= room))
+
+
+def attend_chunks(q, k, v, allowed, key_pos, last, scale, dtype,
+                  key_chunk: int):
+    """Softmax attention of queries ``q`` (Q, heads, d) over keys ``k`` /
+    ``v`` (S, heads, d) under a mask, a chunk of ``key_chunk`` keys at a
+    time with a running maximum, so that no (heads, Q, S) array exists at
+    once (the TPU compiler serves a softmax over 8,192 keys at a twentieth
+    of the speed of two over 4,096: PERF.md, PR 32).  ``allowed(lo, hi)``
+    gives the mask of keys ``lo .. hi`` (anything that broadcasts to (heads,
+    Q, hi - lo)) where it is used, so that a mask made of smaller parts
+    never exists whole.  A chunk whose keys all lie after the queries' last
+    position ``last`` is skipped.  Returns (Q, heads * d_v)."""
+    n = k.shape[0]
+    parts = []
+    for lo in range(0, n, key_chunk):
+        hi = min(lo + key_chunk, n)
+
+        def chunk(lo=lo, hi=hi):
+            att = ein("qhd,shd->hqs", q, k[lo:hi], dtype) * scale
+            att = jnp.where(allowed(lo, hi), att, NEG_INF)
+            m = att.max(-1)
+            e = jnp.exp(att - m[..., None]).astype(dtype)
+            return (m, e.sum(-1, dtype=jnp.float32),
+                    ein("hqs,shd->hqd", e, v[lo:hi], dtype))
+
+        def skip():
+            hq = (q.shape[1], q.shape[0])
+            return (jnp.full(hq, NEG_INF, jnp.float32),
+                    jnp.zeros(hq, jnp.float32),
+                    jnp.zeros(hq + (v.shape[-1],), jnp.float32))
+
+        parts.append(chunk() if lo == 0 else jax.lax.cond(
+            jnp.min(key_pos[lo:hi]) <= last, chunk, skip))
+    top = functools.reduce(jnp.maximum, [m for m, _, _ in parts])
+    den = sum(jnp.exp(m - top) * s for m, s, _ in parts)
+    out = sum(jnp.exp(m - top)[..., None] * o for m, _, o in parts)
+    return (out / den[..., None]).transpose(1, 0, 2).reshape(
+        q.shape[0], -1)
+
+
+def no_counts(names):
+    return jnp.zeros((len(names),), jnp.int32)
+
+
+def bump(counters, counts):
+    """Add a call's ``counts`` (each < 2 ** 30) to the (n, 2) int32
+    ``(hi, lo)`` counters, which so hold 61 bits without int64."""
+    lo = counters[:, 1] + counts
+    return jnp.stack([counters[:, 0] + (lo >> LIMB),
+                      lo & ((1 << LIMB) - 1)], axis=1)
+
+
+def read_counters(counters, names) -> dict:
+    """The counters leaf as Python ints by name, read on the host (one
+    small transfer; the caller owns the state, which must not be in a
+    call's hands)."""
+    c = np.asarray(counters).astype(np.int64)
+    return {name: int((c[i, 0] << LIMB) + c[i, 1])
+            for i, name in enumerate(names)}

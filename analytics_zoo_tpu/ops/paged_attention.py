@@ -412,7 +412,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, lengths,
 # prefill batch's K/V into blocks), ``pool_gather`` (prefix blocks back out
 # as float32 K/V), ``pool_cursor`` + ``pool_append_attend`` (one decode token
 # a row: append, then attend), ``pool_bytes`` (each leaf's accounting class).
-# A second format, one latent row a token, follows at the end of the file.
+# A second format, one latent row a token, and a third, grouped-query K/V with
+# compressed keys and a per-slot recurrent state, follow at the end of the file.
 #
 # A pool is a dict of per-layer lists.  ``k`` / ``v``: (n_blocks, block_len,
 # heads * head_dim) blocks, float32 or int8; block 0 is the TRASH block
@@ -426,7 +427,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, lengths,
 _LEAF_CLASS = {"k": "paged_pool", "v": "paged_pool",
                "ks": "scales", "vs": "scales",
                "stk": "lanes", "stv": "lanes",
-               "kv": "paged_pool", "ik": "paged_pool"}   # the latent format
+               "kv": "paged_pool", "ik": "paged_pool",   # the latent format
+               "ck": "paged_pool", "lin": "lanes"}       # the grouped format
 
 
 def init_pools(n_layers: int, n_blocks: int, block_len: int, n_head: int,
@@ -453,6 +455,8 @@ def pool_bytes(pools) -> Dict[str, int]:
     ``lanes`` (per-slot staging buffers)."""
     out = {"paged_pool": 0, "scales": 0, "lanes": 0}
     for name, leaves in pools.items():
+        if not isinstance(leaves, (list, tuple)):
+            leaves = [leaves]           # one array for every layer
         out[_LEAF_CLASS[name]] += sum(
             int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
             for leaf in leaves)
@@ -664,3 +668,157 @@ def latent_select(pool, block_tables, sel, block_len: int):
     blk = jnp.where(hit, block_tables[:, :, None], 0).sum(axis=1)
     flat = blk * block_len + sel % block_len
     return jnp.take(pool.reshape(-1, pool.shape[-1]), flat, axis=0)
+
+
+# -- the GROUPED-QUERY pool format (PR 35) -------------------------------------
+#
+# A decoder whose attention layers share each key/value head among a GROUP of
+# query heads and keep, for a long context, only the best BLOCKS of it (chosen
+# per key head from compressed keys), beside layers that hold no keys at all
+# but a fixed-size recurrent state.  Four leaves:
+#
+# - ``k`` / ``v``: a layer a (n_blocks, kv_heads, block_len, head_dim) pool.
+#   The key head comes BEFORE the positions: the key heads keep different
+#   blocks, so what a decode step gathers is one key head's (block_len,
+#   head_dim) tile of one block, contiguous and whole lane tiles wide (64 x
+#   128 bfloat16 = 16 KB), never a strided half of a wider row;
+# - ``ck``: a layer a (n_blocks, windows * kv_heads * head_dim) pool of the
+#   compressed keys: window j of a sequence (tokens ``j * stride ...``) lies
+#   in the block its first token lies in, at slot ``j % windows`` of that
+#   block's row, ``[slot 0 | slot 1 | ...]``, each slot (kv_heads, head_dim)
+#   flat.  One row a block, so that scoring a context gathers whole rows of 2
+#   KB through the table and a 4-row tile is never padded to a sublane tile;
+# - ``lin``: ONE (linear layers, max_active, heads, dim, dim) float32 array,
+#   the recurrent state of every slot (no block table: a slot's state is its
+#   own); stacked by layer, so that a run of like layers scans over it.
+#
+# Block 0 is the trash block.  ``init_grouped_pools`` (zeroed),
+# ``grouped_commit`` (one prefilled sequence into its blocks),
+# ``grouped_append`` (one decode token a row), ``grouped_rows`` (single
+# positions back out: the window a decode step completes), ``compressed_put``
+# (that window's key into its slot), ``compressed_gather`` (a table's
+# compressed keys, in order), ``grouped_state_put`` (a prefilled sequence's
+# recurrent states into its slot), ``grouped_blocks`` (whole kept blocks of each key
+# head); ``pool_cursor`` and ``pool_bytes`` serve this format too.
+
+def init_grouped_pools(n_layers: int, n_blocks: int, block_len: int,
+                       kv_heads: int, head_dim: int, windows: int,
+                       n_linear: int, max_active: int, lin_heads: int,
+                       lin_dim: int, dtype):
+    """Zeroed host-side state pytree (``n_blocks`` counts the trash block):
+    ``n_layers`` attention layers' pools and ``n_linear`` layers' states."""
+    kv = (n_blocks, kv_heads, block_len, head_dim)
+    return {"k": [np.zeros(kv, dtype) for _ in range(n_layers)],
+            "v": [np.zeros(kv, dtype) for _ in range(n_layers)],
+            "ck": [np.zeros((n_blocks, windows * kv_heads * head_dim), dtype)
+                   for _ in range(n_layers)],
+            "lin": np.zeros((n_linear, max_active, lin_heads, lin_dim,
+                             lin_dim), np.float32)}
+
+
+def grouped_commit(pools, ks, vs, cks, dest, *, block_len: int):
+    """ONE prefilled sequence into the pools: ``ks`` / ``vs`` per-layer (P,
+    kv_heads, head_dim) rows, ``cks`` per-layer (P // stride, kv_heads,
+    head_dim) compressed keys (window j at row j); block t lands at pool id
+    ``dest[t]`` (0 = trash).  Returns the new ``k``, ``v``, ``ck`` lists."""
+    bl, npb = int(block_len), dest.shape[0]
+
+    def blocks(rows, per_block):
+        pad = npb * per_block - rows.shape[0]
+        if pad:
+            rows = jnp.concatenate(
+                [rows, jnp.zeros((pad,) + rows.shape[1:], rows.dtype)])
+        return rows.reshape((npb, per_block) + rows.shape[1:])
+
+    def kv(p, rows):
+        return p.at[dest].set(
+            blocks(rows, bl).transpose(0, 2, 1, 3).astype(p.dtype))
+
+    def ck(p, rows):
+        per = p.shape[1] // (rows.shape[1] * rows.shape[2])
+        return p.at[dest].set(
+            blocks(rows, per).reshape(npb, -1).astype(p.dtype))
+
+    return ([kv(p, r) for p, r in zip(pools["k"], ks)],
+            [kv(p, r) for p, r in zip(pools["v"], vs)],
+            [ck(p, r) for p, r in zip(pools["ck"], cks)])
+
+
+def _grouped_row_ids(blk, off, G: int, bl: int):
+    """Flat row ids ``((blk * G + g) * bl + off)`` of one position's rows in
+    a pool seen as (n_blocks * G * bl, head_dim): (..., G)."""
+    return (blk[..., None] * G + jnp.arange(G, dtype=blk.dtype)) * bl \
+        + off[..., None]
+
+
+def grouped_append(pools, li: int, k_rows, v_rows, cursor):
+    """Layer ``li`` of one decode step: each row's ``k`` / ``v`` (rows,
+    kv_heads, head_dim) into the block under its ``cursor``
+    (``pool_cursor``).  Returns the layer's new ``(k, v)`` leaves.  Every
+    access of this format sees the pool as rows or whole tiles of ONE
+    row-major buffer, so that the compiler keeps one layout for it."""
+    _, cur, off = cursor
+    k, v = pools["k"][li], pools["v"][li]
+    _, G, bl, d = k.shape
+    ids = _grouped_row_ids(cur, off, G, bl).reshape(-1)
+
+    def put(pool, rows):
+        return pool.reshape(-1, d).at[ids].set(
+            rows.reshape(-1, d).astype(pool.dtype)).reshape(pool.shape)
+
+    return put(k, k_rows), put(v, v_rows)
+
+
+def grouped_rows(pool, block_tables, positions, block_len: int):
+    """The rows of the cache positions ``positions`` (rows, n) of each row,
+    through its block table: (rows, n, kv_heads, head_dim)."""
+    _, G, bl, d = pool.shape
+    entry = jnp.clip(positions // block_len, 0, block_tables.shape[1] - 1)
+    blk = jnp.take_along_axis(block_tables, entry, axis=1)
+    return jnp.take(pool.reshape(-1, d), _grouped_row_ids(
+        blk, positions % block_len, G, bl), axis=0)
+
+
+def compressed_put(ck_pool, block_tables, window, rows, on, windows: int):
+    """Window ``window`` (rows,) of each row gets the compressed key
+    ``rows`` (rows, kv_heads, head_dim) where ``on`` (rows,); the others
+    write to the trash block.  A block's row is read, one slot of it
+    replaced, and written back whole."""
+    A = rows.shape[0]
+    flat = rows.reshape(A, -1)
+    entry = jnp.clip(window // windows, 0, block_tables.shape[1] - 1)
+    blk = jnp.where(on, jnp.take_along_axis(
+        block_tables, entry[:, None], axis=1)[:, 0], 0)
+    slot = jnp.arange(ck_pool.shape[1]) // flat.shape[1]
+    new = jnp.where(slot[None, :] == (window % windows)[:, None],
+                    jnp.tile(flat, (1, windows)).astype(ck_pool.dtype),
+                    ck_pool[blk])
+    return ck_pool.at[blk].set(new)
+
+
+def grouped_state_put(lin, states, slot):
+    """One prefilled sequence's recurrent states ``states`` (layers, heads,
+    dim, dim) into slot ``slot`` of ``lin``: a slice a layer, in place (a
+    scatter along the slot axis makes the compiler re-lay the whole array
+    out, there and back)."""
+    for li in range(lin.shape[0]):
+        lin = jax.lax.dynamic_update_slice(
+            lin, states[li][None, None].astype(lin.dtype),
+            (li, slot, 0, 0, 0))
+    return lin
+
+
+def compressed_gather(ck_pool, block_tables, kv_heads: int, head_dim: int):
+    """Every compressed key under ``block_tables`` (rows, n), in window
+    order: (rows, n * windows, kv_heads, head_dim)."""
+    got = jnp.take(ck_pool, block_tables, axis=0)
+    return got.reshape(got.shape[0], -1, kv_heads, head_dim)
+
+
+def grouped_blocks(pool, blocks):
+    """Whole blocks of each key head: ``blocks`` (rows, kv_heads, n) pool
+    ids, head g's from head g's part of the pool: (rows, kv_heads, n,
+    block_len, head_dim), one gather of contiguous tiles."""
+    n_blocks, G, bl, d = pool.shape
+    flat = blocks * G + jnp.arange(G, dtype=blocks.dtype)[None, :, None]
+    return jnp.take(pool.reshape(n_blocks * G, bl, d), flat, axis=0)

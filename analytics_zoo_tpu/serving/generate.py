@@ -435,6 +435,12 @@ class ContinuousBatcher:
                 else gen.max_active_slots * lane.ntab
             self._pool = BlockPool(n_pool, gen.block_len)
             if gen.prefix_cache:
+                if not getattr(inner, "paged_prefix_sharing", True):
+                    raise ValueError(
+                        f"generation.prefix_cache=true, but "
+                        f"{type(inner).__name__} cannot prefill behind a "
+                        f"shared prefix (it keeps per-slot state that no "
+                        f"resident block holds); set prefix_cache: false")
                 self._prefix = PrefixIndex(self._pool)
         else:
             self._lanes = [_Lane(b, gen.max_active_slots) for b in usable]
