@@ -14,8 +14,10 @@ routed expert layer that holds its chip's share of the experts (PR 32).
   into the query and the output, so the cache holds ``c_kv`` and ``k_r`` only;
 - **DSA**: an indexer (``index_n_heads`` x ``index_head_dim``) scores every
   earlier token and attention runs over the ``index_topk`` best only.  Prefill
-  masks the dense scores (an exact k-th-largest threshold a query); decode
-  gathers the selected rows through the block table;
+  masks the dense scores (an exact k-th-largest threshold a query), a block of
+  ``_QUERY_BLOCK`` queries at a time, and does not run a block that lies past
+  the row's length (PR 36): a call's time follows the document, not the
+  bucket; decode gathers the selected rows through the block table;
 - **routed FFN**: sigmoid scores over ALL ``n_routed_experts``, the
   ``num_experts_per_tok`` largest of score + selection bias, normalised and
   scaled; this process computes the pairs that land on the experts it HOLDS
@@ -31,7 +33,10 @@ built in ``dtype`` (bfloat16 as served; the router and the norms float32), so
 ``matmul_operands`` is the tree itself.
 
 Counters (the contract's optional ``paged_counters``): the programs add to a
-small int32 leaf of the state; see ``COUNTERS``.
+small int32 leaf of the state; see ``COUNTERS``.  Its last two say how often
+the prefill's skip engages: the query blocks a prefill program was asked for
+(bucket / ``_QUERY_BLOCK`` a row a layer) and those that held a real query
+and so ran.
 
 Not built: the multi-token-prediction layer (the base model's logits do not
 depend on it), the indexer's FP8 / Hadamard rotation (quantisation aids).
@@ -59,6 +64,8 @@ _PAIR_SLAB = 2048       # token-expert pairs one grouped matmul takes
 # (no padding, no idle slot), except the two marked (decode): a prefill call
 # touches every expert, so they describe the decode step alone.  ``dsa_*``
 # over decode rows only (a prefill's selection is a mask, not a gather).
+# ``prefill_query_blocks*`` over every row a prefill program is given, a
+# batch's padding row included (it runs the blocks of the length it is given).
 COUNTERS = (
     "moe_pairs",            # token-expert pairs routed, over all experts
     "moe_pairs_held",       # ... that landed on an expert held here
@@ -67,6 +74,8 @@ COUNTERS = (
     "moe_layer_steps",      # (decode) expert-layer calls
     "dsa_keys_selected",    # keys attention read, summed a row a layer
     "dsa_keys_context",     # keys in context there (selected <= context)
+    "prefill_query_blocks",       # blocks of a row's bucket, summed a layer
+    "prefill_query_blocks_live",  # ... that held a real query, and so ran
 )
 
 
@@ -271,8 +280,8 @@ class LatentMoELM(Layer):
         step = jnp.int32(1 if decode else 0)
         counts = jnp.stack([
             valid.sum().astype(jnp.int32) * k, total, sizes.max(),
-            step * (sizes > 0).sum().astype(jnp.int32), step,
-            jnp.int32(0), jnp.int32(0)])
+            step * (sizes > 0).sum().astype(jnp.int32), step]
+            + [jnp.int32(0)] * (len(COUNTERS) - 5))      # the other stages'
         return y, counts
 
     def _blocks(self, params, x, pos, valid, attend, decode: bool = False):
@@ -347,8 +356,15 @@ class LatentMoELM(Layer):
         = per-layer ``(kv rows, ik rows)`` of ``base`` earlier tokens
         (padded to PL rows) that join attention as keys; the first
         ``counted`` tokens (default ``length``) enter the counters and the
-        expert layer's groups.  Returns ``(h (S, H), kvs, iks, counts)``:
-        the final hidden states and this sequence's per-layer cache rows."""
+        expert layer's groups.  A block of ``_QUERY_BLOCK`` queries whose
+        first position is not below ``base + length`` is not attended (no
+        indexer scores, no selection, no softmax): its output rows are
+        zeros, which nothing reads (padding keys are masked, padding tokens
+        are no expert's, the head reads position ``length - 1``).  Returns
+        ``(h (S, H), kvs, iks, counts)``: the final hidden states (a real
+        position's only), this sequence's per-layer cache rows, and the
+        counters' increments: the expert layer's, and a layer's
+        ``S // _QUERY_BLOCK`` blocks asked for beside those that ran."""
         S = ids.shape[0]
         qb = min(_QUERY_BLOCK, S)
         if S % qb:
@@ -380,8 +396,7 @@ class LatentMoELM(Layer):
                                                     self.rope))], axis=-1)
                 v = kvb[..., self.nope:]
 
-            def block(args):
-                qq, qi, wi, t = args
+            def attend_block(qq, qi, wi, t):
                 with _scope("dsa_index"):
                     score = self._index_scores(qi, keys_ik, wi)
                 with _scope("dsa_select"):
@@ -392,13 +407,24 @@ class LatentMoELM(Layer):
                         qq, k, v, lambda lo, hi: allowed[None, :, lo:hi],
                         key_pos, t[-1], scale)
 
+            def block(args):
+                return jax.lax.cond(
+                    args[0], attend_block,
+                    lambda *_: jnp.zeros((qb, nh * self.v_dim), jnp.float32),
+                    *args[1:])
+
             def blocked(a):
                 return a.reshape((S // qb, qb) + a.shape[1:])
 
-            o = jax.lax.map(block, (blocked(q), blocked(q_i), blocked(w_i),
-                                    blocked(pos)))
-            return (o.reshape(S, nh * self.v_dim), (kv_rows, ik_rows),
-                    _no_counts())
+            # a block past the row's length holds no real query: not run
+            live = blocked(pos)[:, 0] < base + length
+            o = jax.lax.map(block, (live, blocked(q), blocked(q_i),
+                                    blocked(w_i), blocked(pos)))
+            counts = _no_counts() \
+                .at[COUNTERS.index("prefill_query_blocks")].set(S // qb) \
+                .at[COUNTERS.index("prefill_query_blocks_live")].set(
+                    live.sum().astype(jnp.int32))
+            return o.reshape(S, nh * self.v_dim), (kv_rows, ik_rows), counts
 
         x = jnp.take(params["embed"], ids, axis=0).astype(jnp.float32)
         h, keeps, counts = self._blocks(

@@ -193,7 +193,7 @@ def test_the_state_is_donated_and_sorted_into_the_ledger(served):
     n_blocks = b._pool.n_blocks + 1
     doc = b.state_bytes_doc()
     assert doc["paged_pool"] == 3 * n_blocks * 4 * (128 + 8) * 4
-    assert doc["lanes"] == 7 * 2 * 4 + 4 * (64 * 97 + 1) * 4
+    assert doc["lanes"] == 9 * 2 * 4 + 4 * (64 * 97 + 1) * 4
     assert doc["scales"] == 0
 
 
@@ -657,6 +657,286 @@ def test_decode_is_the_lookup_oracle_bit_for_bit(path):
         # idle slot's none
         assert lm.paged_counters(state)["dsa_keys_selected"] \
             == lm.n_layers * (4 * 6 + (4 + 5 + 6 + 6))
+
+
+# -- (c3) prefill runs only the query blocks a row has (PR 36) ------------------
+
+def _unskipped_oracle():
+    """``LatentMoELM`` with the query-block loop as it was before PR 36:
+    EVERY block of the bucket is attended, the rest of ``_forward_row`` line
+    for line (what the package no longer does).  ``_lm()``'s weights serve
+    it."""
+    import jax
+    import jax.numpy as jnp
+    from analytics_zoo_tpu.models import latent_moe_lm as mod
+    from analytics_zoo_tpu.ops import paged_attention as paged
+
+    class Oracle(mod.LatentMoELM):
+        def _forward_row(self, params, ids, length, base=0, prefix=None,
+                         counted=None):
+            S = ids.shape[0]
+            qb = min(mod._QUERY_BLOCK, S)
+            nh, dt = self.n_head, self.dtype
+            pos = base + jnp.arange(S)
+            PL = 0 if prefix is None else prefix[0][0].shape[0]
+            key_pos = jnp.concatenate([jnp.arange(PL), pos])
+            key_ok = jnp.concatenate([jnp.arange(PL) < base,
+                                      jnp.arange(S) < length])
+            scale = self._softmax_scale()
+
+            def attend(li, blk, q, c_kv, k_r, q_i, k_i, w_i):
+                kv_rows = paged.latent_rows(c_kv, k_r, self.kv_width, dt)
+                ik_rows = k_i.astype(dt)
+                keys_kv, keys_ik = kv_rows, ik_rows
+                if prefix is not None:
+                    keys_kv = jnp.concatenate([prefix[0][li], kv_rows])
+                    keys_ik = jnp.concatenate([prefix[1][li], ik_rows])
+                c = keys_kv[:, :self.kv_rank]
+                kr = keys_kv[:, self.kv_rank:self.kv_rank + self.rope]
+                with mod._scope("mla"):
+                    kvb = self._mm(c, blk["kv_b"], out=dt).reshape(
+                        c.shape[0], nh, self.nope + self.v_dim)
+                    k = jnp.concatenate(
+                        [kvb[..., :self.nope],
+                         jnp.broadcast_to(kr[:, None], (c.shape[0], nh,
+                                                        self.rope))],
+                        axis=-1)
+                    v = kvb[..., self.nope:]
+
+                def block(args):
+                    qq, qi, wi, t = args
+                    with mod._scope("dsa_index"):
+                        score = self._index_scores(qi, keys_ik, wi)
+                    with mod._scope("dsa_select"):
+                        ok = (key_pos[None, :] <= t[:, None]) \
+                            & key_ok[None, :]
+                        allowed = self._topk_mask(score, ok, self.index_topk)
+                    with mod._scope("mla"):
+                        return self._attend_chunks(
+                            qq, k, v, lambda lo, hi: allowed[None, :, lo:hi],
+                            key_pos, t[-1], scale)
+
+                def blocked(a):
+                    return a.reshape((S // qb, qb) + a.shape[1:])
+
+                o = jax.lax.map(block, (blocked(q), blocked(q_i),
+                                        blocked(w_i), blocked(pos)))
+                return (o.reshape(S, nh * self.v_dim), (kv_rows, ik_rows),
+                        mod._no_counts())
+
+            x = jnp.take(params["embed"], ids, axis=0).astype(jnp.float32)
+            h, keeps, counts = self._blocks(
+                params, x, pos,
+                jnp.arange(S) < (length if counted is None else counted),
+                attend)
+            kvs, iks = map(list, zip(*keeps))
+            return h, kvs, iks, counts
+
+    return _lm(Oracle)[0]
+
+
+_BLOCKS = ("prefill_query_blocks", "prefill_query_blocks_live")
+
+
+def _small_blocks(monkeypatch, key_chunk=8):
+    """Query blocks of 4 and key chunks of 8, so that a toy bucket of 32
+    holds 8 blocks over 4 key chunks (the served 8,192 bucket: 32 over 2)."""
+    from analytics_zoo_tpu.models import latent_moe_lm
+    monkeypatch.setattr(latent_moe_lm, "_QUERY_BLOCK", 4)
+    monkeypatch.setattr(latent_moe_lm, "_KEY_CHUNK", key_chunk)
+
+
+def _shared_draw(lm, params):
+    """A pool that holds the 8-token prefix of a document in blocks 1-2, and
+    the arguments of a suffix-only prefill over it: two rows in a bucket of
+    32, 13 and 6 real tokens at positions 8.."""
+    doc = np.random.default_rng(36).integers(1, 97, (1, 32)).astype(np.int32)
+    filled, _, _ = _prefilled(lm, params, doc[:, :8], np.array([8]),
+                              ntab=20)
+    suffix = np.zeros((2, 32), np.int32)
+    suffix[0, :13], suffix[1, :6] = doc[0, 8:21], doc[0, 8:14]
+    lens, base = np.array([13, 6], np.int32), np.array([8, 8], np.int32)
+    ptab = np.array([[1, 2], [1, 2]], np.int32)
+    dest = np.zeros((2, 8), np.int32)
+    dest[0, :4], dest[1, :2] = [3, 4, 5, 6], [7, 8]
+    return filled, (suffix, lens, base, ptab, dest, np.arange(2))
+
+
+@pytest.mark.parametrize("path", ["paged", "shared"])
+def test_a_skipped_block_changes_no_real_position_bit_for_bit(monkeypatch,
+                                                              path):
+    """Rows of 13, 6 and 18 tokens in a bucket of 32 (8 query blocks, 3-6 of
+    them past the row): at every REAL position the final hidden states, the
+    ``kv`` / ``ik`` rows the pool received and the last position's logits
+    are the same BITS as with every block attended, through ``prefill_paged``
+    and through ``prefill_shared_paged`` at ``base`` 8: a real query's block
+    runs the code it ran, and no real position reads a padding query's
+    output."""
+    import jax
+    from analytics_zoo_tpu.ops import paged_attention as paged
+    _small_blocks(monkeypatch)
+    lm, params, _ = _lm()
+    oracle = _unskipped_oracle()
+    if path == "paged":
+        prompt = np.random.default_rng(36).integers(1, 97, (3, 32)) \
+            .astype(np.int32)
+        lens = np.array([13, 6, 18], np.int32)
+        base = np.zeros((3,), np.int32)
+        dest = np.where(np.arange(8)[None] * 4 < lens[:, None],
+                        1 + np.arange(24).reshape(3, 8), 0)
+        state = jax.device_put(lm.init_paged_pools(1 + 24, 4, 3))
+        args = (prompt, lens, dest, np.arange(3))
+        prefixes = [None] * 3
+
+        def program(model):
+            return functools.partial(model.prefill_paged, block_len=4)
+    else:
+        state, args = _shared_draw(lm, params)
+        prompt, lens, base, ptab, dest = args[:5]
+        prefixes = [tuple([paged.latent_gather(p, ptab[r:r + 1])[0]
+                           for p in state[leaf]] for leaf in ("kv", "ik"))
+                    for r in range(2)]
+
+        def program(model):
+            return functools.partial(model.prefill_shared_paged, block_len=4)
+
+    got, logits = jax.jit(program(lm))(params, state, *args)
+    want, ologits = jax.jit(program(oracle))(params, state, *args)
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(ologits))
+    for r, n in enumerate(lens):
+        for leaf in ("kv", "ik"):
+            for a, b in zip(got[leaf], want[leaf]):
+                rows = [np.asarray(x)[dest[r, :-(-n // 4)]].reshape(
+                    -1, x.shape[-1])[:n] for x in (a, b)]
+                assert rows[0].shape[0] == n            # each one written
+                assert np.abs(rows[0]).max(-1).min() > 0
+                np.testing.assert_array_equal(*rows)
+        hs = [np.asarray(jax.jit(
+            lambda p, ids, m=m, r=r: m._forward_row(
+                p, ids, lens[r], base[r], prefixes[r])[0])(
+                    params, prompt[r]))[:n] for m in (lm, oracle)]
+        np.testing.assert_array_equal(*hs)
+    # the skip is taken: past the last live block the two DO differ
+    assert np.abs(hs[0]).max() > 0
+    after = [np.asarray(jax.jit(
+        lambda p, ids, m=m: m._forward_row(p, ids, 6, base[1],
+                                           prefixes[1])[0])(
+            params, prompt[1]))[8:] for m in (lm, oracle)]
+    assert np.abs(after[0] - after[1]).max() > 1e-3
+
+
+@pytest.mark.parametrize("case", ["rows", "padding_row", "shared", "call"])
+def test_the_query_block_counters_count_blocks_asked_for_and_run(monkeypatch,
+                                                                 case):
+    """A row of ``n`` tokens in a bucket of ``S`` adds ``layers x S / 4`` to
+    ``prefill_query_blocks`` and ``layers x ceil(n / 4)`` to
+    ``prefill_query_blocks_live`` (through both prefill programs); a batch's
+    padding row (length 1, every block to the trash) adds ONE live block a
+    layer; teacher forcing (``call``: ``length = S``) runs every block."""
+    import jax
+    from analytics_zoo_tpu.models.latent_moe_lm import COUNTERS
+    _small_blocks(monkeypatch)
+    lm, params, _ = _lm()
+    L = lm.n_layers
+    if case == "call":
+        ids = np.random.default_rng(37).integers(1, 97, 32).astype(np.int32)
+        counts = np.asarray(jax.jit(
+            lambda p, x: lm._forward_row(p, x, x.shape[0])[3])(params, ids))
+        got = dict(zip(COUNTERS, counts))
+        assert (got[_BLOCKS[0]], got[_BLOCKS[1]]) == (L * 8, L * 8)
+        return
+    if case == "shared":
+        state, args = _shared_draw(lm, params)
+        lens = args[1]
+        before = lm.paged_counters(state)
+        state, _ = jax.jit(functools.partial(
+            lm.prefill_shared_paged, block_len=4))(params, state, *args)
+    else:
+        lens = np.array([13, 32, 1] if case == "rows" else [18, 1], np.int32)
+        prompt = np.random.default_rng(37).integers(
+            1, 97, (len(lens), 32)).astype(np.int32)
+        dest = np.where(np.arange(8)[None] * 4 < lens[:, None],
+                        1 + np.arange(8 * len(lens)).reshape(-1, 8), 0)
+        if case == "padding_row":
+            dest[1] = 0
+        state = jax.device_put(lm.init_paged_pools(1 + dest.size, 4,
+                                                   len(lens)))
+        before = lm.paged_counters(state)
+        state, _ = jax.jit(functools.partial(lm.prefill_paged, block_len=4))(
+            params, state, prompt, lens, dest, np.arange(len(lens)))
+    after = lm.paged_counters(state)
+    assert after[_BLOCKS[0]] - before[_BLOCKS[0]] == L * 8 * len(lens)
+    assert after[_BLOCKS[1]] - before[_BLOCKS[1]] \
+        == L * sum(-(-int(n) // 4) for n in lens)
+    if case == "padding_row":
+        # ... and it stays out of the expert layer's counts, as before
+        assert after["moe_pairs"] == (L - 1) * 18 * 3
+
+
+def test_the_lowered_prefill_keeps_the_skip_a_conditional(monkeypatch):
+    """In the prefill program every layer's query-block loop (a ``scan`` of
+    bucket / 4 steps inside the rows' ``scan``) holds ONE ``cond`` and no
+    matmul beside it; the ``cond``'s live branch holds the block's three
+    stages and the other none; and the StableHLO text carries one ``case`` a
+    layer (one key chunk here, so ``attend_chunks`` adds none).  A later edit
+    that batches the block (a ``vmap`` turns ``cond`` into ``select``: every
+    padding block computed again) fails here, not in a benchmark."""
+    import re
+
+    import jax
+    _small_blocks(monkeypatch, key_chunk=4096)
+    lm, params, _ = _lm()
+    state = jax.device_put(lm.init_paged_pools(1 + 24, 4, 3))
+    fn = functools.partial(lm.prefill_paged, block_len=4)
+    args = (params, state, np.zeros((3, 32), np.int32),
+            np.array([13, 6, 1], np.int32), np.zeros((3, 8), np.int32),
+            np.arange(3))
+    loops = []
+
+    def stages(jaxpr, out):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general":
+                out.append(re.findall(r"zoo\.lm\.(\w+)",
+                                      str(eqn.source_info.name_stack))[-1])
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                stages(sub, out)
+        return out
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "scan" and eqn.params["length"] == 8:
+                loops.append(eqn.params["jaxpr"].jaxpr)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    assert len(loops) == lm.n_layers
+    for body in loops:
+        names = [e.primitive.name for e in body.eqns]
+        assert names.count("cond") == 1 and "dot_general" not in names
+        cond = body.eqns[names.index("cond")]
+        held = sorted(sorted(set(stages(b.jaxpr, [])))
+                      for b in cond.params["branches"])
+        assert held == [[], ["dsa_index", "mla"]]
+        assert "select_n" not in names
+    text = jax.jit(fn).lower(*args).as_text()
+    assert text.count("stablehlo.case") == lm.n_layers
+
+
+def test_a_decode_call_leaves_the_query_block_counters():
+    """Three rows were prefilled (one block each at the toy bucket); two
+    decode steps later the two counters read what they read."""
+    import jax
+    lm, params, state, tables, pos, tok = _decode_rows()
+    before = lm.paged_counters(state)
+    assert before[_BLOCKS[0]] == before[_BLOCKS[1]] == 3 * lm.n_layers
+    step = jax.jit(functools.partial(lm.decode_paged, block_len=4))
+    for _ in range(2):
+        logits, state = step(params, state, tables, pos, tok)
+        tok, pos = np.asarray(logits).argmax(-1).astype(np.int32), pos + 1
+    after = lm.paged_counters(state)
+    assert {n: after[n] for n in _BLOCKS} == {n: before[n] for n in _BLOCKS}
+    assert after["dsa_keys_context"] > before["dsa_keys_context"]
 
 
 # -- (d), (e) the expert layer -------------------------------------------------
