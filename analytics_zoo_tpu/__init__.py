@@ -6,3 +6,7 @@ from analytics_zoo_tpu.common.context import (
 from analytics_zoo_tpu.common import dtypes
 
 __version__ = "0.1.0"
+
+# the start-up timeline (PR 37): the package, and with it jax, is imported
+from analytics_zoo_tpu.common.observability import get_startup as _startup
+_startup().stamp("imported")

@@ -1065,6 +1065,49 @@ class PhaseClock:
         return seconds, counts
 
 
+# -- start-up marks (PR 37) -----------------------------------------------------
+
+class StartupMarks:
+    """Named instants of one start, ``time.monotonic()`` seconds; the first
+    stamp of a name wins, so a mark says when its stage was FIRST reached.
+
+    The process-wide object (``get_startup()``) holds ``imported`` (end of
+    ``analytics_zoo_tpu/__init__.py``) and ``model_loaded``
+    (``InferenceModel.do_load_model``).  A serving engine starts its own
+    from those (``StartupMarks(get_startup().snapshot())``) and stamps
+    ``engine``, ``warm_begin``, ``ready`` and ``first_result`` there, so
+    that several engines of one process each keep their own."""
+
+    ORDER = ("imported", "model_loaded", "engine", "warm_begin", "ready",
+             "first_result")
+
+    def __init__(self, marks: Optional[Dict[str, float]] = None):
+        self._t: Dict[str, float] = dict(marks or {})
+
+    def stamp(self, name: str, t: Optional[float] = None) -> float:
+        """Set ``name`` to ``t`` (default: now) unless it is set; returns
+        the mark's value either way."""
+        if t is None:
+            t = time.monotonic()
+        return self._t.setdefault(name, t)      # atomic: first stamp wins
+
+    def get(self, name: str) -> Optional[float]:
+        return self._t.get(name)
+
+    def snapshot(self) -> Dict[str, float]:
+        """The marks set so far, in ``ORDER`` (others after)."""
+        t = dict(self._t)
+        return {k: t[k] for k in (*self.ORDER, *t) if k in t}
+
+
+_global_startup = StartupMarks()
+
+
+def get_startup() -> StartupMarks:
+    """The process-wide start-up marks (see ``StartupMarks``)."""
+    return _global_startup
+
+
 # -- SLO attribution (PR 13) ---------------------------------------------------
 
 class SloTracker:

@@ -29,6 +29,12 @@ makes cold start a *derived, measured* path:
   not) and persistent-cache hits/misses — with every program cacheable,
   ``cache_misses`` is the true backend-compile count, so "the warm path
   performs zero XLA compiles" is a tested number, not a hope.
+- ``compile_recorded(fn, args, program, cause)`` (PR 37) is ``lower`` +
+  ``compile`` with a record of how it went — the walls of both, jax's own
+  seconds inside them (attributed through ``COMPILE_STATS.making``), the
+  persistent cache's verdict and the executable's bytes: what
+  ``ContinuousBatcher._compiled`` keeps for every scheduler program and
+  ``warmup_state()["programs"]`` shows.
 
 Single-input models only (the serving engine stacks one tensor per record);
 multi-input ``do_predict`` callers still go through the same AOT cache,
@@ -37,6 +43,7 @@ they just warm lazily on first use.
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import os
 import threading
@@ -70,6 +77,18 @@ class WarmupEntry(NamedTuple):
     variant: str = "float"
 
 
+# jax's monitoring keys this module reads (jax 0.9.0), by the field of
+# ``CompileStats`` / of a program's record their seconds are summed into
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_MLIR = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND = "/jax/core/compile/backend_compile_duration"
+_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_MISS = "/jax/compilation_cache/cache_misses"
+_SECONDS = {_TRACE: "trace_s", _MLIR: "mlir_s", _BACKEND: "backend_s",
+            _RETRIEVAL: "retrieval_s"}
+
+
 class CompileStats:
     """Process-wide XLA compile accounting, fed by jax's monitoring
     events.  ``compile_requests`` counts trips into
@@ -80,10 +99,24 @@ class CompileStats:
     retrieval).  ``cache_hits``/``cache_misses`` count persistent-cache
     traffic once a cache dir is configured: with every program cacheable
     (see ``enable_persistent_cache``), **``cache_misses`` IS the true
-    backend-compile count** — the warm path asserts it stays zero."""
+    backend-compile count** — the warm path asserts it stays zero.
+
+    ``making(record)`` (PR 37) attributes the calling thread's events to
+    one program's record (``compile_recorded``): jax fires them
+    synchronously on the thread inside ``lower()`` / ``compile()``, so a
+    thread-local is enough although the warm-up thread and a serving
+    thread may both be compiling.  Beside the backend's seconds a record
+    takes the stages this object keeps no sum of: ``trace_s`` (Python
+    tracing to a jaxpr) and ``mlir_s`` (jaxpr to StableHLO), OUTERMOST
+    events only — jax times every nested ``jit`` it traces (``jnp.matmul``
+    is one) inside its caller's event, so a plain sum would count the same
+    seconds at every depth — and ``retrieval_s`` (a persistent-cache hit's
+    read, deserialisation and load onto the device; inside the backend's
+    seconds)."""
 
     def __init__(self):
         self._lock = threading.Lock()
+        self._local = threading.local()     # .record, .depth
         self.compile_requests = 0
         self.compile_seconds = 0.0
         self.cache_hits = 0
@@ -96,27 +129,75 @@ class CompileStats:
                     "cache_hits": self.cache_hits,
                     "cache_misses": self.cache_misses}
 
+    @contextlib.contextmanager
+    def making(self, record: Dict):
+        """Until exit, this thread's events also count into ``record``
+        (``trace_s``, ``mlir_s``, ``backend_s``, ``retrieval_s``,
+        ``cache``)."""
+        local = self._local
+        outer = getattr(local, "record", None)
+        local.record, local.depth = record, 0
+        try:
+            yield record
+        finally:
+            local.record = outer
+
+    def _scalar(self, key: str, value, **kw) -> None:
+        # jax announces a timed stage's START as a scalar under the
+        # stage's own key: the depth of trace / lowering on this thread
+        # while it makes a program
+        if key == _TRACE or key == _MLIR:
+            local = self._local
+            if getattr(local, "record", None) is not None:
+                local.depth += 1
+
     def _event(self, key: str, **kw) -> None:
-        if key == "/jax/compilation_cache/cache_hits":
-            with self._lock:
+        if key == _CACHE_HIT:
+            verdict = "hit"
+        elif key == _CACHE_MISS:
+            verdict = "miss"
+        else:
+            return
+        with self._lock:
+            if verdict == "hit":
                 self.cache_hits += 1
-        elif key == "/jax/compilation_cache/cache_misses":
-            with self._lock:
+            else:
                 self.cache_misses += 1
+        record = getattr(self._local, "record", None)
+        if record is not None and record["cache"] != "miss":
+            record["cache"] = verdict
 
     def _duration(self, key: str, dur: float, **kw) -> None:
-        if key == "/jax/core/compile/backend_compile_duration":
+        field = _SECONDS.get(key)
+        if field is None:
+            return
+        dur = float(dur)
+        local = self._local
+        record = getattr(local, "record", None)
+        if record is not None:
+            if key == _TRACE or key == _MLIR:
+                local.depth = depth = max(local.depth - 1, 0)
+                if not depth:       # else: inside the outer event's seconds
+                    record[field] += dur
+            else:
+                record[field] += dur
+        if key == _BACKEND:
             with self._lock:
                 self.compile_requests += 1
-                self.compile_seconds += float(dur)
+                self.compile_seconds += dur
             # incident flight recorder (PR 15): compile requests are
             # first-class forensic events — "the replica was compiling"
-            # explains a stall better than any latency histogram
+            # explains a stall better than any latency histogram; since
+            # PR 37 with WHICH program, for whom, and whether the
+            # persistent cache had it
+            made = record or {}
             try:
                 from analytics_zoo_tpu.common.observability import (
                     get_recorder)
-                get_recorder().record("compile",
-                                      seconds=round(float(dur), 4))
+                get_recorder().record(
+                    "compile", seconds=round(dur, 4),
+                    program=made.get("program"), cause=made.get("cause"),
+                    cache=made.get("cache"))
             except Exception:  # noqa: BLE001 — diagnostics only
                 pass
 
@@ -137,6 +218,7 @@ def install_compile_listeners() -> CompileStats:
         monitoring.register_event_listener(COMPILE_STATS._event)
         monitoring.register_event_duration_secs_listener(
             COMPILE_STATS._duration)
+        monitoring.register_scalar_listener(COMPILE_STATS._scalar)
         _LISTENERS_INSTALLED = True
     return COMPILE_STATS
 
@@ -390,23 +472,21 @@ def warm_error(entry, exc: BaseException) -> str:
     return f"{entry}: {type(exc).__name__}: {exc}"[:500]
 
 
-def warm_up(model, manifest: Optional[Sequence[WarmupEntry]] = None,
-            progress=None, stop=None, **manifest_kw) -> Dict:
-    """Compile every program in ``manifest`` (default: derived via
-    ``warmup_manifest``) into the model's AOT executable cache.  Each
-    entry that is already cached (an earlier warm-up, or a live request
-    that beat us to it) is skipped for free.  ``progress(done, total,
-    entry)`` is called after each entry — the serving engine uses it to
-    publish per-bucket progress on ``/readyz``.
+def warm_pass(manifest: Sequence, warm_one, progress=None, stop=None,
+              who: str = "aot") -> Dict:
+    """One pass over a warm-up manifest, for either plane: the loop and
+    the stats document ``warm_up`` and ``ContinuousBatcher.warm`` share.
+    ``warm_one(entry)`` compiles one entry's program and returns False
+    when it was there already; ``progress(done, total, entry)`` is called
+    after each entry; ``stop()`` true ends the pass (a draining engine
+    must not keep the process alive compiling programs nobody will run).
 
     Returns ``{"programs", "compiled", "skipped", "failed", "errors",
-    "seconds", "compile_stats"}`` where ``compile_stats`` is the COMPILE_STATS delta
-    for the pass — on a process whose persistent cache is already
-    populated, ``cache_misses`` stays 0 and ``cache_hits`` covers the
-    set (the zero-cold-start evidence)."""
+    "stopped", "seconds", "compile_stats"}`` where ``compile_stats`` is
+    the COMPILE_STATS delta for the pass — on a process whose persistent
+    cache is already populated, ``cache_misses`` stays 0 and
+    ``cache_hits`` covers the set (the zero-cold-start evidence)."""
     install_compile_listeners()
-    if manifest is None:
-        manifest = warmup_manifest(model, **manifest_kw)
     before = COMPILE_STATS.snapshot()
     t0 = time.monotonic()
     compiled = skipped = failed = 0
@@ -414,13 +494,10 @@ def warm_up(model, manifest: Optional[Sequence[WarmupEntry]] = None,
     stopped = False
     for i, entry in enumerate(manifest):
         if stop is not None and stop():
-            # a draining engine must not keep the process alive compiling
-            # programs nobody will run
             stopped = True
             break
         try:
-            fresh = model.warm(entry.bucket, entry.shape, dtype=entry.dtype,
-                               scales=entry.scales)
+            fresh = warm_one(entry)
             compiled += 1 if fresh else 0
             skipped += 0 if fresh else 1
         except Exception as e:  # noqa: BLE001 — one bad entry must not
@@ -428,25 +505,100 @@ def warm_up(model, manifest: Optional[Sequence[WarmupEntry]] = None,
             # rides the stats so `degraded` says WHAT failed
             failed += 1
             errors.append(warm_error(entry, e))
-            logger.warning("aot: warm-up entry %s failed", entry,
+            logger.warning("%s: warm-up entry %s failed", who, entry,
                            exc_info=True)
         if progress is not None:
             progress(i + 1, len(manifest), entry)
     after = COMPILE_STATS.snapshot()
-    stats = {
-        "programs": len(manifest),
-        "compiled": compiled,
-        "skipped": skipped,
-        "failed": failed,
-        "errors": errors,
-        "stopped": stopped,
-        "seconds": round(time.monotonic() - t0, 3),
-        "compile_stats": {k: round(after[k] - before[k], 3)
-                          for k in after},
-    }
+    return {"programs": len(manifest), "compiled": compiled,
+            "skipped": skipped, "failed": failed, "errors": errors,
+            "stopped": stopped,
+            "seconds": round(time.monotonic() - t0, 3),
+            "compile_stats": {k: round(after[k] - before[k], 3)
+                              for k in after}}
+
+
+def warm_up(model, manifest: Optional[Sequence[WarmupEntry]] = None,
+            progress=None, stop=None, **manifest_kw) -> Dict:
+    """Compile every program in ``manifest`` (default: derived via
+    ``warmup_manifest``) into the model's AOT executable cache.  Each
+    entry that is already cached (an earlier warm-up, or a live request
+    that beat us to it) is skipped for free.  ``progress(done, total,
+    entry)`` is called after each entry — the serving engine uses it to
+    publish per-bucket progress on ``/readyz``.  Returns ``warm_pass``'s
+    stats document."""
+    if manifest is None:
+        manifest = warmup_manifest(model, **manifest_kw)
+    stats = warm_pass(
+        manifest,
+        lambda entry: model.warm(entry.bucket, entry.shape,
+                                 dtype=entry.dtype, scales=entry.scales),
+        progress=progress, stop=stop)
     logger.info("aot: warm-up %d program(s) in %.2fs (%d fresh, %d cached, "
                 "%d failed; %s backend compile(s), %s cache hit(s))",
-                stats["programs"], stats["seconds"], compiled, skipped,
-                failed, stats["compile_stats"]["cache_misses"],
+                stats["programs"], stats["seconds"], stats["compiled"],
+                stats["skipped"], stats["failed"],
+                stats["compile_stats"]["cache_misses"],
                 stats["compile_stats"]["cache_hits"])
     return stats
+
+
+# -- one program's start-up record (PR 37) --------------------------------------
+
+_MEMORY_FIELDS = (("code_bytes", "generated_code_size_in_bytes"),
+                  ("alias_bytes", "alias_size_in_bytes"))
+
+
+def compile_recorded(fn, args, program: str, cause: str
+                     ) -> Tuple[object, Dict]:
+    """``fn.lower(*args).compile()`` and the record of how it went:
+    ``program`` and ``cause`` as given (``"warmup"``, or ``"request"``
+    when a serving thread had to make the program: a stall with a name),
+    ``t`` (start, ``time.monotonic()``), ``lower_s`` (wall of ``lower``:
+    Python tracing + lowering to StableHLO), ``compile_s`` (wall of
+    ``compile``: a backend compile on a persistent-cache miss, retrieval
+    + load on a hit), ``cache`` (``hit`` / ``miss`` / ``off``), jax's own
+    seconds inside those two (``trace_s`` + ``mlir_s`` within
+    ``lower_s``; ``backend_s`` within ``compile_s``, ``retrieval_s``
+    within that: see ``CompileStats``), and of the executable's
+    ``memory_analysis()`` ``code_bytes`` (the generated code: what the
+    persistent cache has to hold) and ``alias_bytes`` (what the program
+    takes over in place of its arguments; 0 where the backend reports
+    none)."""
+    record = {"program": program, "cause": cause, "t": time.monotonic(),
+              "lower_s": 0.0, "compile_s": 0.0, "cache": "off",
+              "trace_s": 0.0, "mlir_s": 0.0, "backend_s": 0.0,
+              "retrieval_s": 0.0}
+    with COMPILE_STATS.making(record):
+        lowered = fn.lower(*args)
+        t_lowered = time.monotonic()
+        exe = lowered.compile()
+        t_compiled = time.monotonic()
+    record["lower_s"] = t_lowered - record["t"]
+    record["compile_s"] = t_compiled - t_lowered
+    memory = exe.memory_analysis()
+    for field, attr in _MEMORY_FIELDS:
+        record[field] = int(getattr(memory, attr, 0) or 0)
+    return exe, record
+
+
+def startup_totals(records: Sequence[Dict]) -> Dict[str, float]:
+    """The flat sums over program records that ``ContinuousBatcher.stats()``
+    publishes.  ``startup_s.<stage>``: the WARM-UP's seconds by stage — a
+    program made for a request (``startup_n.late`` counts them) keeps its
+    seconds in its record: they lie inside the stall it caused, and a
+    start's stages must not count them a second time.  ``startup_n.*``
+    and ``startup_b.code`` (generated code in all) are over every program
+    made."""
+    warmed = [r for r in records if r["cause"] == "warmup"]
+    totals = {"startup_s." + field[:-2]: sum(r[field] for r in warmed)
+              for field in ("lower_s", "compile_s", "trace_s", "mlir_s",
+                            "backend_s", "retrieval_s")}
+    totals.update({
+        "startup_n.programs": len(records),
+        "startup_n.cache_hits": sum(r["cache"] == "hit" for r in records),
+        "startup_n.cache_misses": sum(r["cache"] == "miss"
+                                      for r in records),
+        "startup_n.late": len(records) - len(warmed),
+        "startup_b.code": sum(r["code_bytes"] for r in records)})
+    return totals

@@ -35,6 +35,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 import jax
 import numpy as np
 
+from analytics_zoo_tpu.common.observability import get_startup
 from analytics_zoo_tpu.nn.module import Layer
 
 logger = logging.getLogger(__name__)
@@ -516,6 +517,7 @@ class InferenceModel:
         self._sharding_mode = None
         self._batch_multiple = 1
         self._bump_epoch()
+        get_startup().stamp("model_loaded")
         return self
 
     def do_load(self, topology_builder: Callable[[], Layer],

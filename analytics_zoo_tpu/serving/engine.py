@@ -101,7 +101,8 @@ import numpy as np
 
 from analytics_zoo_tpu.common.observability import (MetricsRegistry,
                                                     SloTracker, SpanContext,
-                                                    Tracer, new_trace_id,
+                                                    StartupMarks, Tracer,
+                                                    get_startup, new_trace_id,
                                                     trace_sampled)
 from analytics_zoo_tpu.common.resilience import (CircuitBreaker,
                                                  CircuitBreakerOpen,
@@ -707,10 +708,13 @@ class ClusterServing:
         self._inflight: Dict[str, float] = {}
         self._hb_ts = time.monotonic()       # read-loop heartbeat stamp
         # zero cold start (PR 11): AOT warm-up progress (published on
-        # /readyz + the health doc) and the construction-to-first-result
-        # clock the cold-start metric reports
-        self._t_construct = time.monotonic()
+        # /readyz + the health doc) and the construction-to-capable clock
+        # the cold-start metric reports, read off this engine's start-up
+        # marks (PR 37; ``_capable``)
+        self.startup = StartupMarks(get_startup().snapshot())
+        self.startup.stamp("engine")
         self._cold_start_s: Optional[float] = None
+        self._awaits_first_result = True
         self._warm_state: Dict = {"state": "off", "total": 0,
                                   "compiled": 0, "failed": 0,
                                   "seconds": None}
@@ -960,6 +964,7 @@ class ClusterServing:
             self._gen_params = GenerationParams.from_dict(
                 self.params.generation)
             self._batcher = ContinuousBatcher(model, self._gen_params)
+            self._batcher.startup = self.startup
             self._m_decode_steps = reg.counter(
                 "serving_decode_steps_total",
                 "Decode-step boundaries executed by the token scheduler")
@@ -2274,14 +2279,8 @@ class ClusterServing:
                 e2e_by_tenant.setdefault(ten, []).append(e2e)
             for ten, vals in e2e_by_tenant.items():
                 self.meter.request_seconds_many(ten, vals)
-        if n and self._cold_start_s is None:
-            # construction-to-serving-capable, the number the autoscaler's
-            # actuation lag is made of.  Stamped by whichever comes first:
-            # the first result written (a backlog was waiting — the bench's
-            # spawn-to-first-result) or warm-up completion (an idle boot
-            # must not count time spent waiting for traffic as cold start)
-            self._cold_start_s = now - self._t_construct
-            self._g_cold.set(self._cold_start_s)
+        if n and self._awaits_first_result:
+            self._capable("first_result", now)
         self.total_records += n
         dt = max(now - inflight.t_dispatch, 1e-9)
         if self._tb is not None:
@@ -2475,8 +2474,25 @@ class ClusterServing:
             name="serving-warmup", daemon=True)
         self._warm_thread.start()
 
+    def _capable(self, mark: str, t: float) -> None:
+        """``ready`` (the warm-up pass is done) or ``first_result`` (a
+        result was written) at ``t``.  Construction to serving-capable,
+        the number the autoscaler's actuation lag is made of, stops at
+        whichever comes first: the first result (a backlog was waiting —
+        the bench's spawn-to-first-result) or warm-up completion (an idle
+        boot must not count time spent waiting for traffic)."""
+        if mark == "first_result":
+            self._awaits_first_result = False
+        self.startup.stamp(mark, t)
+        if self._cold_start_s is None:
+            self._cold_start_s = t - self.startup.get("engine")
+            self._g_cold.set(self._cold_start_s)
+
     def _warmup_loop(self, manifest) -> None:
         from analytics_zoo_tpu.inference import aot
+        t_begin = self.startup.stamp("warm_begin")
+        self._g_warm.labels(phase="init").set(
+            t_begin - self.startup.get("engine"))
         self._warm_state["state"] = "warming"
         self._event("warmup", state="warming",
                     total=self._warm_state.get("total"))
@@ -2504,6 +2520,10 @@ class ClusterServing:
         if stats.get("stopped"):
             self._warm_state.update(state="cancelled")
             return
+        # serving-capable without having seen traffic yet: the replica is
+        # warm — the clock stops here, not at the first record (and before
+        # the state says so: who reads `ready` finds the mark)
+        self._capable("ready", time.monotonic())
         self._warm_state.update(
             state="ready" if not stats["failed"] else "degraded",
             failed=stats["failed"], errors=stats["errors"],
@@ -2514,11 +2534,11 @@ class ClusterServing:
                     programs=stats["programs"], failed=stats["failed"],
                     seconds=stats["seconds"])
         self._g_warm.labels(phase="compile").set(float(stats["seconds"]))
-        if self._cold_start_s is None:
-            # serving-capable without having seen traffic yet: the replica
-            # is warm — the clock stops here, not at the first record
-            self._cold_start_s = time.monotonic() - self._t_construct
-            self._g_cold.set(self._cold_start_s)
+        if self._batcher is not None:
+            totals = aot.startup_totals(list(self._batcher.program_records))
+            for phase in ("lower", "backend", "retrieval"):
+                self._g_warm.labels(phase=phase).set(
+                    totals["startup_s." + phase])
         logger.info(
             "serving: replica %s warm — %d/%d program(s) in %.2fs (%s "
             "backend compile(s), %s persistent-cache hit(s))",
@@ -2530,7 +2550,9 @@ class ClusterServing:
     def warmup_state(self) -> Dict:
         """Warm-up progress document (health doc / readyz / manager
         status surface)."""
-        return dict(self._warm_state)
+        programs = [] if self._batcher is None \
+            else [dict(r) for r in list(self._batcher.program_records)]
+        return dict(self._warm_state, programs=programs)
 
     def _pre_loop(self):
         sup = self._pre_sup
@@ -2906,9 +2928,8 @@ class ClusterServing:
                 e2e = self._slo_observe(ev.rid, now - ev.t_read, stages,
                                         tenant=ev.tenant)
                 self.meter.request_seconds(ev.tenant, e2e)
-        if n and self._cold_start_s is None:
-            self._cold_start_s = now - self._t_construct
-            self._g_cold.set(self._cold_start_s)
+        if n and self._awaits_first_result:
+            self._capable("first_result", now)
         self.total_records += n
         self._maybe_trim()
 

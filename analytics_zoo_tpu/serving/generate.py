@@ -62,6 +62,14 @@ models/textmodels.py):
   one cycle), and every request is stamped where each step of its time to
   first token happens (submit -> admit -> first token -> first output).
   ``stats()`` carries both as cumulative sums.
+- **its start on record** (PR 37) — ``_compiled`` is the one place a
+  program is made, from ``warm()`` and from the hot path alike, and every
+  program it makes leaves a record there (``program_records``: name,
+  ``cause`` = ``warmup`` or ``request``, the seconds of lowering and
+  compiling, the persistent cache's verdict, the executable's bytes);
+  ``stats()`` carries their sums (``startup_s.*``, ``startup_n.*``,
+  ``startup_b.*``) beside the process's start-up marks
+  (``startup_t.<mark>``).  A looked-up program costs what it cost before.
 """
 
 from __future__ import annotations
@@ -75,7 +83,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from analytics_zoo_tpu.common.observability import PhaseClock
+from analytics_zoo_tpu.common.observability import PhaseClock, get_startup
 
 logger = logging.getLogger(__name__)
 
@@ -458,11 +466,15 @@ class ContinuousBatcher:
         # per-program execution counts (PR 15 resource accounting):
         # scheduler-thread-only, keyed by the manifest-style program name
         self._exec_counts: Dict[str, int] = {}
-        # bytes each compiled paged program aliases from its inputs to its
-        # outputs (memory_analysis, at compile time), and over the calls
-        # of those programs: pool bytes handed in, and how many of them
-        # the program took over in place
-        self._alias_bytes: Dict[tuple, int] = {}
+        # one record a program MADE (``aot.compile_recorded``, PR 37): when,
+        # for whom, the seconds of lowering and compiling, the persistent
+        # cache's verdict and the executable's bytes; in the order made,
+        # and by key (a key made again after a changed matmul rule keeps
+        # its newest).  ``alias_bytes`` there is what a paged program
+        # takes over in place of the pool it is handed; over the calls of
+        # those programs: pool bytes handed in, and how many were aliased
+        self.program_records: List[Dict] = []
+        self._record_of: Dict[tuple, Dict] = {}
         self.state_bytes_passed = 0
         self.state_bytes_aliased = 0
         # the weights' operand form (``_params``): the tree and the rule it
@@ -495,6 +507,9 @@ class ContinuousBatcher:
         import jax
         self.clock = PhaseClock(PHASES, annotate=jax.profiler.TraceAnnotation,
                                 prefix=PHASE_SPAN_PREFIX)
+        # the start-up marks ``stats()`` publishes: the process's, until an
+        # engine hands over its own (``ClusterServing``)
+        self.startup = get_startup()
         # the TTFT chain (PR 25), cumulative seconds and counts, each added
         # where its interval ends: read -> submit (engine intake), submit ->
         # admit (waiting room), admit -> first token (batch assembly +
@@ -671,20 +686,23 @@ class ContinuousBatcher:
         self._programs[key] = fns
         return fns
 
-    def _compiled(self, key: tuple, lane: _Lane):
+    def _compiled(self, key: tuple, lane: _Lane, cause: str = "request"):
         """AOT-compiled executable for one fixed-shape program, compiled
-        exactly once; ``warm()`` walks the same path, so a warmed program
-        is the very executable the hot path runs."""
+        exactly once; ``warm()`` walks the same path (``cause="warmup"``),
+        so a warmed program is the very executable the hot path runs.
+        Making one leaves its record (``program_records``); a program the
+        generate thread had to make reads ``cause == "request"``."""
         # a changed matmul rule drops the other form's executables HERE,
         # before the look-up, not between it and the call
         self._params()
         exe = self._programs.get(key)
         if exe is None:
+            from analytics_zoo_tpu.inference import aot
             fn, args = self._lowering(key, lane)
-            exe = fn.lower(*args).compile()
-            if key[0] in self._POOL_PROGRAMS:
-                self._alias_bytes[key] = int(
-                    exe.memory_analysis().alias_size_in_bytes)
+            exe, record = aot.compile_recorded(
+                fn, args, self._program_name(key), cause)
+            self._record_of[key] = record
+            self.program_records.append(record)
             self._programs[key] = exe
             self.compiles += 1
         return exe
@@ -787,7 +805,7 @@ class ContinuousBatcher:
         if lane is not None:
             # raw on both sides: a share above 100 is a miscount to find
             self.state_bytes_passed += lane.state_nbytes
-            self.state_bytes_aliased += self._alias_bytes.get(key, 0)
+            self.state_bytes_aliased += self._record_of[key]["alias_bytes"]
 
     def _commit_state(self, state):
         """Commit a lane state buffer over the serving mesh (PR 6): slot
@@ -1665,34 +1683,10 @@ class ContinuousBatcher:
         from analytics_zoo_tpu.inference import aot
         if manifest is None:
             manifest = self.warmup_manifest()
-        before = aot.COMPILE_STATS.snapshot()
-        t0 = time.monotonic()
-        compiled = skipped = failed = 0
-        errors: List[str] = []
-        stopped = False
         lanes = {lane.bucket: lane for lane in self._lanes}
-        for i, entry in enumerate(manifest):
-            if stop is not None and stop():
-                stopped = True
-                break
-            try:
-                fresh = self._warm_entry(entry, lanes)
-                compiled += 1 if fresh else 0
-                skipped += 0 if fresh else 1
-            except Exception as e:  # noqa: BLE001 — one bad entry must not
-                failed += 1         # strand the set; counted and reported
-                errors.append(aot.warm_error(entry, e))
-                logger.warning("generate: warm-up entry %s failed", entry,
-                               exc_info=True)
-            if progress is not None:
-                progress(i + 1, len(manifest), entry)
-        after = aot.COMPILE_STATS.snapshot()
-        return {"programs": len(manifest), "compiled": compiled,
-                "skipped": skipped, "failed": failed, "errors": errors,
-                "stopped": stopped,
-                "seconds": round(time.monotonic() - t0, 3),
-                "compile_stats": {k: round(after[k] - before[k], 3)
-                                  for k in after}}
+        return aot.warm_pass(
+            manifest, lambda entry: self._warm_entry(entry, lanes),
+            progress=progress, stop=stop, who="generate")
 
     def _warm_entry(self, entry, lanes: Dict[int, "_Lane"]) -> bool:
         """Compile one manifest entry's program; False = it was there."""
@@ -1711,7 +1705,7 @@ class ContinuousBatcher:
         if key is None:
             raise ValueError(f"unknown warm-up entry kind {entry.kind!r}")
         fresh = key not in self._programs
-        self._compiled(key, lane)
+        self._compiled(key, lane, cause="warmup")
         return fresh
 
     # -- observability --------------------------------------------------------
@@ -1815,6 +1809,12 @@ class ContinuousBatcher:
             d["phase_s." + name] = seconds[name]
             d["phase_n." + name] = counts[name]
         d["loop_s"] = sum(seconds.values())
+        # the start (PR 37): the marks set so far, and the sums over the
+        # programs made so far (``program_records``)
+        from analytics_zoo_tpu.inference import aot
+        for name, t in self.startup.snapshot().items():
+            d["startup_t." + name] = t
+        d.update(aot.startup_totals(list(self.program_records)))
         for name, value in self.model_counters.items():
             d["model." + name] = value
         if self._pool is not None:

@@ -152,7 +152,7 @@ def test_compiled_decode_aliases_every_pool_leaf_prefill_none(warmed, kind):
         want = lane.state_nbytes if kind in DONATING else 0
         assert aliased == want, \
             f"{key}: {aliased} of {lane.state_nbytes} pool bytes aliased"
-        assert b._alias_bytes[key] == aliased
+        assert b._record_of[key]["alias_bytes"] == aliased
 
 
 # -- a call consumes the pool, and the counters say so ------------------------

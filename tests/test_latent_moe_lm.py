@@ -189,7 +189,8 @@ def test_the_state_is_donated_and_sorted_into_the_ledger(served):
     b, lm, _, _, _ = served
     lane = b._lanes[0]
     assert set(lane.state) == {"kv", "ik", "counters", "seen", "who"}
-    assert b._alias_bytes[("pdecode", lane.bucket)] == lane.state_nbytes
+    assert b._record_of[("pdecode", lane.bucket)]["alias_bytes"] \
+        == lane.state_nbytes
     n_blocks = b._pool.n_blocks + 1
     doc = b.state_bytes_doc()
     assert doc["paged_pool"] == 3 * n_blocks * 4 * (128 + 8) * 4

@@ -512,8 +512,8 @@ def test_scheduler_serves_a_model_with_another_state_layout():
     assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(lane.state))
     assert int(np.asarray(lane.state["seen"]).max()) > 0
     nbytes = sum(leaf.nbytes for leaf in jax.tree.leaves(lane.state))
-    assert b._alias_bytes[("pdecode", lane.bucket)] == nbytes \
-        == lane.state_nbytes
+    assert b._record_of[("pdecode", lane.bucket)]["alias_bytes"] \
+        == nbytes == lane.state_nbytes
     assert s["state_bytes_aliased"] == s["boundaries"] * nbytes
     n_blocks = b._pool.n_blocks + 1
     kv = 2 * n_blocks * 8 * 2 * 32 * 4

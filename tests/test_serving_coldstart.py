@@ -304,6 +304,16 @@ def test_engine_readyz_warming_progress():
         h = s.health()
         assert h["cold_start_s"] is not None
         assert h["warmup"]["compiled"] == 8
+        # ... and read off the engine's start-up marks (PR 37): engine ->
+        # ready, with the warm-up pass between its own two marks
+        marks = s.startup.snapshot()
+        assert h["cold_start_s"] == round(marks["ready"] - marks["engine"], 3)
+        assert marks["imported"] <= marks["model_loaded"] <= marks["engine"] \
+            <= marks["warm_begin"] <= marks["ready"]
+        assert marks["ready"] - marks["warm_begin"] \
+            >= h["warmup"]["seconds"] - 0.01
+        # the predict plane has no scheduler programs to list
+        assert h["warmup"]["programs"] == [] and "programs" not in doc["warmup"]
         # …and serving still works, off the warmed executables
         compiles = im.aot_stats()["compiles"]
         cin, cout = InputQueue(q), OutputQueue(q)
@@ -315,6 +325,7 @@ def test_engine_readyz_warming_progress():
         prom = s.prom_metrics()
         assert "replica_cold_start_seconds" in prom
         assert 'serving_warmup_seconds{phase="compile"}' in prom
+        assert 'serving_warmup_seconds{phase="init"}' in prom
     finally:
         s.shutdown()
 

@@ -326,7 +326,8 @@ def test_the_counters_total_what_the_requests_needed(served):
         == sum(len(p) for _, p, _ in reqs) * N_LINEAR
     lane = b._lanes[0]
     assert set(lane.state) == {"k", "v", "ck", "lin", "counters"}
-    assert b._alias_bytes[("pdecode", lane.bucket)] == lane.state_nbytes
+    assert b._record_of[("pdecode", lane.bucket)]["alias_bytes"] \
+        == lane.state_nbytes
     doc = b.state_bytes_doc()
     n_blocks = b._pool.n_blocks + 1
     assert doc["paged_pool"] == N_SPARSE * n_blocks * (2 * 4 + 2) * 2 * 8 * 4
