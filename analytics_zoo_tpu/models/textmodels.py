@@ -518,8 +518,8 @@ class TransformerLM(Layer):
         and the read through the ``paged_attention`` kernel
         (``ops/paged_attention.pool_append_attend``).  Inactive rows point
         their whole table at the trash block, so their writes land
-        harmlessly.  Returns ``(logits, new_pstate)`` — the caller
-        advances ``pos``."""
+        harmlessly and their attention reads nothing (a zero row).
+        Returns ``(logits, new_pstate)`` — the caller advances ``pos``."""
         tokens = jnp.asarray(tokens, jnp.int32)
         pos = jnp.asarray(pos, jnp.int32)
         bt = jnp.asarray(block_tables, jnp.int32)

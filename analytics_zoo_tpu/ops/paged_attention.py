@@ -1,4 +1,4 @@
-"""Paged decode attention — block-pool KV gather kernels (PR 18, 26).
+"""Paged decode attention — block-pool KV gather kernels (PR 18, 26, 38).
 
 The continuous batcher's monolithic per-slot KV lanes become a fixed pool
 of ``(n_blocks, block_len, heads * head_dim)`` buffers; each decode row
@@ -21,27 +21,35 @@ Two data paths, the `quant_matmul.py` shape:
   Because the gather materializes the same values at the same positions,
   the float path is BITWISE-equal to monolithic decode on one backend —
   the parity anchor, and what a CPU process serves through.
-- ``_paged_kernel`` — Pallas TPU kernel over a grid of (rows, table
-  groups).  The block table and the lengths ride in as SCALAR-PREFETCH
-  operands (``pltpu.PrefetchScalarGridSpec``); the pools stay in HBM
-  (``pl.ANY``) and the kernel copies pool blocks HBM->VMEM itself by
-  PHYSICAL id — no host gather, no (A, used_len) materialization.
-  - A grid step folds a GROUP of G consecutive table entries of a row,
-    ``G * block_len`` = 128 cache positions at the served shapes, into the
-    row's online-softmax carry (flash_attention style, PR 26; one block a
-    step before).  G follows from the shapes the kernel is handed
+- ``_paged_kernel`` — Pallas TPU kernel over a grid of rows, one step a
+  row (PR 38; (rows, table groups) before).  The block table and the
+  lengths ride in as SCALAR-PREFETCH operands
+  (``pltpu.PrefetchScalarGridSpec``); the pools stay in HBM (``pl.ANY``)
+  and the kernel copies pool blocks HBM->VMEM itself by PHYSICAL id — no
+  host gather, no (A, used_len) materialization.
+  - A row's step loops over its LIVE groups only (a ``fori_loop`` of
+    ``ceil(live blocks / G)`` trips); a group is G consecutive table
+    entries, ``G * block_len`` = 128 cache positions at the served shapes,
+    folded into the row's online-softmax carry (flash_attention style, PR
+    26).  G follows from the shapes the kernel is handed
     (``_group_blocks``): the largest power of two with ``G * block_len <=
     128``, no wider than the table rounded up to a power of two (a narrower
     table is one group, a table that is no multiple of G is padded with
     entries that are never fetched), halved until both pools'
     double-buffered fetch slots fit ``_FETCH_VMEM_BYTES``.
-  - Fetching: one DMA a LIVE block (one holding positions below the row's
-    length) into one of two VMEM slots; a live step starts the next live
-    step's DMAs (the row's next group, or the next row's first) before it
-    waits for its own, so fetch and fold overlap across steps and rows.
+  - A row whose first table entry is block 0, the trash block (how the
+    batcher marks an inactive slot), has NO live group whatever its
+    length: no DMA, no fold, a zero output row.  What the kernel skips
+    follows its inputs alone: each row's length and first table entry.
+  - Fetching: one DMA a live block (one holding positions below the row's
+    length) into one of two VMEM slots; each fold starts the next live
+    group's DMAs (the row's next group, or group 0 of the next row that
+    has one) before it waits for its own, so fetch and fold overlap across
+    groups and rows; only the call's first group, of its first live row,
+    is fetched in the open, and a call without a live row fetches nothing.
     Entries past a row's length are not fetched at all — their buffer rows
     keep stale values and are masked — and a group wholly past it is
-    skipped.
+    never visited.
   - Per-head reductions over the folded lane axis are matmuls against a
     0/1 head-membership matrix (``_head_segments``), so every in-kernel
     value is a plain 2-D tile; with ~128 rows on the moving side the
@@ -124,7 +132,8 @@ def paged_attention_xla(q, k_pool, v_pool, block_tables, lengths,
     """Reference path: gather -> dequant -> the exact decode_step
     attention (same einsums, same -1e30 mask, same softmax), so the float
     path is bitwise-identical to attending over a monolithic cache that
-    holds the same values."""
+    holds the same values; a trash row (first table entry 0) reads zero,
+    as in the kernel."""
     nh = q.shape[1]
     kc = _gather_dequant(k_pool, k_scale, block_tables, nh)
     vc = _gather_dequant(v_pool, v_scale, block_tables, nh)
@@ -136,7 +145,8 @@ def paged_attention_xla(q, k_pool, v_pool, block_tables, lengths,
     valid = jnp.arange(T * bl)[None] < lengths[:, None]         # (A, T*bl)
     att = jnp.where(valid[:, None], att, NEG_INF)
     att = jax.nn.softmax(att, axis=-1)
-    return jnp.einsum("bhk,bkhd->bhd", att, vc)
+    out = jnp.einsum("bhk,bkhd->bhd", att, vc)
+    return jnp.where((block_tables[:, 0] == 0)[:, None, None], 0.0, out)
 
 
 def _head_segments(nh: int, hd: int):
@@ -184,36 +194,44 @@ def _group_blocks(block_len: int, n_table: int, width: int,
 def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
                   block_len: int, n_table: int, group: int, quant: bool,
                   scale: float):
-    """One (row, table-group) grid step: fold the group's ``group`` pool
-    blocks — ``GB = group * block_len`` cache positions — into the row's
-    online-softmax carry (m/l per head, acc per lane; scratch persists
-    across the group axis), emit at the row's last group.  W = heads *
-    head_dim lanes, P = heads padded to a lane multiple.
+    """One row's grid step: fold the row's LIVE groups, ``group`` pool
+    blocks — ``GB = group * block_len`` cache positions — each, in a loop
+    into the row's online-softmax carry (m/l per head, acc per lane), then
+    emit.  W = heads * head_dim lanes, P = heads padded to a lane multiple.
 
-    The pools stay in HBM.  A live step (one that holds positions below the
-    row's length) first starts the DMAs of the NEXT live step's blocks —
-    the row's next group, or group 0 of the next row — into the other of
-    two VMEM slots, then waits for its own, so a group's fetch overlaps
-    the previous group's fold; only the call's first group is fetched in
-    the open.  One DMA a live block by physical id from the scalar-
-    prefetched table; a block wholly past the length is never fetched (its
-    buffer rows keep whatever they held, and are masked), a group wholly
-    past it costs one predicate."""
+    A row whose first table entry is block 0, the trash block (an inactive
+    slot), has no live group: no DMA, no fold, a zero output row.
+
+    The pools stay in HBM.  Each fold first starts the DMAs of the NEXT
+    live group's blocks — the row's next group, or group 0 of the next row
+    that has one — into the other of two VMEM slots, then waits for its
+    own, so a group's fetch overlaps the previous group's fold; only the
+    call's first group is fetched in the open.  One DMA a live block by
+    physical id from the scalar-prefetched table; a block wholly past the
+    length is never fetched (its buffer rows keep whatever they held, and
+    are masked), a group wholly past it never visited."""
     if quant:
         ks_hbm, vs_hbm, seg_ref, o_ref, m_ref, l_ref, acc_ref, slot_ref, \
             k_buf, v_buf, ks_buf, vs_buf, sems = rest
     else:
         seg_ref, o_ref, m_ref, l_ref, acc_ref, slot_ref, k_buf, v_buf, \
             sems = rest
-    a, t = pl.program_id(0), pl.program_id(1)
+    a = pl.program_id(0)
     n_rows = pl.num_programs(0)
     gb = group * block_len
 
     def live_blocks(row):
         # table entries holding positions below the row's length; at least
-        # one, so that every row has a live group 0 and the chain of
-        # prefetches never breaks
-        return jnp.clip(pl.cdiv(len_ref[row], block_len), 1, n_table)
+        # one for a row that is not trash
+        return jnp.where(bt_ref[row, 0] == 0, 0,
+                         jnp.clip(pl.cdiv(len_ref[row], block_len), 1,
+                                  n_table))
+
+    def next_live(row):
+        # the first row at or after ``row`` with a live group (n_rows: none)
+        return jax.lax.while_loop(
+            lambda r: (r < n_rows) & (live_blocks(jnp.minimum(
+                r, n_rows - 1)) == 0), lambda r: r + 1, row)
 
     def fetch(row, grp, slot, wait: bool = False):
         """Start, or wait for, the DMAs of group ``grp`` of ``row`` into
@@ -238,7 +256,7 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
         # seg.T, contracted on seg's head axis
         return _dot01(rows, seg_ref[...], ((1,), (1,)))
 
-    @pl.when((a == 0) & (t == 0))
+    @pl.when(a == 0)
     def _first():
         slot_ref[0] = 0
         if not quant:
@@ -246,64 +264,74 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
             # VMEM may hold NaN patterns (any int8 is finite)
             k_buf[...] = jnp.zeros(k_buf.shape, k_buf.dtype)
             v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
-        fetch(0, 0, 0)      # the one fetch nothing overlaps
+        first = next_live(0)
 
-    @pl.when(t == 0)
-    def _init():
+        @pl.when(first < n_rows)
+        def _open():
+            fetch(first, 0, 0)      # the one fetch nothing overlaps
+
+    n_groups = pl.cdiv(live_blocks(a), group)
+
+    @pl.when(n_groups > 0)
+    def _row():
         m_ref[...] = jnp.full(m_ref.shape, NEG_INF, m_ref.dtype)
         l_ref[...] = jnp.zeros(l_ref.shape, l_ref.dtype)
         acc_ref[...] = jnp.zeros(acc_ref.shape, acc_ref.dtype)
+        after = next_live(a + 1)
 
-    # groups wholly past the row's length contribute nothing: skip them
-    @pl.when(t * group < live_blocks(a))
-    def _fold():
-        slot = slot_ref[0]
-        more = (t + 1) * group < live_blocks(a)
+        def fold(t, carry):
+            slot = slot_ref[0]
+            more = t + 1 < n_groups
 
-        @pl.when(more | (a + 1 < n_rows))
-        def _prefetch():
-            fetch(jnp.where(more, a, a + 1), jnp.where(more, t + 1, 0),
-                  1 - slot)
+            @pl.when(more | (after < n_rows))
+            def _prefetch():
+                fetch(jnp.where(more, a, after), jnp.where(more, t + 1, 0),
+                      1 - slot)
 
-        fetch(a, t, slot, wait=True)
-        slot_ref[0] = 1 - slot
+            fetch(a, t, slot, wait=True)
+            slot_ref[0] = 1 - slot
 
-        q = q_ref[0].astype(jnp.float32) * scale                  # (1, W)
-        k = k_buf[slot].astype(jnp.float32).reshape(gb, -1)       # (GB, W)
-        v = v_buf[slot].astype(jnp.float32).reshape(gb, -1)
-        # s[j, h] = ks[block of j, h] * sum_{lanes of h} q * k[j]
-        s = _dot01(q * k, seg_ref[...], ((1,), (0,)))             # (GB, P)
-        # (a table padded into its last group has positions past its end)
-        valid = t * gb + jax.lax.broadcasted_iota(
-            jnp.int32, (gb, 1), 0) < jnp.minimum(len_ref[a],
-                                                 n_table * block_len)
-        if quant:
-            def rows(buf):        # (G, 1, P) block scales -> (GB, P)
-                sc = buf[slot]
-                return jnp.broadcast_to(
-                    sc, (group, block_len, sc.shape[-1])).reshape(gb, -1)
-            s = s * rows(ks_buf)
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_ref[...]                                       # (8, P)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
-        p = jnp.exp(s - m_new[:1])                                # (GB, P)
-        alpha = jnp.exp(m_prev - m_new)                           # (8, P)
-        m_ref[...] = m_new
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=0, keepdims=True)
-        if quant:
-            # an unfetched block's scale rows are stale: 0 * stale != 0
-            p = jnp.where(valid, p * rows(vs_buf), 0.0)
-        # one spread for both per-head factors: the (dequantized)
-        # probabilities and the carry rescale (twice: 16-row bf16 tiles)
-        wide = spread(jnp.concatenate([p, alpha, alpha], axis=0))
-        acc_ref[...] = acc_ref[...] * wide[gb:gb + 1] \
-            + jnp.sum(wide[:gb] * v, axis=0, keepdims=True)
+            q = q_ref[0].astype(jnp.float32) * scale                  # (1, W)
+            k = k_buf[slot].astype(jnp.float32).reshape(gb, -1)       # (GB, W)
+            v = v_buf[slot].astype(jnp.float32).reshape(gb, -1)
+            # s[j, h] = ks[block of j, h] * sum_{lanes of h} q * k[j]
+            s = _dot01(q * k, seg_ref[...], ((1,), (0,)))             # (GB, P)
+            # (a table padded into its last group has positions past its end)
+            valid = t * gb + jax.lax.broadcasted_iota(
+                jnp.int32, (gb, 1), 0) < jnp.minimum(len_ref[a],
+                                                     n_table * block_len)
+            if quant:
+                def rows(buf):        # (G, 1, P) block scales -> (GB, P)
+                    sc = buf[slot]
+                    return jnp.broadcast_to(
+                        sc, (group, block_len, sc.shape[-1])).reshape(gb, -1)
+                s = s * rows(ks_buf)
+            s = jnp.where(valid, s, NEG_INF)
+            m_prev = m_ref[...]                                       # (8, P)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+            p = jnp.exp(s - m_new[:1])                                # (GB, P)
+            alpha = jnp.exp(m_prev - m_new)                           # (8, P)
+            m_ref[...] = m_new
+            l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=0,
+                                                      keepdims=True)
+            if quant:
+                # an unfetched block's scale rows are stale: 0 * stale != 0
+                p = jnp.where(valid, p * rows(vs_buf), 0.0)
+            # one spread for both per-head factors: the (dequantized)
+            # probabilities and the carry rescale (twice: 16-row bf16 tiles)
+            wide = spread(jnp.concatenate([p, alpha, alpha], axis=0))
+            acc_ref[...] = acc_ref[...] * wide[gb:gb + 1] \
+                + jnp.sum(wide[:gb] * v, axis=0, keepdims=True)
+            return carry
 
-    @pl.when(t == pl.num_programs(1) - 1)
-    def _emit():
+        jax.lax.fori_loop(0, n_groups, fold, 0)
         l = l_ref[...]
         o_ref[0] = (acc_ref[...] / spread(
             jnp.concatenate([l, l], axis=0))[:1]).astype(o_ref.dtype)
+
+    @pl.when(n_groups == 0)
+    def _trash():
+        o_ref[0] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -325,10 +353,10 @@ def _paged_pallas(q, k_pool, v_pool, block_tables, lengths, k_scale,
         return jnp.pad(jnp.asarray(s, jnp.float32),
                        [(0, 0), (0, P - nh)])[:, None, :]
 
-    def row_block(a, t, bt, ln):
+    def row_block(a, bt, ln):
         return (a, 0, 0)
 
-    def whole(a, t, bt, ln):
+    def whole(a, bt, ln):
         return (0, 0)
 
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
@@ -336,7 +364,7 @@ def _paged_pallas(q, k_pool, v_pool, block_tables, lengths, k_scale,
     scale_buf = pltpu.VMEM((2, group, 1, P), jnp.float32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(A, pl.cdiv(n_table, group)),
+        grid=(A,),
         in_specs=[pl.BlockSpec((1, 1, W), row_block), in_hbm, in_hbm]
         + [in_hbm, in_hbm] * quant
         + [pl.BlockSpec((W, P), whole)],
@@ -358,10 +386,10 @@ def _paged_pallas(q, k_pool, v_pool, block_tables, lengths, k_scale,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((A, 1, W), jnp.float32),
-        # the fetch slot and the DMAs in flight carry from one step to the
-        # next, across rows too: both axes run in order
+        # the fetch slot and the DMAs in flight carry from one row's step
+        # to the next: the rows run in order
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_attention_int8" if k_pool.dtype == jnp.int8
         else "paged_attention",
@@ -383,7 +411,10 @@ def paged_attention(q, k_pool, v_pool, block_tables, lengths,
       lives in pool block ``block_tables[a, j]``.  Entries past a row's
       allocation may point anywhere resident (conventionally block 0, the
       batcher's trash block): the reference masks their positions by
-      ``lengths``, the kernel does not fetch them.
+      ``lengths``, the kernel does not fetch them.  A row whose FIRST
+      entry is block 0 is an inactive slot: whatever its length, its
+      output is exactly zero and the kernel reads nothing for it (block 0
+      is never allocated to a request).
     - ``lengths`` (rows,) int32 >= 1 — valid cache positions per row
       (cursor + 1 at decode time: the current token's K/V is written
       before the read).

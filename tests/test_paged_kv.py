@@ -259,12 +259,36 @@ KERNEL_CASES = [
     # inactive slots between live rows: all-trash tables, length 1
     pytest.param(5, 32, 8, 2, 8, (1, 200, 1, 256, 1), (0, 2, 4),
                  id="trash-rows"),
+    # as served: an idle slot's cursor climbs by the quantum to the lane,
+    # so trash rows sit at the full lane and at mid lengths
+    pytest.param(5, 32, 8, 2, 8, (256, 200, 256, 129, 256), (0, 2, 4),
+                 id="trash-rows-full-lane"),
+    pytest.param(6, 32, 8, 2, 8, (77, 1, 130, 256, 200, 129), (0, 2, 5),
+                 id="trash-rows-mid-lengths"),
+    # one live row among seven trash rows: the cross-row prefetch chain
+    # skips them (first, last, middle)
+    pytest.param(8, 32, 8, 2, 8, (200, 256, 1, 130, 77, 256, 17, 129),
+                 (1, 2, 3, 4, 5, 6, 7), id="one-live-first"),
+    pytest.param(8, 32, 8, 2, 8, (256, 1, 130, 77, 256, 17, 129, 200),
+                 (0, 1, 2, 3, 4, 5, 6), id="one-live-last"),
+    pytest.param(8, 32, 8, 2, 8, (256, 1, 130, 200, 256, 17, 129, 77),
+                 (0, 1, 2, 4, 5, 6, 7), id="one-live-middle"),
+    # no live row at all: nothing fetched, every output row zero
+    pytest.param(4, 32, 8, 2, 8, (1, 256, 100, 17), (0, 1, 2, 3),
+                 id="all-trash"),
     # block_len 16 -> G 8, n_table 12: two groups, the second half empty
     pytest.param(3, 12, 16, 3, 8, (192, 130, 16), (), id="block-len-16"),
     # the benchmark cells' shape (gpt2-large, 8 slots, lane 1024)
     pytest.param(8, 64, 16, 20, 64, (1, 16, 17, 128, 129, 600, 1023, 1024),
                  (), id="cell-shape"),
 ]
+
+
+def _check_trash_rows(kern, oracle, trash):
+    """A trash row (an inactive slot) reads exactly zero on both paths,
+    whatever its length, and nothing is 0/0."""
+    assert np.isfinite(kern).all()
+    assert not kern[list(trash)].any() and not oracle[list(trash)].any()
 
 
 @pytest.mark.parametrize("A,n_table,bl,nh,hd,lengths,trash", KERNEL_CASES)
@@ -281,6 +305,7 @@ def test_paged_attention_kernel_parity_float(A, n_table, bl, nh, hd,
     kern = np.asarray(paged_attention(q, kp, vp, tables, lens,
                                       impl="interpret"))
     np.testing.assert_allclose(kern, oracle, rtol=2e-5, atol=2e-5)
+    _check_trash_rows(kern, oracle, trash)
 
 
 @pytest.mark.parametrize("A,n_table,bl,nh,hd,lengths,trash", KERNEL_CASES)
@@ -300,6 +325,7 @@ def test_paged_attention_kernel_parity_int8(A, n_table, bl, nh, hd,
                                       k_scale=ks, v_scale=vs,
                                       impl="interpret"))
     np.testing.assert_allclose(kern, oracle, rtol=2e-5, atol=2e-5)
+    _check_trash_rows(kern, oracle, trash)
     # the quantization itself stays close to the float answer
     flt = np.asarray(paged_attention_xla(q, _fold(kp), _fold(vp), tables,
                                          lens))
