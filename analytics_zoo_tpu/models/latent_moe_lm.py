@@ -24,7 +24,8 @@ routed expert layer that holds its chip's share of the experts (PR 32).
   (``experts_held = (first, count)``: a chip's share of an expert-parallel
   deployment), no pair dropped at any imbalance (pairs sorted by expert, slabs
   of them through ``jax.lax.ragged_dot`` until none is left), plus the shared
-  expert; what the absent experts would add is left out.
+  expert; what the absent experts would add is left out.  The routed part
+  is ``lm_common.routed_experts``, which ``WindowMoELM`` calls too.
 
 The decoder block is written once (``_blocks``); the paths supply their
 attention step.  The pool's device format (one ``[c_kv | k_r]`` row and one
@@ -227,60 +228,19 @@ class LatentMoELM(Layer):
 
     def _moe(self, blk, h, valid, decode: bool):
         """The routed layer over tokens ``h`` (T, H), of which ``valid``
-        (T,) are real.  Returns ``(y, counts)``: routed (held experts only)
-        plus shared output, and this call's ``COUNTERS`` increments."""
-        T, H = h.shape
-        k, (first, count) = self.top_k, self.experts_held
-        with _scope("moe_route"):
-            s = jax.nn.sigmoid(jnp.matmul(
-                h, blk["router"], precision=jax.lax.Precision.HIGHEST))
-            _, idx = jax.lax.top_k(s + blk["e_bias"], k)          # (T, k)
-            g = jnp.take_along_axis(s, idx, axis=-1)
-            g = self.route_scale * g / (g.sum(-1, keepdims=True) + 1e-20)
-            local = idx - first
-            held = (local >= 0) & (local < count) & valid[:, None]
-            # pairs sorted by held expert; what is not held sorts behind
-            key = jnp.where(held, local, count).reshape(-1)
-            order = jnp.argsort(key)
-            tok, gate = order // k, g.reshape(-1)[order]
-            sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
-            ends = jnp.cumsum(sizes)
-            total = ends[-1]
+        (T,) are real (``lm_common.routed_experts``, sigmoid scoring; the
+        router reads ``h``).  Returns ``(y, counts)``: routed (held experts
+        only) plus shared output, and this call's ``COUNTERS`` increments."""
+        y, c = common.routed_experts(
+            h, h, blk, valid, top_k=self.top_k, held=self.experts_held,
+            dtype=self.dtype, scoring="sigmoid", scale=self.route_scale,
+            slab=_PAIR_SLAB)
         with _scope("moe_experts"):
-            R = min(_PAIR_SLAB, T * k)
-            # the pair list is padded so that its last slab is whole
-            pad = (-(T * k)) % R
-            tok = jnp.concatenate([tok, jnp.zeros((pad,), tok.dtype)])
-            gate = jnp.concatenate([gate, jnp.zeros((pad,), gate.dtype)])
-
-            def slab(i, y):
-                # pairs [lo, lo + R) of the sorted list: each expert's rows
-                # inside the slab are one group of the grouped matmul
-                lo = i * R
-                rows = jax.lax.dynamic_slice(tok, (lo,), (R,))
-                wts = jax.lax.dynamic_slice(gate, (lo,), (R,))
-                gs = jnp.clip(ends, lo, lo + R) \
-                    - jnp.clip(ends - sizes, lo, lo + R)
-                x = jnp.take(h, rows, axis=0).astype(self.dtype)
-
-                def gmm(a, W):
-                    return jax.lax.ragged_dot(
-                        a, W, gs, preferred_element_type=jnp.float32)
-
-                mid = jax.nn.silu(gmm(x, blk["w_gate"])) * gmm(x, blk["w_up"])
-                out = gmm(mid.astype(self.dtype), blk["w_down"])
-                live = (lo + jnp.arange(R) < total)[:, None]
-                return y.at[rows].add(
-                    jnp.where(live, out * wts[:, None], 0.0))
-
-            y = jax.lax.fori_loop(0, (total + R - 1) // R, slab,
-                                  jnp.zeros((T, H), jnp.float32))
             y = y + self._swiglu(h, blk["s_gate"], blk["s_up"],
                                  blk["s_down"])
         step = jnp.int32(1 if decode else 0)
         counts = jnp.stack([
-            valid.sum().astype(jnp.int32) * k, total, sizes.max(),
-            step * (sizes > 0).sum().astype(jnp.int32), step]
+            c["pairs"], c["held"], c["busiest"], step * c["touched"], step]
             + [jnp.int32(0)] * (len(COUNTERS) - 5))      # the other stages'
         return y, counts
 

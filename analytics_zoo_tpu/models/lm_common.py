@@ -7,11 +7,15 @@ SparseLinearLM`` would otherwise copy, as plain functions of their arrays.
   half-split pairs, matmuls in the weights' type that accumulate in float32,
   the gated feed-forward, token ids out of a batch);
 - ``topk_mask``: the exact k-th largest of every row as a mask, no sort;
+- ``routed_experts``: the routed expert layer both MoE classes
+  (``LatentMoELM``, ``models/window_moe_lm.WindowMoELM``) call: the route
+  (sigmoid + selection bias, or softmax over the top-k), the pair sort, the
+  ``ragged_dot`` slabs over the experts held, the ``moe_*`` counts;
 - ``attend_chunks``: softmax attention a chunk of keys at a time under a
   running maximum;
-- ``bump`` / ``read_counters`` / ``no_counts``: the device-side counters a
-  class publishes through the paged contract's ``paged_counters`` (an int32
-  ``(n, 2)`` leaf of the state, 61 bits a counter without int64);
+- ``bump`` / ``read_counters`` / ``no_counts`` / ``counts``: the device-side
+  counters a class publishes through the paged contract's ``paged_counters``
+  (an int32 ``(n, 2)`` leaf of the state, 61 bits a counter without int64);
 - ``scope``: the ``zoo.lm.<stage>`` name a stage's operations carry in the
   compiled HLO.
 """
@@ -151,8 +155,102 @@ def attend_chunks(q, k, v, allowed, key_pos, last, scale, dtype,
         q.shape[0], -1)
 
 
+def routed_experts(h, router_in, blk, valid, *, top_k: int, held, dtype,
+                   scoring: str = "sigmoid", scale: float = 1.0,
+                   act=jax.nn.silu, slab: int = 2048):
+    """A routed expert layer over tokens ``h`` (T, H), of which ``valid``
+    (T,) are real; the router reads ``router_in`` (T, H) (``h`` itself, or
+    the layer's input where the router stands before attention).  ``blk``
+    holds ``router`` (H, experts) float32, ``w_gate`` / ``w_up`` (held, H,
+    F) and ``w_down`` (held, F, H); ``e_bias`` (experts,) where the scoring
+    has a selection bias.
+
+    - ``scoring="sigmoid"``: ``s = sigmoid(router_in W_r)``, the ``top_k``
+      largest of ``s + e_bias``; ``scoring="softmax"``: ``s =
+      softmax(router_in W_r)``, its ``top_k`` largest.  Either way the
+      chosen scores, normalised over the ``top_k`` and times ``scale``, are
+      the pairs' weights (with ``softmax`` that is the softmax over the
+      chosen logits);
+    - ``held = (first, count)``: this process computes the pairs that land
+      on experts ``first ... first + count - 1`` (a chip's share of an
+      expert-parallel deployment); what the others would add is left out;
+    - no pair is dropped at any imbalance: pairs sorted by held expert,
+      slabs of ``slab`` of them through ``jax.lax.ragged_dot`` until none
+      is left; an expert is ``act(x W_gate) * (x W_up)`` then ``W_down``.
+
+    Returns ``(y, counts)``: the routed output (T, H) float32 and
+    ``{"pairs", "held", "busiest", "touched"}`` int32: pairs routed over all
+    experts, those held here, those on the busiest held expert, held experts
+    with at least one pair."""
+    T, H = h.shape
+    k, (first, count) = top_k, held
+    with scope("moe_route"):
+        logits = jnp.matmul(router_in, blk["router"],
+                            precision=jax.lax.Precision.HIGHEST)
+        if scoring == "sigmoid":
+            s = jax.nn.sigmoid(logits)
+            _, idx = jax.lax.top_k(s + blk["e_bias"], k)          # (T, k)
+        elif scoring == "softmax":
+            s = jax.nn.softmax(logits, axis=-1)
+            _, idx = jax.lax.top_k(s, k)
+        else:
+            raise ValueError(f"scoring must be sigmoid|softmax, got "
+                             f"{scoring!r}")
+        g = jnp.take_along_axis(s, idx, axis=-1)
+        g = scale * g / (g.sum(-1, keepdims=True) + 1e-20)
+        local = idx - first
+        on = (local >= 0) & (local < count) & valid[:, None]
+        # pairs sorted by held expert; what is not held sorts behind
+        key = jnp.where(on, local, count).reshape(-1)
+        order = jnp.argsort(key)
+        tok, gate = order // k, g.reshape(-1)[order]
+        sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
+        ends = jnp.cumsum(sizes)
+        total = ends[-1]
+    with scope("moe_experts"):
+        R = min(slab, T * k)
+        # the pair list is padded so that its last slab is whole
+        pad = (-(T * k)) % R
+        tok = jnp.concatenate([tok, jnp.zeros((pad,), tok.dtype)])
+        gate = jnp.concatenate([gate, jnp.zeros((pad,), gate.dtype)])
+
+        def one_slab(i, y):
+            # pairs [lo, lo + R) of the sorted list: each expert's rows
+            # inside the slab are one group of the grouped matmul
+            lo = i * R
+            rows = jax.lax.dynamic_slice(tok, (lo,), (R,))
+            wts = jax.lax.dynamic_slice(gate, (lo,), (R,))
+            gs = jnp.clip(ends, lo, lo + R) \
+                - jnp.clip(ends - sizes, lo, lo + R)
+            x = jnp.take(h, rows, axis=0).astype(dtype)
+
+            def gmm(a, W):
+                return jax.lax.ragged_dot(
+                    a, W, gs, preferred_element_type=jnp.float32)
+
+            mid = act(gmm(x, blk["w_gate"])) * gmm(x, blk["w_up"])
+            out = gmm(mid.astype(dtype), blk["w_down"])
+            live = (lo + jnp.arange(R) < total)[:, None]
+            return y.at[rows].add(jnp.where(live, out * wts[:, None], 0.0))
+
+        y = jax.lax.fori_loop(0, (total + R - 1) // R, one_slab,
+                              jnp.zeros((T, H), jnp.float32))
+    return y, {"pairs": valid.sum().astype(jnp.int32) * k, "held": total,
+               "busiest": sizes.max(),
+               "touched": (sizes > 0).sum().astype(jnp.int32)}
+
+
 def no_counts(names):
     return jnp.zeros((len(names),), jnp.int32)
+
+
+def counts(names, **named):
+    """A call's increments of the counters ``names``: ``named`` values at
+    their places, zeros elsewhere."""
+    c = no_counts(names)
+    for name, value in named.items():
+        c = c.at[names.index(name)].set(jnp.asarray(value, jnp.int32))
+    return c
 
 
 def bump(counters, counts):

@@ -93,10 +93,7 @@ def _no_counts():
 
 
 def _count(**named):
-    c = _no_counts()
-    for name, value in named.items():
-        c = c.at[COUNTERS.index(name)].set(value.astype(jnp.int32))
-    return c
+    return common.counts(COUNTERS, **named)
 
 
 class SparseLinearLM(Layer):

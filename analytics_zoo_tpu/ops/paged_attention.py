@@ -443,8 +443,9 @@ def paged_attention(q, k_pool, v_pool, block_tables, lengths,
 # prefill batch's K/V into blocks), ``pool_gather`` (prefix blocks back out
 # as float32 K/V), ``pool_cursor`` + ``pool_append_attend`` (one decode token
 # a row: append, then attend), ``pool_bytes`` (each leaf's accounting class).
-# A second format, one latent row a token, and a third, grouped-query K/V with
-# compressed keys and a per-slot recurrent state, follow at the end of the file.
+# A second format, one latent row a token, a third, grouped-query K/V with
+# compressed keys and a per-slot recurrent state, and a fourth, grouped-query
+# pages beside per-slot rings of a window's K/V, follow at the end of the file.
 #
 # A pool is a dict of per-layer lists.  ``k`` / ``v``: (n_blocks, block_len,
 # heads * head_dim) blocks, float32 or int8; block 0 is the TRASH block
@@ -459,7 +460,8 @@ _LEAF_CLASS = {"k": "paged_pool", "v": "paged_pool",
                "ks": "scales", "vs": "scales",
                "stk": "lanes", "stv": "lanes",
                "kv": "paged_pool", "ik": "paged_pool",   # the latent format
-               "ck": "paged_pool", "lin": "lanes"}       # the grouped format
+               "ck": "paged_pool", "lin": "lanes",       # the grouped format
+               "rk": "lanes", "rv": "lanes"}             # the window format
 
 
 def init_pools(n_layers: int, n_blocks: int, block_len: int, n_head: int,
@@ -751,7 +753,8 @@ def grouped_commit(pools, ks, vs, cks, dest, *, block_len: int):
     """ONE prefilled sequence into the pools: ``ks`` / ``vs`` per-layer (P,
     kv_heads, head_dim) rows, ``cks`` per-layer (P // stride, kv_heads,
     head_dim) compressed keys (window j at row j); block t lands at pool id
-    ``dest[t]`` (0 = trash).  Returns the new ``k``, ``v``, ``ck`` lists."""
+    ``dest[t]`` (0 = trash).  Returns the new ``k``, ``v``, ``ck`` lists (a
+    pool without ``ck``, the window format's, gives an empty third)."""
     bl, npb = int(block_len), dest.shape[0]
 
     def blocks(rows, per_block):
@@ -772,7 +775,7 @@ def grouped_commit(pools, ks, vs, cks, dest, *, block_len: int):
 
     return ([kv(p, r) for p, r in zip(pools["k"], ks)],
             [kv(p, r) for p, r in zip(pools["v"], vs)],
-            [ck(p, r) for p, r in zip(pools["ck"], cks)])
+            [ck(p, r) for p, r in zip(pools.get("ck", ()), cks)])
 
 
 def _grouped_row_ids(blk, off, G: int, bl: int):
@@ -853,3 +856,66 @@ def grouped_blocks(pool, blocks):
     n_blocks, G, bl, d = pool.shape
     flat = blocks * G + jnp.arange(G, dtype=blocks.dtype)[None, :, None]
     return jnp.take(pool.reshape(n_blocks * G, bl, d), flat, axis=0)
+
+
+# -- the WINDOW pool format ---------------------------------------------------
+#
+# A grouped-query decoder whose layers attend either the whole context (FULL
+# layers) or its last ``window`` positions (WINDOW layers).  A full layer keeps
+# the grouped-query pages above (``k`` / ``v``: a layer a (n_blocks, kv_heads,
+# block_len, head_dim) pool, written by ``grouped_commit`` / ``grouped_append``
+# and read through the block table by ``grouped_blocks``); a window layer keeps
+# for each slot a RING of its last ``window`` positions:
+#
+# - ``rk`` / ``rv``: a window layer a (max_active, kv_heads, window, head_dim)
+#   array; position p of a slot's sequence lies at ring row ``p % window``.
+#   A slot's ring is its own (no block table: the allocator's blocks hold the
+#   full layers alone), so a window layer's state does not grow with the
+#   context, and a slot's rows are one contiguous (window, head_dim) tile a
+#   key head.
+#
+# ``init_window_pools`` (zeroed), ``ring_commit`` (a prefilled sequence's last
+# ``window`` rows of one layer into its slot's ring), ``ring_put`` (one decode
+# token a row); ``pool_cursor`` and ``pool_bytes`` serve this format too.
+
+def init_window_pools(n_full: int, n_window: int, n_blocks: int,
+                      block_len: int, kv_heads: int, head_dim: int,
+                      window: int, max_active: int, dtype):
+    """Zeroed host-side state pytree (``n_blocks`` counts the trash block):
+    ``n_full`` full layers' pages and ``n_window`` window layers' rings."""
+    kv = (n_blocks, kv_heads, block_len, head_dim)
+    ring = (max_active, kv_heads, window, head_dim)
+    return {"k": [np.zeros(kv, dtype) for _ in range(n_full)],
+            "v": [np.zeros(kv, dtype) for _ in range(n_full)],
+            "rk": [np.zeros(ring, dtype) for _ in range(n_window)],
+            "rv": [np.zeros(ring, dtype) for _ in range(n_window)]}
+
+
+def ring_commit(ring, rows, length, slot):
+    """ONE prefilled sequence into slot ``slot`` of one window layer's ring
+    (slots, kv_heads, window, head_dim): of ``rows`` (S, kv_heads, head_dim),
+    the last ``min(length, window)`` real positions, position p at ring row
+    ``p % window``; a ring row no position reaches (a sequence shorter than
+    the window) is zeroed.  A slice of the ring, written in place."""
+    W = ring.shape[2]
+    r = jnp.arange(W)
+    # the latest real position p with p % W == r (negative: none)
+    p = r + W * ((length - 1 - r) // W)
+    got = jnp.take(rows, jnp.clip(p, 0, rows.shape[0] - 1), axis=0)
+    got = jnp.where((p >= 0)[:, None, None], got, 0).transpose(1, 0, 2)
+    return jax.lax.dynamic_update_slice(
+        ring, got[None].astype(ring.dtype), (slot, 0, 0, 0))
+
+
+def ring_put(ring, rows, pos, on):
+    """One decode token a row: row a's ``rows[a]`` (kv_heads, head_dim) into
+    ring row ``pos[a] % window`` of slot a (decode row a IS slot a), where
+    ``on[a]``; an idle slot's ring is left as it is.  The ring is seen as
+    rows of ONE row-major buffer, as ``grouped_append`` sees its pool."""
+    A, G, W, d = ring.shape
+    ids = (jnp.arange(A)[:, None] * G + jnp.arange(G)) * W \
+        + (pos % W)[:, None]
+    ids = jnp.where(on[:, None], ids, A * G * W)         # past the end: dropped
+    return ring.reshape(-1, d).at[ids.reshape(-1)].set(
+        rows.reshape(-1, d).astype(ring.dtype), mode="drop").reshape(
+        ring.shape)
