@@ -39,10 +39,14 @@ weights a row, and its kind by a flag) and a block's key chunks in a
 ``lax.fori_loop``, so that a prefill program holds one layer's code and one
 chunk's, whatever the depth and the bucket: a serving start lowers and
 compiles one prefill program a batch and prompt bucket, each in a fraction
-of the time unrolled layers took.  Decode reads a window layer's
-ring, and a full layer's pages in chunks of table entries, each skipped when
-it lies past every active row's context, so a step's bytes follow the live
-lengths, not the lane.  Weights are built in ``dtype`` (bfloat16 as served;
+of the time unrolled layers took.  Decode reads a window layer's ring in
+chunks, each skipped when it lies past every active row's context.  A full
+layer's pages are read on a TPU by ``ops/paged_attention``'s grouped-page
+kernel, which folds each row's own live blocks and no others, so that a
+row's bytes follow its own length; the XLA path (a CPU, and the kernel's
+oracle) gathers the pages for every row in chunks of table entries, a chunk
+skipped past every active row's context, so that it reads each row to the
+longest live row.  Weights are built in ``dtype`` (bfloat16 as served;
 the router and the norms float32), so ``matmul_operands`` is the tree itself.
 
 Not built: sharing a resident prefix (``prefill_shared_paged`` raises: the
@@ -64,6 +68,7 @@ from analytics_zoo_tpu.models import lm_common as common
 from analytics_zoo_tpu.models.lm_common import NEG_INF, scope as _scope
 from analytics_zoo_tpu.nn.module import Layer
 from analytics_zoo_tpu.ops import paged_attention as paged
+from analytics_zoo_tpu.ops.dispatch import resolve_impl
 
 _POS_CHUNK = 2048       # prefill positions a layer takes at once
 _QUERY_BLOCK = 256      # ... of which the attention takes this many queries
@@ -77,7 +82,8 @@ _PAIR_SLAB = 2048       # token-expert pairs one grouped matmul takes
 # except the two marked (decode); ``window_keys_*`` over decode rows (idle
 # slots left out) x window layers; ``prefill_window_chunks*`` over the live
 # query blocks of window layers (a prefill row's padding rows and blocks run
-# nothing and count nothing).
+# nothing and count nothing); ``full_keys_*`` over decode rows (idle slots
+# left out) x full layers.
 COUNTERS = (
     "moe_pairs",                  # token-expert pairs routed
     "moe_pairs_busiest",          # ... on the busiest expert, summed a call
@@ -87,6 +93,8 @@ COUNTERS = (
     "window_keys_context",        # keys in context there
     "prefill_window_chunks",      # key chunks a causal square would run
     "prefill_window_chunks_run",  # ... those that met the block's window
+    "full_keys_read",             # positions a full layer's read fetched
+    "full_keys_context",          # keys in context there: pos + 1
 )
 
 
@@ -459,12 +467,19 @@ class WindowMoELM(Layer):
             q, [(lo, min(lo + C, W)) for lo in range(0, W, C)], load,
             lambda lo: (active & (pos >= lo)).any())
 
-    def _full_decode(self, q, k_pool, v_pool, bt, pos, active, bl):
-        """A full layer: the context's blocks through the table, ``E``
-        entries at a time, a chunk skipped past every active row's context."""
-        A, G = q.shape[:2]
-        n = bt.shape[1]
+    @staticmethod
+    def _full_spans(n: int, bl: int):
+        """The XLA path's chunks of a table of ``n`` entries: ``E`` entries
+        (``_DECODE_CHUNK`` positions) each."""
         E = max(_DECODE_CHUNK // bl, 1)
+        return [(lo, min(lo + E, n)) for lo in range(0, n, E)]
+
+    def _full_decode(self, q, k_pool, v_pool, bt, pos, active, bl):
+        """A full layer's XLA path: the context's blocks through the table,
+        gathered for every row a chunk of ``_full_spans`` at a time, a chunk
+        skipped past every active row's context; an idle slot reads zero, as
+        from the kernel."""
+        A, G = q.shape[:2]
 
         def load(lo, hi):
             blocks = jnp.broadcast_to(bt[:, None, lo:hi], (A, G, hi - lo))
@@ -475,18 +490,31 @@ class WindowMoELM(Layer):
                 vv.reshape(A, G, -1, vv.shape[-1]), \
                 tok[None, :] <= pos[:, None]
 
-        return self._attend_parts(
-            q, [(lo, min(lo + E, n)) for lo in range(0, n, E)], load,
+        out = self._attend_parts(
+            q, self._full_spans(bt.shape[1], bl), load,
             lambda lo: (active & (pos >= lo * bl)).any())
+        return jnp.where(active[:, None], out, 0.0)
+
+    def _full_read(self, pos, active, n: int, bl: int, mode: str):
+        """Positions a full layer's read fetches for each row: the kernel
+        its live blocks, the XLA path the chunks that ran (every row the
+        same)."""
+        if mode != "xla":
+            return jnp.minimum(-(-(pos + 1) // bl), n) * bl
+        return sum(jnp.where((i == 0) | (active & (pos >= lo * bl)).any(),
+                             (hi - lo) * bl, 0)
+                   for i, (lo, hi) in enumerate(self._full_spans(n, bl)))
 
     def decode_paged(self, params, state, block_tables, pos, tokens, *,
                      block_len: int, kv_quant: str = "off", impl=None):
-        """One token a row (the contract's decode step; XLA gathers through
-        the block table, so ``impl`` has nothing to choose).  A window layer
+        """One token a row (the contract's decode step).  A window layer
         writes its slot's ring and reads it; a full layer appends to its
-        pages and reads the live context through the table.  An idle slot
-        (table all trash) changes no state of its own.  Returns ``(logits,
-        state)``."""
+        pages and reads the live context through the table: ``impl``
+        (``ops/dispatch.resolve_impl``) picks the grouped-page kernel
+        (``pallas`` on a TPU, ``interpret``) or the chunked XLA gather
+        (``xla``, a CPU's).  An idle slot (table all trash) changes no state
+        of its own.  Returns ``(logits, state)``."""
+        mode = resolve_impl(impl)
         bl = int(block_len)
         bt = jnp.asarray(block_tables, jnp.int32)
         pos = jnp.asarray(pos, jnp.int32)
@@ -512,17 +540,25 @@ class WindowMoELM(Layer):
                 fi = self.full_ids.index(li)
                 ks[fi], vs[fi] = paged.grouped_append(state, fi, k, v, cursor)
                 with _scope("full_attend"):
-                    o = self._full_decode(q, ks[fi], vs[fi], bt, pos, active,
-                                          bl)
+                    if mode == "xla":
+                        o = self._full_decode(q, ks[fi], vs[fi], bt, pos,
+                                              active, bl)
+                    else:
+                        o = paged.grouped_paged_attention(
+                            q, ks[fi], vs[fi], bt, pos + 1,
+                            interpret=mode == "interpret").reshape(A, -1)
             h = x + common.mm(o, blk["o"])
             y, c = self._experts(blk, h, x, active, decode=True)
             x = h + y
             counts = counts + c
-        n_win = len(self.window_ids)
+        n_win, n_full = len(self.window_ids), len(self.full_ids)
+        read = self._full_read(pos, active, bt.shape[1], bl, mode)
         counts = counts + _count(
             window_keys_attended=jnp.where(
                 active, jnp.minimum(pos + 1, self.window), 0).sum() * n_win,
-            window_keys_context=jnp.where(active, pos + 1, 0).sum() * n_win)
+            window_keys_context=jnp.where(active, pos + 1, 0).sum() * n_win,
+            full_keys_read=jnp.where(active, read, 0).sum() * n_full,
+            full_keys_context=jnp.where(active, pos + 1, 0).sum() * n_full)
         return self._logits(params, x), dict(
             state, k=ks, v=vs, rk=rks, rv=rvs,
             counters=common.bump(state["counters"], counts))

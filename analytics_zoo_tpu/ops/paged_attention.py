@@ -64,6 +64,12 @@ Two data paths, the `quant_matmul.py` shape:
 backend, the reference on CPU; ``"interpret"`` runs the kernel on CPU for
 the parity tests.
 
+The window format at the end of the file has a decode kernel of its own,
+``grouped_paged_attention``: grouped-query pages ((kv_heads, block_len,
+head_dim) a block, in the cache's type), several query heads a key head, so
+it shares no code with the kernel above; the same grid of one step a row,
+the same live-only loop, fetch and idle-slot rules.
+
 Quantization contract: ``inference/quantize.kv_pack_int8`` /
 ``kv_unpack_int8`` (symmetric, scale = per-(block, head) absmax / 127) —
 the ONE contract the kernel above all, and the append and the commit at
@@ -876,7 +882,9 @@ def grouped_blocks(pool, blocks):
 #
 # ``init_window_pools`` (zeroed), ``ring_commit`` (a prefilled sequence's last
 # ``window`` rows of one layer into its slot's ring), ``ring_put`` (one decode
-# token a row); ``pool_cursor`` and ``pool_bytes`` serve this format too.
+# token a row), ``grouped_paged_attention`` (a full layer's decode read: a
+# Pallas kernel over each row's own live pages); ``pool_cursor`` and
+# ``pool_bytes`` serve this format too.
 
 def init_window_pools(n_full: int, n_window: int, n_blocks: int,
                       block_len: int, kv_heads: int, head_dim: int,
@@ -919,3 +927,214 @@ def ring_put(ring, rows, pos, on):
     return ring.reshape(-1, d).at[ids.reshape(-1)].set(
         rows.reshape(-1, d).astype(ring.dtype), mode="drop").reshape(
         ring.shape)
+
+
+_FOLD_BYTES = 1 << 20       # K and V bytes one fold of the grouped kernel moves
+
+
+def _fold_blocks(n_table: int, block_bytes: int) -> int:
+    """Table entries one fold of ``_grouped_kernel`` takes: the smallest power
+    of two whose K and V blocks (``block_bytes`` each) move ``_FOLD_BYTES``, no
+    wider than the table rounded up to a power of two."""
+    f = 1
+    while 2 * f * block_bytes < _FOLD_BYTES and f < n_table:
+        f *= 2
+    return f
+
+
+def _grouped_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, m_ref, l_ref,
+                    acc_ref, slot_ref, k_buf, v_buf, sems, *, block_len: int,
+                    n_table: int, fold: int, scale: float):
+    """One row's grid step over grouped-query pages: fold the row's LIVE table
+    entries, ``fold`` blocks (``FB = fold * block_len`` positions) at a time,
+    into each key head's online-softmax carry (m / l per query row, acc per
+    query row and lane), then emit.  Query head ``g * R + j`` (R query rows a
+    key head, padded to a sublane multiple) reads key head g.
+
+    A row whose first table entry is block 0, the trash block (an idle slot),
+    has no live block: no DMA, no fold, a zero output row.
+
+    The pools stay in HBM.  One DMA a live block for K and one for V (a
+    block's key heads are one contiguous (kv_heads, block_len, head_dim)
+    tile), by physical id from the scalar-prefetched table, into one of two
+    VMEM slots; each fold first starts the NEXT live fold's DMAs (the row's
+    next fold, or fold 0 of the next row that has a live block), then waits
+    for its own, so a fold's fetch overlaps the previous fold's arithmetic.
+    A block wholly past the length is never fetched (its buffer rows keep
+    whatever they held, and are masked)."""
+    a = pl.program_id(0)
+    n_rows = pl.num_programs(0)
+    G = k_buf.shape[2]
+    fb = fold * block_len
+
+    def live_blocks(row):
+        return jnp.where(bt_ref[row, 0] == 0, 0,
+                         jnp.clip(pl.cdiv(len_ref[row], block_len), 1,
+                                  n_table))
+
+    def next_live(row):
+        # the first row at or after ``row`` with a live block (n_rows: none)
+        return jax.lax.while_loop(
+            lambda r: (r < n_rows) & (live_blocks(jnp.minimum(
+                r, n_rows - 1)) == 0), lambda r: r + 1, row)
+
+    def fetch(row, t, slot, wait: bool = False):
+        """Start, or wait for, the DMAs of fold ``t`` of ``row`` into
+        ``slot`` (a wait rebuilds the descriptors its start used)."""
+        n_live = live_blocks(row)
+        for i in range(fold):
+            j = t * fold + i
+
+            @pl.when(j < n_live)
+            def _block():
+                blk = bt_ref[row, j]
+                for n, (src, dst) in enumerate(((k_hbm, k_buf),
+                                                (v_hbm, v_buf))):
+                    copy = pltpu.make_async_copy(
+                        src.at[blk], dst.at[slot, i], sems.at[slot, n])
+                    copy.wait() if wait else copy.start()
+
+    @pl.when(a == 0)
+    def _first():
+        slot_ref[0] = 0
+        # masked positions multiply the buffer by 0: never-written VMEM may
+        # hold NaN patterns
+        k_buf[...] = jnp.zeros(k_buf.shape, k_buf.dtype)
+        v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
+        first = next_live(0)
+
+        @pl.when(first < n_rows)
+        def _open():
+            fetch(first, 0, 0)      # the one fetch nothing overlaps
+
+    n_folds = pl.cdiv(live_blocks(a), fold)
+
+    @pl.when(n_folds > 0)
+    def _row():
+        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, m_ref.dtype)
+        l_ref[...] = jnp.zeros(l_ref.shape, l_ref.dtype)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, acc_ref.dtype)
+        after = next_live(a + 1)
+        length = jnp.minimum(len_ref[a], n_table * block_len)
+
+        def body(t, carry):
+            slot = slot_ref[0]
+            more = t + 1 < n_folds
+
+            @pl.when(more | (after < n_rows))
+            def _prefetch():
+                fetch(jnp.where(more, a, after), jnp.where(more, t + 1, 0),
+                      1 - slot)
+
+            fetch(a, t, slot, wait=True)
+            slot_ref[0] = 1 - slot
+            valid = t * fb + jax.lax.broadcasted_iota(
+                jnp.int32, (1, fb), 1) < length
+            for g in range(G):
+                k = k_buf[slot, :, g].reshape(fb, -1)             # (FB, d)
+                v = v_buf[slot, :, g].reshape(fb, -1)
+                q = q_ref[0, g].astype(k.dtype)                   # (R, d)
+                s = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale   # (R, FB)
+                s = jnp.where(valid, s, NEG_INF)
+                # m / l keep a lane tile of copies: read one back by a max
+                m_prev = jnp.max(m_ref[g], axis=1, keepdims=True)   # (R, 1)
+                m_new = jnp.maximum(m_prev, jnp.max(s, axis=1,
+                                                    keepdims=True))
+                p = jnp.exp(s - m_new)                            # (R, FB)
+                alpha = jnp.exp(m_prev - m_new)                   # (R, 1)
+                m_ref[g] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+                l_ref[g] = l_ref[g] * alpha + jnp.sum(p, axis=1,
+                                                      keepdims=True)
+                acc_ref[g] = acc_ref[g] * alpha + jnp.dot(
+                    p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            return carry
+
+        jax.lax.fori_loop(0, n_folds, body, 0)
+        for g in range(G):
+            l = jnp.max(l_ref[g], axis=1, keepdims=True)
+            o_ref[0, g] = (acc_ref[g] / l).astype(o_ref.dtype)
+
+    @pl.when(n_folds == 0)
+    def _idle():
+        o_ref[0] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _grouped_pallas(q, k_pool, v_pool, block_tables, lengths,
+                    interpret: bool):
+    # jitted so that a model's full layers, which call this with one set of
+    # shapes, share one trace and one Mosaic lowering of the kernel
+    A, G, J, d = q.shape
+    bl = k_pool.shape[2]
+    n_table = int(block_tables.shape[1])
+    R = -(-J // _SUBLANE) * _SUBLANE
+    fold = _fold_blocks(n_table, G * bl * d * k_pool.dtype.itemsize)
+
+    def row_block(a, bt, ln):
+        return (a, 0, 0, 0)
+
+    rows = pl.BlockSpec((1, G, R, d), row_block)
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    fetch_buf = pltpu.VMEM((2, fold, G, bl, d), k_pool.dtype)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(A,),
+        in_specs=[rows, in_hbm, in_hbm],
+        out_specs=rows,
+        scratch_shapes=[pltpu.VMEM((G, R, _LANE), jnp.float32),
+                        pltpu.VMEM((G, R, _LANE), jnp.float32),
+                        pltpu.VMEM((G, R, d), jnp.float32),
+                        pltpu.SMEM((1,), jnp.int32),
+                        fetch_buf, fetch_buf,
+                        pltpu.SemaphoreType.DMA((2, 2))])
+    kernel = functools.partial(_grouped_kernel, block_len=bl,
+                               n_table=n_table, fold=fold,
+                               scale=1.0 / np.sqrt(d))
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((A, G, R, d), jnp.float32),
+        # the fetch slot and the DMAs in flight carry from one row's step
+        # to the next: the rows run in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="grouped_paged_attention",
+    )(jnp.asarray(block_tables, jnp.int32), jnp.asarray(lengths, jnp.int32),
+      jnp.pad(q.astype(jnp.float32), [(0, 0), (0, 0), (0, R - J), (0, 0)]),
+      k_pool, v_pool)
+    return out[:, :, :J]
+
+
+def grouped_paged_attention(q, k_pool, v_pool, block_tables, lengths, *,
+                            interpret: bool = False):
+    """One decode token a row over grouped-query pages, through a Pallas
+    kernel that reads each row's own live blocks and no others.
+
+    - ``q`` (rows, kv_heads, group, head_dim): the current token's queries,
+      query head ``g * group + j`` of key head g.
+    - ``k_pool`` / ``v_pool`` (n_blocks, kv_heads, block_len, head_dim), the
+      cache's type (the operands of both products: scores and the
+      probabilities are cast to it, both accumulate in float32).
+    - ``block_tables`` (rows, n_table) int32; a row whose FIRST entry is block
+      0 is an idle slot: its output is exactly zero and nothing is read for
+      it.  Entries past a row's length are never read.
+    - ``lengths`` (rows,) int32 >= 1: the valid positions (``pos + 1`` at
+      decode time: the current token's K/V is appended before the read).
+
+    Returns (rows, kv_heads, group, head_dim) float32.  The XLA path that a
+    CPU process serves through, and the kernel's oracle, is the chunked
+    gather of ``models/window_moe_lm.WindowMoELM._full_decode``; ``interpret``
+    runs the kernel on any backend (the parity tests)."""
+    q = jnp.asarray(q)
+    if q.ndim != 4 or k_pool.ndim != 4 or v_pool.shape != k_pool.shape \
+            or k_pool.shape[1] != q.shape[1] \
+            or k_pool.shape[3] != q.shape[3]:
+        raise ValueError(
+            f"q (rows, kv_heads, group, head_dim) {q.shape} does not match "
+            f"pools (n_blocks, kv_heads, block_len, head_dim) {k_pool.shape}"
+            f" / {v_pool.shape}")
+    return _grouped_pallas(q, k_pool, v_pool, block_tables, lengths,
+                           interpret=interpret)

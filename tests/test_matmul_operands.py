@@ -576,3 +576,25 @@ def test_compiled_for_a_v5e_pdecode_neither_converts_nor_copies_a_weight(
     # float32 at a weight's size is the embedding (the gather's) alone
     assert not re.findall(rf"= f32\[(?:{dims(*matmul)})\]", hlo), \
         "a float32 matmul weight in the program"
+
+
+def test_compiled_for_a_v5e_the_grouped_page_kernel_lowers_at_the_cells_widths(
+        one_chip):
+    """``WindowMoELM``'s full-layer read at the ``smallthinker-21b-a3b`` cell's
+    widths (16 rows, 4 key heads of 7 queries, a pool of 2,049 blocks of
+    (4, 128, 128) bfloat16, a lane of 128 entries), compiled (not run) for the
+    chip: Mosaic takes the kernel, and it needs no temporary beside its
+    operands."""
+    import jax
+    import jax.numpy as jnp
+    from analytics_zoo_tpu.ops import paged_attention as paged
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = on_chip((2049, 4, 128, 128), jnp.bfloat16)
+    compiled = jax.jit(paged.grouped_paged_attention).lower(
+        on_chip((16, 4, 7, 128), jnp.float32), pool, pool,
+        on_chip((16, 128), jnp.int32), on_chip((16,), jnp.int32)).compile()
+    assert "grouped_paged_attention" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes == 0

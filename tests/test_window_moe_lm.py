@@ -268,6 +268,138 @@ def test_a_prefill_program_holds_one_layers_code_and_one_key_chunks(grown):
                               else matmuls(4, 128))
 
 
+# -- (b2) the full layers' read: the grouped-page kernel against the XLA path --
+
+@pytest.fixture
+def folds_of_four(monkeypatch):
+    """The kernel's fold cut to 4 blocks of whatever pool it is handed (at the
+    cell's widths 4 blocks are 1 MB; at the toy's a fold would be the whole
+    table), its trace cache cleared on both sides."""
+    from analytics_zoo_tpu.ops import paged_attention as paged
+
+    def four(n_table, block_bytes):
+        return min(4, n_table)
+
+    paged._grouped_pallas.clear_cache()
+    monkeypatch.setattr(paged, "_fold_blocks", four)
+    yield 4
+    paged._grouped_pallas.clear_cache()
+
+
+BL, NTAB = 4, 12                   # 3 folds of 4 blocks: a lane of 48
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("length", [1, BL - 1, BL, BL + 1, 4 * BL + 1,
+                                    NTAB * BL],
+                         ids=["one", "block_less_one", "block", "block_and_one",
+                              "second_fold", "lane_end"])
+def test_the_grouped_page_kernel_equals_the_xla_path(length, dtype,
+                                                     folds_of_four):
+    """``paged.grouped_paged_attention`` (interpreted) against the chunked XLA
+    gather of ``_full_decode`` on toy grouped pools whose blocks lie in a
+    shuffled order: row 0 at ``length``, rows of mixed lengths beside it, and
+    an idle row (first table entry the trash block) whose output is exactly
+    zero.  float32 agrees to rounding; bfloat16 to the probabilities'
+    rounding (2 ** -9 relative, on values of magnitude ~3)."""
+    import jax
+    import jax.numpy as jnp
+    import analytics_zoo_tpu.models.window_moe_lm as M
+    from analytics_zoo_tpu.ops import paged_attention as paged
+    _lm()                                      # the toy's chunk sizes
+    lm = M.WindowMoELM.from_config(CFG, dtype=dtype)
+    G, J, d, A = lm.n_kv, lm.group, lm.head_dim, 5
+    rng = np.random.default_rng(length)
+    nb = 1 + A * NTAB
+    k, v = (jnp.asarray(rng.normal(size=(nb, G, BL, d)), dtype)
+            for _ in range(2))
+    tables = (1 + rng.permutation(A * NTAB)).reshape(A, NTAB).astype(np.int32)
+    tables[3] = 0
+    lens = np.asarray([length, 7, NTAB * BL, 30, 2], np.int32)
+    q = jnp.asarray(rng.normal(size=(A, G, J, d)), jnp.float32)
+    got = np.asarray(paged.grouped_paged_attention(
+        q, k, v, tables, lens, interpret=True)).reshape(A, -1)
+    want = np.asarray(jax.jit(lm._full_decode, static_argnums=6)(
+        q, k, v, jnp.asarray(tables), jnp.asarray(lens - 1),
+        jnp.asarray(tables[:, 0] != 0), BL))
+    assert M._DECODE_CHUNK == 8 and not got[3].any() and not want[3].any()
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=1e-5 if dtype == "float32" else 2e-2)
+
+
+def _full_reads(pos, active, impl):
+    """``full_keys_read`` of one decode step, by hand: the kernel reads each
+    active row's blocks up to its position; the XLA path reads every active
+    row to the last chunk (2 entries, 8 positions) any active row reaches."""
+    if impl == "interpret":
+        return sum(-(-(p + 1) // BL) * BL for p, on in zip(pos, active) if on)
+    reach = max(p for p, on in zip(pos, active) if on)
+    return sum(active) * min((reach // 8 + 1) * 8, 16 * BL)
+
+
+def test_decode_through_the_kernel_equals_the_xla_path_across_a_wrap(
+        folds_of_four):
+    """``prefill_paged`` two rows (10 and 30 tokens: the window of 16 wraps
+    twice by the end), then 18 ``decode_paged`` steps with
+    ``impl="interpret"`` and with ``impl="xla"`` from the same state, an idle
+    slot beside them, blocks in falling pool order: the logits agree to
+    float32 rounding and the served tokens are the same; each path's
+    ``full_keys_read`` is what it read, and ``full_keys_context`` the
+    context, an active row and a full layer a step."""
+    import jax
+    lm, params = _lm()
+    A, P = 3, 32
+    seqs = np.stack([_ids(41, 64), _ids(42, 64), _ids(43, 64)])
+    lens = np.asarray([10, 30, 0], np.int32)
+    tables = np.ascontiguousarray(
+        (1 + np.arange(A * 16, dtype=np.int32)).reshape(A, 16)[:, ::-1])
+    tables[2] = 0
+    state = jax.device_put(lm.init_paged_pools(1 + A * 16, BL, A))
+    prompt = np.where(np.arange(P)[None] < lens[:, None], seqs[:, :P], 0)
+    state, _ = _prefill(lm, params, state, prompt[:2], lens[:2],
+                        tables[:2, :P // BL], range(2), BL)
+    active = [True, True, False]
+    out = {}
+    for impl in ("interpret", "xla"):
+        step = jax.jit(lambda *a, impl=impl: lm.decode_paged(
+            *a, block_len=BL, impl=impl))
+        st, pos, logits_all, read, ctx = state, lens.copy(), [], 0, 0
+        for _ in range(18):
+            toks = np.where(active, seqs[np.arange(A), pos], 0)
+            logits, st = step(params, st, tables, pos, toks)
+            logits_all.append(np.asarray(logits)[:2])
+            read += _full_reads(pos, active, impl) * N_FULL
+            ctx += sum(int(p) + 1 for p, on in zip(pos, active) if on) \
+                * N_FULL
+            pos = pos + np.asarray(active, np.int32)
+        c = lm.paged_counters(st)
+        assert c["full_keys_read"] == read, impl
+        assert c["full_keys_context"] == ctx, impl
+        out[impl] = np.stack(logits_all)
+    np.testing.assert_allclose(out["interpret"], out["xla"], atol=LOGIT_TOL,
+                               rtol=0)
+    assert (out["interpret"].argmax(-1) == out["xla"].argmax(-1)).all()
+
+
+def test_decode_paged_picks_the_full_layers_read_by_impl():
+    """``pallas`` puts ONE Pallas call a full layer into the step (the
+    grouped-page kernel); ``xla`` none: the window layers and the experts
+    are XLA on both."""
+    import jax
+    lm, params = _lm()
+    A = 2
+    state = lm.init_paged_pools(1 + A * 16, BL, A)
+    tables = (1 + np.arange(A * 16, dtype=np.int32)).reshape(A, 16)
+    args = (params, state, tables, np.asarray([5, 9], np.int32),
+            np.asarray([3, 4], np.int32))
+    calls = {}
+    for impl in ("pallas", "xla"):
+        jaxpr = str(jax.make_jaxpr(lambda *a, impl=impl: lm.decode_paged(
+            *a, block_len=BL, impl=impl))(*args))
+        calls[impl] = jaxpr.count("name=grouped_paged_attention")
+    assert calls == {"pallas": N_FULL, "xla": 0}
+
+
 # -- (c) the shared expert layer -----------------------------------------------
 
 @pytest.mark.parametrize("scoring", ["sigmoid", "softmax"])
@@ -404,7 +536,7 @@ def test_the_counters_total_what_the_requests_needed(served):
     n_blocks = b._pool.n_blocks + 1
     assert doc["paged_pool"] == N_FULL * 2 * n_blocks * 2 * 4 * 8 * 4
     assert doc["lanes"] == N_WINDOW * 2 * 2 * 2 * WINDOW * 8 * 4 \
-        + 8 * 2 * 4
+        + 10 * 2 * 4
 
 
 def test_prefix_cache_is_refused_at_start(served):
