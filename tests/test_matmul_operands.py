@@ -598,3 +598,25 @@ def test_compiled_for_a_v5e_the_grouped_page_kernel_lowers_at_the_cells_widths(
         on_chip((16, 128), jnp.int32), on_chip((16,), jnp.int32)).compile()
     assert "grouped_paged_attention" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+def test_compiled_for_a_v5e_the_grouped_page_kernel_reads_key_pairs(one_chip):
+    """``SSMHybridLM``'s page read at the ``phi-4-mini-flash`` cell's widths
+    (32 rows; 10 key PAIRS, each one 128-wide key head of the pages, 4 query
+    rows a pair: ``[q1 | 0]`` and ``[0 | q2]`` of its two differential
+    heads; a pool of 1,025 blocks of (10, 128, 128) bfloat16, a lane of 32
+    entries), compiled (not run) for the chip: Mosaic takes the kernel, and
+    it needs no temporary beside its operands."""
+    import jax
+    import jax.numpy as jnp
+    from analytics_zoo_tpu.ops import paged_attention as paged
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = on_chip((1025, 10, 128, 128), jnp.bfloat16)
+    compiled = jax.jit(paged.grouped_paged_attention).lower(
+        on_chip((32, 10, 4, 128), jnp.float32), pool, pool,
+        on_chip((32, 32), jnp.int32), on_chip((32,), jnp.int32)).compile()
+    assert "grouped_paged_attention" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
