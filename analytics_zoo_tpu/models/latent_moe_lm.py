@@ -226,15 +226,16 @@ class LatentMoELM(Layer):
     def _rotary(self, x, pos):
         return common.rotary(x, pos, self.theta)
 
-    def _moe(self, blk, h, valid, decode: bool):
+    def _moe(self, blk, h, valid, decode: bool, impl=None):
         """The routed layer over tokens ``h`` (T, H), of which ``valid``
         (T,) are real (``lm_common.routed_experts``, sigmoid scoring; the
-        router reads ``h``).  Returns ``(y, counts)``: routed (held experts
-        only) plus shared output, and this call's ``COUNTERS`` increments."""
+        router reads ``h``; ``impl`` as it takes it).  Returns ``(y,
+        counts)``: routed (held experts only) plus shared output, and this
+        call's ``COUNTERS`` increments."""
         y, c = common.routed_experts(
             h, h, blk, valid, top_k=self.top_k, held=self.experts_held,
             dtype=self.dtype, scoring="sigmoid", scale=self.route_scale,
-            slab=_PAIR_SLAB)
+            slab=_PAIR_SLAB, impl=impl)
         with _scope("moe_experts"):
             y = y + self._swiglu(h, blk["s_gate"], blk["s_up"],
                                  blk["s_down"])
@@ -244,7 +245,8 @@ class LatentMoELM(Layer):
             + [jnp.int32(0)] * (len(COUNTERS) - 5))      # the other stages'
         return y, counts
 
-    def _blocks(self, params, x, pos, valid, attend, decode: bool = False):
+    def _blocks(self, params, x, pos, valid, attend, decode: bool = False,
+                impl=None):
         """The decoder stack, written once, over tokens ``x`` (T, H) at
         positions ``pos`` (T,).  ``attend(li, blk, q, c_kv, k_r, q_i, k_i,
         w_i) -> (o, keep, counts)`` is the calling path's attention step:
@@ -252,7 +254,8 @@ class LatentMoELM(Layer):
         row (normalised ``c_kv``, rotary-applied ``k_r``), the indexer's
         queries (T, index heads, index dim), key and head weights; ``o`` is
         (T, heads * v_head_dim), ``keep`` what the path carries out of the
-        layer.  Returns ``(h, keeps, counts)``."""
+        layer; ``impl`` goes to the expert layer.  Returns ``(h, keeps,
+        counts)``."""
         nh, T = self.n_head, x.shape[0]
         keeps, counts = [], _no_counts()
         for li, blk in enumerate(params["blocks"]):
@@ -288,7 +291,7 @@ class LatentMoELM(Layer):
             x = x + self._mm(o, blk["o"])
             h2 = self._rms(blk["ln2"], x)
             if "router" in blk:
-                y, c = self._moe(blk, h2, valid, decode)
+                y, c = self._moe(blk, h2, valid, decode, impl)
                 counts = counts + c
             else:
                 y = self._swiglu(h2, blk["gate"], blk["up"], blk["down"])
@@ -408,10 +411,12 @@ class LatentMoELM(Layer):
                      block_len: int, kv_quant: str = "off", impl=None):
         """One token a row against the latent pool (the contract's decode
         step; the selected rows come through the block table by an XLA
-        gather, so ``impl`` has nothing to choose).  That gather is the
-        selection's only one: whether a selected position is in context and
-        where its row lies are computed from ``pos`` and the table.  Returns
-        ``(logits, state)``."""
+        gather).  That gather is the selection's only one: whether a
+        selected position is in context and where its row lies are computed
+        from ``pos`` and the table.  ``impl`` (``ops/dispatch.resolve_impl``)
+        picks the expert layer's kernel (``pallas`` on a TPU, ``interpret``)
+        or its ``ragged_dot`` slabs (``xla``, a CPU's).  Returns ``(logits,
+        state)``."""
         nh, bl = self.n_head, int(block_len)
         bt = jnp.asarray(block_tables, jnp.int32)
         pos = jnp.asarray(pos, jnp.int32)
@@ -459,7 +464,7 @@ class LatentMoELM(Layer):
         x = jnp.take(params["embed"], jnp.asarray(tokens, jnp.int32),
                      axis=0).astype(jnp.float32)
         h, keeps, counts = self._blocks(params, x, pos, active, attend,
-                                        decode=True)
+                                        decode=True, impl=impl)
         kvs, iks = map(list, zip(*keeps))
         return self._mm(h, params["head"]), dict(
             state, kv=kvs, ik=iks,

@@ -10,7 +10,9 @@ SparseLinearLM`` would otherwise copy, as plain functions of their arrays.
 - ``routed_experts``: the routed expert layer both MoE classes
   (``LatentMoELM``, ``models/window_moe_lm.WindowMoELM``) call: the route
   (sigmoid + selection bias, or softmax over the top-k), the pair sort, the
-  ``ragged_dot`` slabs over the experts held, the ``moe_*`` counts;
+  ``moe_*`` counts, and ``expert_pairs``: the experts held over the sorted
+  pairs (a decode step's through ``ops/expert_matmul``'s kernel, larger
+  calls through ``ragged_dot`` slabs);
 - ``attend_chunks``: softmax attention a chunk of keys at a time under a
   running maximum;
 - ``bump`` / ``read_counters`` / ``no_counts`` / ``counts``: the device-side
@@ -27,6 +29,9 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from analytics_zoo_tpu.ops import expert_matmul
+from analytics_zoo_tpu.ops.dispatch import resolve_impl
 
 NEG_INF = -1e30
 LIMB = 30               # a counter is (hi, lo) int32 with lo < 2 ** LIMB
@@ -157,7 +162,7 @@ def attend_chunks(q, k, v, allowed, key_pos, last, scale, dtype,
 
 def routed_experts(h, router_in, blk, valid, *, top_k: int, held, dtype,
                    scoring: str = "sigmoid", scale: float = 1.0,
-                   act=jax.nn.silu, slab: int = 2048):
+                   act=jax.nn.silu, slab: int = 2048, impl=None):
     """A routed expert layer over tokens ``h`` (T, H), of which ``valid``
     (T,) are real; the router reads ``router_in`` (T, H) (``h`` itself, or
     the layer's input where the router stands before attention).  ``blk``
@@ -174,15 +179,14 @@ def routed_experts(h, router_in, blk, valid, *, top_k: int, held, dtype,
     - ``held = (first, count)``: this process computes the pairs that land
       on experts ``first ... first + count - 1`` (a chip's share of an
       expert-parallel deployment); what the others would add is left out;
-    - no pair is dropped at any imbalance: pairs sorted by held expert,
-      slabs of ``slab`` of them through ``jax.lax.ragged_dot`` until none
-      is left; an expert is ``act(x W_gate) * (x W_up)`` then ``W_down``.
+    - no pair is dropped at any imbalance: pairs sorted by held expert; an
+      expert is ``act(x W_gate) * (x W_up)`` then ``W_down`` (``expert_pairs``,
+      which ``slab`` and ``impl`` go to).
 
     Returns ``(y, counts)``: the routed output (T, H) float32 and
     ``{"pairs", "held", "busiest", "touched"}`` int32: pairs routed over all
     experts, those held here, those on the busiest held expert, held experts
     with at least one pair."""
-    T, H = h.shape
     k, (first, count) = top_k, held
     with scope("moe_route"):
         logits = jnp.matmul(router_in, blk["router"],
@@ -205,12 +209,42 @@ def routed_experts(h, router_in, blk, valid, *, top_k: int, held, dtype,
         order = jnp.argsort(key)
         tok, gate = order // k, g.reshape(-1)[order]
         sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
+    y = expert_pairs(h, tok, gate, sizes, blk, dtype=dtype, act=act,
+                     slab=slab, impl=impl)
+    return y, {"pairs": valid.sum().astype(jnp.int32) * k,
+               "held": sizes.sum(), "busiest": sizes.max(),
+               "touched": (sizes > 0).sum().astype(jnp.int32)}
+
+
+def expert_pairs(h, tok, gate, sizes, blk, *, dtype, act=jax.nn.silu,
+                 slab: int = 2048, impl=None):
+    """``routed_experts``' experts over its sorted pairs: pair p is token
+    ``tok[p]`` of ``h`` (T, H) with weight ``gate[p]``; held expert e owns
+    the next ``sizes[e]`` pairs, from pair 0 on; pairs past ``sum(sizes)``
+    are held by none.  A call of at most ``expert_matmul.MAX_PAIRS`` pairs
+    (a decode step) runs through ``expert_matmul.grouped_expert_ffn``, which
+    reads each touched expert's weights once, back to back, unless ``impl``
+    (``ops/dispatch.resolve_impl``) is ``xla`` (a CPU's); a larger call
+    (prefill), or ``xla``, runs slabs of ``slab`` pairs through
+    ``jax.lax.ragged_dot`` until none is left (the kernel's oracle).
+    Returns the weighted sum over each token's pairs, (T, H) float32."""
+    T, H = h.shape
+    P = tok.shape[0]
+    with scope("moe_experts"):
+        mode = resolve_impl(impl)
+        if mode != "xla" and P <= expert_matmul.MAX_PAIRS:
+            out = expert_matmul.grouped_expert_ffn(
+                jnp.take(h, tok, axis=0).astype(dtype), blk["w_gate"],
+                blk["w_up"], blk["w_down"], sizes, act=act,
+                interpret=mode == "interpret")
+            # the rows of pairs held by none come out zero
+            return jnp.zeros((T, H), jnp.float32).at[tok].add(
+                out * gate[:, None])
         ends = jnp.cumsum(sizes)
         total = ends[-1]
-    with scope("moe_experts"):
-        R = min(slab, T * k)
+        R = min(slab, P)
         # the pair list is padded so that its last slab is whole
-        pad = (-(T * k)) % R
+        pad = (-P) % R
         tok = jnp.concatenate([tok, jnp.zeros((pad,), tok.dtype)])
         gate = jnp.concatenate([gate, jnp.zeros((pad,), gate.dtype)])
 
@@ -233,11 +267,8 @@ def routed_experts(h, router_in, blk, valid, *, top_k: int, held, dtype,
             live = (lo + jnp.arange(R) < total)[:, None]
             return y.at[rows].add(jnp.where(live, out * wts[:, None], 0.0))
 
-        y = jax.lax.fori_loop(0, (total + R - 1) // R, one_slab,
-                              jnp.zeros((T, H), jnp.float32))
-    return y, {"pairs": valid.sum().astype(jnp.int32) * k, "held": total,
-               "busiest": sizes.max(),
-               "touched": (sizes > 0).sum().astype(jnp.int32)}
+        return jax.lax.fori_loop(0, (total + R - 1) // R, one_slab,
+                                 jnp.zeros((T, H), jnp.float32))
 
 
 def no_counts(names):

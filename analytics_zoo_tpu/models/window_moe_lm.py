@@ -275,14 +275,14 @@ class WindowMoELM(Layer):
         return common.rotary_half(q, pos, self.theta), \
             common.rotary_half(k, pos, self.theta), v
 
-    def _experts(self, blk, x, r_in, valid, decode: bool):
+    def _experts(self, blk, x, r_in, valid, decode: bool, impl=None):
         """The expert layer after attention: ``x`` (T, H) the residual,
-        ``r_in`` the layer's input the router reads.  Returns ``(y,
-        counts)``."""
+        ``r_in`` the layer's input the router reads; ``impl`` as
+        ``lm_common.routed_experts`` takes it.  Returns ``(y, counts)``."""
         y, c = common.routed_experts(
             self._rms(blk["ln2"], x), r_in, blk, valid, top_k=self.top_k,
             held=(0, self.n_experts), dtype=self.dtype, scoring="softmax",
-            act=jax.nn.relu, slab=_PAIR_SLAB)
+            act=jax.nn.relu, slab=_PAIR_SLAB, impl=impl)
         step = jnp.int32(1 if decode else 0)
         return y, _count(moe_pairs=c["pairs"], moe_pairs_busiest=c["busiest"],
                          moe_experts_touched=step * c["touched"],
@@ -512,7 +512,8 @@ class WindowMoELM(Layer):
         pages and reads the live context through the table: ``impl``
         (``ops/dispatch.resolve_impl``) picks the grouped-page kernel
         (``pallas`` on a TPU, ``interpret``) or the chunked XLA gather
-        (``xla``, a CPU's).  An idle slot (table all trash) changes no state
+        (``xla``, a CPU's), and likewise the expert layer's kernel or its
+        ``ragged_dot`` slabs.  An idle slot (table all trash) changes no state
         of its own.  Returns ``(logits, state)``."""
         mode = resolve_impl(impl)
         bl = int(block_len)
@@ -548,7 +549,7 @@ class WindowMoELM(Layer):
                             q, ks[fi], vs[fi], bt, pos + 1,
                             interpret=mode == "interpret").reshape(A, -1)
             h = x + common.mm(o, blk["o"])
-            y, c = self._experts(blk, h, x, active, decode=True)
+            y, c = self._experts(blk, h, x, active, decode=True, impl=mode)
             x = h + y
             counts = counts + c
         n_win, n_full = len(self.window_ids), len(self.full_ids)

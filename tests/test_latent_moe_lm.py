@@ -296,6 +296,17 @@ def test_absorbed_decode_equals_expanded_prefill():
     """``decode_paged`` (absorbed, selected rows gathered through the block
     table) logit for logit against ``call`` (expanded, masked dense scores)
     over the same tokens, 10 steps, contexts 5-26 around ``index_topk``."""
+    _absorbed_against_expanded(impl=None)
+
+
+def test_absorbed_decode_through_the_expert_kernel_equals_expanded_prefill():
+    """The same with ``impl="interpret"``: the decode step's routed experts
+    through ``ops/expert_matmul``'s kernel (a share of 4 of 8 experts held,
+    so pairs land off the chip), against ``call``'s ``ragged_dot``."""
+    _absorbed_against_expanded(impl="interpret")
+
+
+def _absorbed_against_expanded(impl):
     import jax
     lm, params, _ = _lm()
     g = np.random.default_rng(1)
@@ -304,7 +315,8 @@ def test_absorbed_decode_equals_expanded_prefill():
     state, tables, logits0 = _prefilled(lm, params, prompt, lens)
     seqs = [list(prompt[r, :lens[r]]) for r in range(2)]
     dec = jax.jit(lambda s, po, tk: lm.decode_paged(params, s, tables, po,
-                                                    tk, block_len=4))
+                                                    tk, block_len=4,
+                                                    impl=impl))
     got, tok, pos = [np.asarray(logits0)], np.asarray(logits0).argmax(-1), lens
     for _ in range(10):
         for r in range(2):

@@ -620,3 +620,32 @@ def test_compiled_for_a_v5e_the_grouped_page_kernel_reads_key_pairs(one_chip):
         on_chip((32, 32), jnp.int32), on_chip((32,), jnp.int32)).compile()
     assert "grouped_paged_attention" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+@pytest.mark.parametrize("pairs,experts,hidden,width,act", [
+    (96, 64, 2560, 768, "relu"), (128, 16, 6144, 2048, "silu")],
+    ids=["smallthinker_docmix", "glm5_longdoc"])
+def test_compiled_for_a_v5e_the_expert_kernel_lowers_at_the_cells_widths(
+        one_chip, pairs, experts, hidden, width, act):
+    """A decode step's routed experts at the two MoE cells' widths (16 rows
+    x top-6 over 64 held experts of 2560 x 768; 16 rows x top-8 over 16
+    held experts of 6144 x 2048), compiled (not run) for the chip: Mosaic
+    takes ``ops/expert_matmul``'s kernel in the tile its shapes give (256
+    and 128 lanes), and no weight is copied beside it."""
+    import jax
+    import jax.numpy as jnp
+    from analytics_zoo_tpu.ops import expert_matmul
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    w = on_chip((experts, hidden, width), jnp.bfloat16)
+    compiled = jax.jit(lambda x, a, b, c, s: expert_matmul.grouped_expert_ffn(
+        x, a, b, c, s, act=getattr(jax.nn, act))).lower(
+        on_chip((pairs, hidden), jnp.bfloat16), w, w,
+        on_chip((experts, width, hidden), jnp.bfloat16),
+        on_chip((experts,), jnp.int32)).compile()
+    assert "grouped_expert_ffn" in compiled.as_text()
+    assert expert_matmul._f_tile(hidden, width, 2) == \
+        {768: 256, 2048: 128}[width]
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20

@@ -70,9 +70,10 @@ def _prefill(lm, params, state, prompt, lens, dest, slots, bl):
         np.asarray(slots, np.int32))
 
 
-def _decode(lm, bl):
+def _decode(lm, bl, impl=None):
     import jax
-    return jax.jit(lambda *a: lm.decode_paged(*a, block_len=bl))
+    return jax.jit(lambda *a: lm.decode_paged(*a, block_len=bl,
+                                              impl=impl))
 
 
 # -- (a) the full forward ------------------------------------------------------
@@ -157,11 +158,23 @@ def test_a_slot_reused_after_a_longer_request_gives_a_fresh_slots_logits():
     """Slot 0 serves a 40-token context first; then a 6-token prompt in the
     same slot (its ring holds the longer request's rows past the short one's
     positions) decodes 12 steps: the logits are a fresh state's."""
+    _slot_reused(impl=None)
+
+
+def test_a_slot_reused_through_the_kernels_gives_a_fresh_slots_logits(
+        folds_of_four):
+    """The same with ``impl="interpret"``: the decode layers' routed experts
+    through ``ops/expert_matmul``'s kernel, the full layers' read through
+    the grouped-page kernel."""
+    _slot_reused(impl="interpret")
+
+
+def _slot_reused(impl):
     import jax
     lm, params = _lm()
     A, bl, ntab, P = 1, 4, 16, 32
     tables = 1 + np.arange(ntab, dtype=np.int32)[None]
-    step = _decode(lm, bl)
+    step = _decode(lm, bl, impl)
 
     def serve(state, ids, n, steps):
         prompt = np.zeros((1, P), np.int32)
@@ -188,6 +201,16 @@ def test_a_padding_row_and_an_idle_slot_change_nothing():
     """A batch's padding row (blocks all trash, slot = the drop sentinel) is
     skipped whole; an idle slot's decode step (table all trash) leaves its
     rings as they were, and counts nothing."""
+    _padding_and_idle(impl=None)
+
+
+def test_an_idle_slot_through_the_kernels_changes_nothing(folds_of_four):
+    """The same with ``impl="interpret"``: the idle slot's token routes no
+    pair through ``ops/expert_matmul``'s kernel."""
+    _padding_and_idle(impl="interpret")
+
+
+def _padding_and_idle(impl):
     import jax
     lm, params = _lm()
     A, bl, ntab = 2, 4, 16
@@ -202,17 +225,18 @@ def test_a_padding_row_and_an_idle_slot_change_nothing():
     assert lm.paged_counters(state)["moe_pairs"] == 32 * TOP_K * 4
     rk = np.asarray(state["rk"][0])
     assert np.abs(rk[0]).max() > 0 and not rk[1].any()
-    _, after = _decode(lm, bl)(params, state, tables,
-                               np.asarray([32, 5], np.int32),
-                               np.asarray([7, 9], np.int32))
+    _, after = _decode(lm, bl, impl)(params, state, tables,
+                                     np.asarray([32, 5], np.int32),
+                                     np.asarray([7, 9], np.int32))
     for name in ("rk", "rv"):
         for before, now in zip(state[name], after[name]):
             assert not np.asarray(now)[1].any()
             np.testing.assert_array_equal(np.asarray(now)[0, :, 1:],
                                           np.asarray(before)[0, :, 1:])
-    c = lm.paged_counters(after)
-    assert c["window_keys_context"] - lm.paged_counters(state)[
-        "window_keys_context"] == 33 * N_WINDOW
+    c, was = lm.paged_counters(after), lm.paged_counters(state)
+    assert c["window_keys_context"] - was["window_keys_context"] \
+        == 33 * N_WINDOW
+    assert c["moe_pairs"] - was["moe_pairs"] == TOP_K * 4
 
 
 @pytest.mark.parametrize("length", [8, 24, 64], ids=["one_block", "three_blocks",
@@ -383,8 +407,9 @@ def test_decode_through_the_kernel_equals_the_xla_path_across_a_wrap(
 
 def test_decode_paged_picks_the_full_layers_read_by_impl():
     """``pallas`` puts ONE Pallas call a full layer into the step (the
-    grouped-page kernel); ``xla`` none: the window layers and the experts
-    are XLA on both."""
+    grouped-page kernel) and runs every layer's routed experts through
+    ``ops/expert_matmul``'s kernel, no ``ragged_dot`` left; ``xla`` has
+    neither kernel: the window layers are XLA on both."""
     import jax
     lm, params = _lm()
     A = 2
@@ -392,12 +417,15 @@ def test_decode_paged_picks_the_full_layers_read_by_impl():
     tables = (1 + np.arange(A * 16, dtype=np.int32)).reshape(A, 16)
     args = (params, state, tables, np.asarray([5, 9], np.int32),
             np.asarray([3, 4], np.int32))
-    calls = {}
+    calls, experts = {}, {}
     for impl in ("pallas", "xla"):
         jaxpr = str(jax.make_jaxpr(lambda *a, impl=impl: lm.decode_paged(
             *a, block_len=BL, impl=impl))(*args))
         calls[impl] = jaxpr.count("name=grouped_paged_attention")
+        experts[impl] = ("name=grouped_expert_ffn" in jaxpr,
+                         "ragged_dot" in jaxpr)
     assert calls == {"pallas": N_FULL, "xla": 0}
+    assert experts == {"pallas": (True, False), "xla": (False, True)}
 
 
 # -- (c) the shared expert layer -----------------------------------------------
